@@ -44,6 +44,20 @@ def _timeit(fn, reps=3):
     return (time.perf_counter() - t0) / reps * 1e6  # µs
 
 
+def _child_env() -> dict:
+    """Environment for a bench group's child process, which runs its own
+    JAX on the parent's platform.  On an accelerator the parent already
+    holds the chip, so a child could not reach it: those groups refuse to
+    start there instead of timing something else."""
+    platform = jax.default_backend()
+    if platform != "cpu":
+        raise RuntimeError(
+            f"this bench group starts child JAX processes, which cannot "
+            f"use the {platform} while this process holds it; run the "
+            "group with JAX_PLATFORMS=cpu")
+    return dict(os.environ, PYTHONPATH="src")
+
+
 def _mbits(hist, tol=1e-6):
     """Headline metric string + extra dict, via the shared `repro.exp`
     helper (one implementation for benches, sweeps and artifacts — the old
@@ -303,10 +317,8 @@ bw = (hists["sharded"].gaps == hists["fast"].gaps
       and hists["sharded"].up_bits == hists["fast"].up_bits)
 print(f"BITWISE {bw}")
 """
-    env = dict(os.environ, PYTHONPATH="src")
-    env.setdefault("JAX_PLATFORMS", "cpu")
     proc = subprocess.run([sys.executable, "-c", script], capture_output=True,
-                          text=True, timeout=900, env=env)
+                          text=True, timeout=900, env=_child_env())
     res, bw = {}, None
     for line in proc.stdout.splitlines():
         if line.startswith("RESULT"):
@@ -414,10 +426,7 @@ def engine_sharded():
     import sys
 
     tiny = os.environ.get("REPRO_BENCH_TINY", "0") == "1"
-    env = dict(os.environ, PYTHONPATH="src")
-    # pin the child to CPU when the parent doesn't say otherwise — on images
-    # with a TPU plugin an unpinned child burns minutes probing for hardware
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env = _child_env()
     rows = []
     for ndev in ((8,) if tiny else (8, 4)):
         script = (_ENGINE_GRID_SCRIPT.replace("@NDEV@", str(ndev))
@@ -542,8 +551,7 @@ def cohort_stream():
     import sys
 
     tiny = os.environ.get("REPRO_BENCH_TINY", "0") == "1"
-    env = dict(os.environ, PYTHONPATH="src")
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    env = _child_env()
     script = _COHORT_STREAM_SCRIPT.replace("@TINY@", str(tiny))
     proc = subprocess.run([sys.executable, "-c", script],
                           capture_output=True, text=True, timeout=900,
@@ -612,8 +620,11 @@ def cold_start():
     for name, exp, cell, backend, ndev in grid:
         work = tempfile.mkdtemp(prefix=f"bench_cold_start_{name}_")
         ckpt = os.path.join(work, "ckpt")
-        env = dict(os.environ, PYTHONPATH="src")
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        env = _child_env()
+        # a compile cache of this run's own, so the cold child really
+        # compiles (the checkout's shared cache may already hold the
+        # programs); the warm child reads the same one
+        env["JAX_COMPILATION_CACHE_DIR"] = os.path.join(work, "xla")
         if ndev:
             env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                                 + f" --xla_force_host_platform_device_count"

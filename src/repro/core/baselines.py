@@ -26,7 +26,14 @@ import numpy as np
 
 from . import glm
 from .basis import MatrixBasis
-from .bl import _BACKENDS, History, _client_hcoef, _server_reconstruct, proj_mu
+from .bl import (
+    _BACKENDS,
+    History,
+    _client_hcoef,
+    _server_reconstruct,
+    proj_mu_eig,
+    proj_mu_solve,
+)
 from .compressors import FLOAT_BITS, Compressor, RandK
 
 
@@ -92,7 +99,7 @@ def newton(
             ) / n
             g = glm.global_grad(clients, x)
             up += sum(b.r * b.r + b.r for b in bases) / n * FLOAT_BITS
-        x = x - jnp.linalg.solve(H, g)
+        x = x - glm.spd_solve(H, g)
     return hist
 
 
@@ -174,8 +181,7 @@ def nl1(
     for _ in range(steps):
         hist.append(float(glm.global_loss(clients, x)) - f_star, up, 0.0)
         g = glm.global_grad(clients, x)
-        H = proj_mu(H_from(hcoef), mu)
-        x = x - jnp.linalg.solve(H, g)
+        x = x - proj_mu_solve(*proj_mu_eig(H_from(hcoef), mu), g)
         step_bits = 0.0
         for i, c in enumerate(clients):
             m = c.A.shape[0]

@@ -44,11 +44,52 @@ from .compressors import FLOAT_BITS, Compressor
 _BACKENDS = ("auto", "fast", "fast+sharded", "reference")
 
 
+def _sym_eigh(S: jax.Array):
+    """(w, V) of a symmetric matrix.  A TPU takes the Jacobi algorithm: its
+    default, QDWH spectral divide-and-conquer, does not compile in f64 at
+    d=1200 within 15 minutes, Jacobi in about 30 seconds.  Every other
+    platform keeps `jnp.linalg.eigh`."""
+    def jacobi(S):
+        V, w = jax.lax.linalg.eigh(
+            S, implementation=jax.lax.linalg.EighImplementation.JACOBI)
+        return w, V
+
+    def default(S):
+        w, V = jnp.linalg.eigh(S)
+        return w, V
+
+    return jax.lax.platform_dependent(S, tpu=jacobi, default=default)
+
+
+def proj_mu_eig(A: jax.Array, mu: float):
+    """[A]_μ, the projection onto {A = Aᵀ, A ⪰ μI}, with the
+    eigendecomposition it comes from: ``(P, w_μ, V)`` with
+    P = V diag(w_μ) Vᵀ and w_μ the eigenvalues of sym(A) clipped at μ.
+
+    P is formed as sym(A) + V diag(w_μ − w) Vᵀ, the same matrix in exact
+    arithmetic, so it is sym(A) itself wherever the projection does not
+    bite, whatever the eigensolver's accuracy (a TPU's Jacobi eigh in
+    emulated f64 is the less accurate one)."""
+    S = (A + A.T) / 2.0
+    w, V = _sym_eigh(S)
+    w_mu = jnp.maximum(w, mu)
+    return S + (V * (w_mu - w)) @ V.T, w_mu, V
+
+
+def proj_mu_solve(P: jax.Array, w: jax.Array, V: jax.Array,
+                  b: jax.Array) -> jax.Array:
+    """P⁻¹b for ``(P, w, V) = proj_mu_eig(...)``: a solve through the
+    eigendecomposition plus one step of iterative refinement against P.
+    The server solves against [H]_μ (BL1, FedNL-BAG) take this form: the
+    factors exist already, and on a TPU an f64 Cholesky (or QR) does not
+    compile inside a client-sharded program, where this does."""
+    x = V @ ((V.T @ b) / w)
+    return x + V @ ((V.T @ (b - P @ x)) / w)
+
+
 def proj_mu(A: jax.Array, mu: float) -> jax.Array:
     """[A]_μ: projection onto {A = Aᵀ, A ⪰ μI} (used by BL1)."""
-    S = (A + A.T) / 2.0
-    w, V = jnp.linalg.eigh(S)
-    return (V * jnp.maximum(w, mu)) @ V.T
+    return proj_mu_eig(A, mu)[0]
 
 
 def _sym(A):

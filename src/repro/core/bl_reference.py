@@ -28,7 +28,8 @@ from .bl import (
     _psd_sum_matrix,
     _server_reconstruct,
     _sym,
-    proj_mu,
+    proj_mu_eig,
+    proj_mu_solve,
 )
 from .compressors import FLOAT_BITS, Compressor
 
@@ -83,7 +84,7 @@ def bl1_reference(
     for _ in range(steps):
         hist.append(float(glm.global_loss(clients, z)) - f_star, up, down)
 
-        Hmu = proj_mu(H, mu)
+        Hmu, wmu, V = proj_mu_eig(H, mu)
         # gradient leg
         if xi == 1:
             w = z
@@ -106,7 +107,7 @@ def bl1_reference(
         up += step_bits / n
 
         # server model step + broadcast
-        x_next = z - jnp.linalg.solve(Hmu, g)
+        x_next = z - proj_mu_solve(Hmu, wmu, V, g)
         H = H + H_delta / n
         key, sk = jax.random.split(key)
         v, vbits = model_comp(sk, x_next - z)
@@ -169,7 +170,7 @@ def bl2_reference(
     hist = History([], [], [])
 
     for _ in range(steps):
-        x_cur = jnp.linalg.solve(_sym(H) + l_avg * jnp.eye(d, dtype=x0.dtype), g)
+        x_cur = glm.spd_solve(_sym(H) + l_avg * jnp.eye(d, dtype=x0.dtype), g)
         hist.append(float(glm.global_loss(clients, x_cur)) - f_star, up, down)
 
         key, sk = jax.random.split(key)
@@ -273,7 +274,7 @@ def bl3_reference(
     for _ in range(steps):
         Hk = beta * A_avg - C_avg
         gk = beta * g1_avg - g2_avg
-        x_cur = jnp.linalg.solve(Hk, gk)
+        x_cur = glm.qr_solve(Hk, gk)
         hist.append(float(glm.global_loss(clients, x_cur)) - f_star, up, down)
 
         key, sk = jax.random.split(key)

@@ -18,10 +18,9 @@ fast-vs-reference parity tests in `tests/test_batched_parity.py` tight.
 
 The hot coefficient transform Γ = VᵀAV can be routed through the batched
 Pallas `basis_project` kernel (`repro.kernels.ops`) by setting
-``REPRO_BL_PALLAS=1`` (or compiling the kernels with
-``REPRO_PALLAS_COMPILE=1`` on a real accelerator); the default on CPU is a
-float64 einsum, which the parity tests rely on (the Pallas MXU path
-accumulates in f32).
+``REPRO_BL_PALLAS=1`` (interpreted on the CPU, compiled on a TPU); the
+default is a float64 einsum, which the parity tests rely on (the kernel
+computes in f32).
 """
 from __future__ import annotations
 
@@ -525,16 +524,19 @@ def newton_solve_fused(batch: ClientBatch, x0: jax.Array,
     The scale-friendly analogue of `glm.newton_solve` (which loops clients
     in Python and stacks (n, d, d) Hessians) — same algorithm, fused math.
     """
-    @jax.jit
-    def one(x):
-        g = global_grad(batch, x)
-        H = global_hess_fused(batch, x)
-        return x - jnp.linalg.solve(H, g)
-
     x = x0
     for _ in range(iters):
-        x = one(x)
+        x = _newton_step_fused(batch, x)
     return x
+
+
+@jax.jit
+def _newton_step_fused(batch: ClientBatch, x: jax.Array) -> jax.Array:
+    # the fleet is an argument, not a closure: a closed-over fleet is
+    # embedded in the executable as a constant (1.1 GB at fig1-xl scale)
+    g = global_grad(batch, x)
+    H = global_hess_fused(batch, x)
+    return x - glm.spd_solve(H, g)
 
 
 def hess_coeff_target(basisb: BatchedBasis, batch: ClientBatch, x: jax.Array) -> jax.Array:

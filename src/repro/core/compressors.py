@@ -159,11 +159,9 @@ def _selection_threshold(a32: jax.Array, k: int) -> jax.Array:
 
     Backends return bitwise-identical thresholds; see module docstring."""
     if os.environ.get("REPRO_BL_PALLAS", "0") == "1":
-        from repro.kernels import ops
         from repro.kernels.topk_threshold import topk_row_threshold
 
-        t = topk_row_threshold(a32.reshape((-1,) + a32.shape[-1:]), k,
-                               interpret=ops.INTERPRET)
+        t = topk_row_threshold(a32.reshape((-1,) + a32.shape[-1:]), k)
         return t.reshape(a32.shape[:-1] + (1,))
     vals, idx = jax.lax.top_k(a32, k)
     # keep both outputs alive: with the indices dead, XLA rewrites top_k into
@@ -243,13 +241,12 @@ class TopK(Compressor):
         if (self.symmetrize or x.dtype != jnp.float32
                 or os.environ.get("REPRO_BL_PALLAS", "0") != "1"):
             return super().compress_sum(keys, x)
-        from repro.kernels import ops
         from repro.kernels.topk_threshold import topk_compress_sum
 
         n = x.shape[0]
         v = x.reshape(n, -1)
         kk = min(self.k, v.shape[1])
-        out, s = topk_compress_sum(v, kk, interpret=ops.INTERPRET)
+        out, s = topk_compress_sum(v, kk)
         c = _full(n, kk)
         return (out.reshape(x.shape), comm.Counts(floats=c, indices=c),
                 s.reshape(x.shape[1:]))

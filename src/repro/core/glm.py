@@ -17,6 +17,7 @@ from typing import List
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.scipy.linalg import cho_solve, solve_triangular
 
 
 @dataclasses.dataclass
@@ -24,6 +25,24 @@ class ClientData:
     A: jax.Array  # (m, d) features
     b: jax.Array  # (m,) labels in {−1, +1}
     lam: float    # ridge coefficient (shared)
+
+
+def spd_solve(A: jax.Array, b: jax.Array) -> jax.Array:
+    """A⁻¹b for symmetric positive definite A: a Cholesky factor and two
+    triangular solves.  The Newton and BL2 server systems are SPD by
+    construction (Hessians with a λI ridge, a symmetrized shift plus its
+    ℓ-weighted identity), and the TPU compiler implements LU only in f32,
+    so their f64 solves go through here ([·]_μ systems take
+    `bl.proj_mu_solve`)."""
+    L = jnp.linalg.cholesky(A)
+    return cho_solve((L, True), b)
+
+
+def qr_solve(A: jax.Array, b: jax.Array) -> jax.Array:
+    """A⁻¹b for a square nonsingular A that need not be symmetric: QR and
+    one triangular solve (compiles in f64 on TPU, unlike LU)."""
+    Q, R = jnp.linalg.qr(A)
+    return solve_triangular(R, Q.T @ b)
 
 
 def sigmoid(t):
@@ -79,7 +98,7 @@ def newton_solve(clients: List[ClientData], x0: jax.Array, iters: int = 20) -> j
     for _ in range(iters):
         g = global_grad(clients, x)
         Hm = global_hess(clients, x)
-        x = x - jnp.linalg.solve(Hm, g)
+        x = x - spd_solve(Hm, g)
     return x
 
 

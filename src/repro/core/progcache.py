@@ -24,9 +24,11 @@ round).  This module makes start-up a *load*:
 
   * **Tier 2 — JAX persistent compilation cache.**  Everything the AOT
     layer doesn't own (gap-stream evaluations, one-off partial-chunk
-    lengths, dry-run compiles) still goes through ``jax.jit``; activating
-    a cache also points ``jax_compilation_cache_dir`` at
-    ``<cache_dir>/xla`` so those compiles persist across processes too.
+    lengths, problem construction, dry-run compiles) still goes through
+    ``jax.jit``; :func:`enable_compile_cache` persists those compiles in
+    the directory ``JAX_COMPILATION_CACHE_DIR`` names, or else in one
+    fixed directory of the checkout (:data:`COMPILE_CACHE_DIR`).  It is
+    independent of where tier 1 lives.
 
 Fallback contract: *any* anomaly — missing entry, torn payload, sha256
 mismatch, schema or environment skew, a deserialization error — is a MISS,
@@ -46,11 +48,14 @@ fed_serve` activates one per serve (``--progcache-dir``, default
 ``<ckpt_dir>/progcache``); any process can opt in via the
 ``REPRO_PROGCACHE_DIR`` environment variable (``REPRO_PROGCACHE=0``
 force-disables).  With no active cache the round engine's dispatch path is
-byte-for-byte the plain jitted fast path — zero added work.
+byte-for-byte the plain jitted fast path — zero added work.  The entry
+points (`repro.launch.fed_serve`, ``python -m repro.exp``,
+``chip_smoke.py``) turn on tier 2 with :func:`enable_compile_cache`.
 """
 from __future__ import annotations
 
 import collections
+import contextlib
 import dataclasses
 import functools
 import hashlib
@@ -92,14 +97,9 @@ def env_fingerprint() -> dict:
     import jax
     import jaxlib
 
-    try:
-        from jax._src.lib import xla_extension_version
-    except Exception:  # pragma: no cover - layout varies across jax versions
-        xla_extension_version = None
     return {
         "jax": jax.__version__,
         "jaxlib": jaxlib.__version__,
-        "xla_extension_version": xla_extension_version,
         "backend": jax.default_backend(),
         "device_count": jax.device_count(),
         "device_kind": jax.devices()[0].device_kind,
@@ -234,6 +234,27 @@ def _ensure_runtime_kernels() -> None:
         pass
 
 
+@contextlib.contextmanager
+def _compile_cache_off():
+    """Compile with jax's persistent compilation cache (tier 2) off.  An
+    executable that jax loaded from its own cache serializes into a
+    payload that fails at dispatch once loaded back (XLA:CPU, jax 0.9:
+    "Function ... not found"), so a tier-1 entry is always compiled
+    afresh.  jax latches whether its cache is in use at the first compile,
+    hence the resets on the way in and out."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        yield
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        cc.reset_cache()
+
+
 # ==========================================================================
 # The cache
 # ==========================================================================
@@ -333,7 +354,8 @@ class ProgramCache:
             return compiled, "hit"
         self.stats["miss"] += 1
         self.stats[why] += 1
-        compiled = lower().compile()
+        with _compile_cache_off():
+            compiled = lower().compile()
         self._store(name, key, compiled, aux)
         self.events.append({"name": name, "key": key, "status": why})
         return compiled, why
@@ -355,16 +377,12 @@ def active() -> Optional[ProgramCache]:
     return _ACTIVE
 
 
-def activate(root: str, *, persistent_compilation_cache: bool = True
-             ) -> ProgramCache:
+def activate(root: str) -> ProgramCache:
     """Activate an AOT cache rooted at ``root`` (idempotent for the same
-    directory) and, by default, point jax's persistent compilation cache
-    (tier 2) at ``<root>/xla``."""
+    directory).  Tier 2 is not touched: see `enable_compile_cache`."""
     global _ACTIVE
     if _ACTIVE is None or _ACTIVE.root != os.path.abspath(root):
         _ACTIVE = ProgramCache(root)
-    if persistent_compilation_cache:
-        enable_persistent_compilation_cache(os.path.join(_ACTIVE.root, "xla"))
     return _ACTIVE
 
 
@@ -373,28 +391,43 @@ def deactivate() -> None:
     _ACTIVE = None
 
 
-def enable_persistent_compilation_cache(path: str) -> None:
-    """Tier 2: persist every jit compile this process does (below the AOT
-    layer — partial-chunk lengths, gap-stream evals, dry-runs) into jax's
-    own on-disk compilation cache.  Thresholds are zeroed so CPU-fast
-    programs cache too (jax's defaults skip sub-second compiles)."""
-    import jax
+#: tier 2's directory when ``JAX_COMPILATION_CACHE_DIR`` is not set: one
+#: fixed path inside the checkout (git-ignored).  The cache hits only
+#: where the path stays the same, so it never names a pid, a time or a
+#: temporary directory.
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))), ".jax_cache")
 
-    os.makedirs(path, exist_ok=True)
-    jax.config.update("jax_compilation_cache_dir", path)
+
+def enable_compile_cache() -> str:
+    """Tier 2: persist every jit compile this process does (below the AOT
+    layer — problem construction, partial-chunk lengths, gap-stream
+    evals) in jax's own on-disk compilation cache, and return its
+    directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, jax has read it at import
+    and this sets no directory of its own.  Otherwise the cache goes to
+    `COMPILE_CACHE_DIR`.  Thresholds are zeroed so CPU-fast programs
+    cache too (jax's defaults skip sub-second compiles)."""
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
     jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    # jax initializes its cache AT MOST ONCE per process, latching whatever
-    # `jax_compilation_cache_dir` held at the first compile.  Serve always
-    # compiles before activation (problem/fleet construction jits), so the
-    # latch has already locked in `None` — reset it or tier 2 silently
-    # never engages.
-    try:
-        from jax._src import compilation_cache as _cc
-
-        _cc.reset_cache()
-    except Exception:  # pragma: no cover - private-module layout shifted
-        pass
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if path:
+        return path
+    os.makedirs(COMPILE_CACHE_DIR, exist_ok=True)
+    if jax.config.jax_compilation_cache_dir != COMPILE_CACHE_DIR:
+        jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+        # jax initializes its cache AT MOST ONCE per process, latching
+        # whatever `jax_compilation_cache_dir` held at the first compile;
+        # entry points compile before they get here (problem and fleet
+        # construction), so the latch holds `None` — reset it, or the
+        # cache silently never engages
+        cc.reset_cache()
+    return COMPILE_CACHE_DIR
 
 
 def from_env() -> Optional[ProgramCache]:

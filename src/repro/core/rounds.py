@@ -68,7 +68,7 @@ from __future__ import annotations
 import collections
 import dataclasses
 import functools
-from typing import Callable, Tuple
+from typing import Callable, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -204,6 +204,16 @@ class Reducer:
         """`mean` mapped over a pytree of (n_local, ...) leaves — the
         cross-client reduction for pytree coefficient streams (BL-DNN)."""
         return self.reduce_tree(tree, "mean")
+
+    def outer_mean(self, V):
+        """``W ↦ mean_i W_iᵀ V_i`` for a constant client-stacked factor V:
+        the fleet mean of per-client products from (n_local, r, d) factors
+        as one contraction over (client, r), so the (n, d, d) stack of
+        products never exists.  Call it outside the round loop: the
+        sharded backend prepares V here, once per program.  The factors
+        keep d as their minor axis: an r-wide minor axis would be padded
+        to the TPU's 128 lanes."""
+        return lambda W: jnp.einsum("nrd,nre->de", W, V) / self.n
 
     def tree_mean_presummed(self, tree, local_sums):
         """Fleet mean of client-stacked leaves given precomputed LOCAL
@@ -382,6 +392,17 @@ class ShardMapReducer(Reducer):
             out[i] = red
         return treedef.unflatten(out)
 
+    def outer_mean(self, V):
+        # exact: contract the whole fleet in one fixed order, bitwise the
+        # single-device contraction — the constant V is gathered here,
+        # once, and each call gathers only W
+        if self.exact:
+            V = self._gather(V)
+            return lambda W: jnp.einsum(
+                "nrd,nre->de", self._gather(W), V) / self.n
+        return lambda W: jax.lax.psum(
+            jnp.einsum("nrd,nre->de", W, V), self.axis) / self.n
+
     def tree_mean_presummed(self, tree, local_sums):
         if self.exact:
             return self.reduce_tree(tree, "mean")
@@ -472,6 +493,13 @@ class CohortReducer:
 
     def once(self, f: Callable, *args):
         return self.inner.once(f, *args)
+
+    def outer_mean(self, V):
+        def unsupported(W):
+            raise NotImplementedError(
+                "CohortReducer cannot take a fleet mean of per-client "
+                "products — absent clients keep no frozen sum for it")
+        return unsupported
 
     # ---- fleet reductions --------------------------------------------------
     def _mask(self, x, fill):
@@ -805,10 +833,15 @@ class CoeffLayout:
     `target_at(z)` gives the per-client coefficient target h^i(∇²f_i(z)),
     `recon(S)` maps coefficient-space updates back to (n_local, d, d)
     Hessian space, `shape` is the local coefficient-state shape, and
-    `ridge` is the analytic λI the server adds for data bases."""
+    `ridge` is the analytic λI the server adds for data bases.  In block
+    mode `recon_mean(S)` is the fleet mean of `recon(S)` taken from
+    (n, r, d) factors, without the (n, d, d) stack; in the full layout it
+    is None and callers reduce `recon(S)` with their other uplink leaves
+    in one fused reduction."""
 
     target_at: Callable
     recon: Callable
+    recon_mean: Optional[Callable]
     shape: Tuple[int, ...]
     ridge: jax.Array
 
@@ -822,9 +855,14 @@ def coeff_layout(R: Reducer, batch, basisb, x0: jax.Array,
         # d×d data Hessian is never materialized (Γ = (AV)ᵀD(AV)/m).
         AV = client_batch.basis_AV(basisb, batch)
         rb = basisb.r_max
+        Vt = jnp.swapaxes(basisb.V, 1, 2)           # (n, r, d)
+        # mean_i V_i S_i V_iᵀ as one contraction of (n, r, d) factors: the
+        # (n, d, d) reconstructions would not fit a chip at fig1-xl scale
+        outer = R.outer_mean(Vt)
         return CoeffLayout(
             target_at=lambda z: client_batch.hess_coeff_block(basisb, batch, z, AV),
             recon=lambda S: client_batch.reconstruct_block(basisb, S),
+            recon_mean=lambda S: outer(jnp.einsum("nsr,nsd->nrd", S, Vt)),
             shape=(R.n_local, rb, rb),
             ridge=lam * jnp.eye(d, dtype=x0.dtype),
         )
@@ -833,6 +871,7 @@ def coeff_layout(R: Reducer, batch, basisb, x0: jax.Array,
     return CoeffLayout(
         target_at=lambda z: client_batch.hess_coeff_target(basisb, batch, z),
         recon=basisb.reconstruct,
+        recon_mean=None,
         shape=(R.n_local, d, d),
         ridge=ridge,
     )
@@ -1185,7 +1224,6 @@ def _sharded_chunk_fns(spec, R: "ShardMapReducer", mesh, flags_key):
     everything else is replicated (per `carry_client_flags`).  The chunk
     program donates its carry argument like the vmap path; its AOT twin
     (third element) is donation-free like `_chunk_jit_aot`."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.sharding.rules import CLIENT_AXIS, client_chunk_specs
@@ -1196,12 +1234,12 @@ def _sharded_chunk_fns(spec, R: "ShardMapReducer", mesh, flags_key):
     in_specs, out_specs = client_chunk_specs(
         carry_specs,
         basis_replicated=getattr(spec, "basis_replicated", False))
-    body = shard_map(
+    body = jax.shard_map(
         functools.partial(_chunk_body, spec, R), mesh=mesh,
-        in_specs=in_specs, out_specs=out_specs, check_rep=False)
-    init = jax.jit(shard_map(
+        in_specs=in_specs, out_specs=out_specs, check_vma=False)
+    init = jax.jit(jax.shard_map(
         functools.partial(_init_body, spec, R), mesh=mesh,
-        in_specs=in_specs[:3], out_specs=carry_specs, check_rep=False))
+        in_specs=in_specs[:3], out_specs=carry_specs, check_vma=False))
     # (batch, basisb, x0, carry, ts, keys, avail) — carry is argument 3
     chunk = jax.jit(body, donate_argnums=(3,))
     chunk_aot = jax.jit(body)
@@ -1343,7 +1381,6 @@ def _sharded_cohort_chunk_fns(spec, R: "ShardMapReducer", mesh, flags_key,
     """The cohort chunk program under shard_map: the COHORT axis shards
     over the client mesh (cidx/creal shard with it; frozen fleet stats are
     replicated like the server state)."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.sharding.rules import CLIENT_AXIS, cohort_chunk_specs
@@ -1354,9 +1391,9 @@ def _sharded_cohort_chunk_fns(spec, R: "ShardMapReducer", mesh, flags_key,
     in_specs, out_specs = cohort_chunk_specs(
         carry_specs,
         basis_replicated=getattr(spec, "basis_replicated", False))
-    body = shard_map(
+    body = jax.shard_map(
         functools.partial(_cohort_chunk_body, spec, R, n_global), mesh=mesh,
-        in_specs=in_specs, out_specs=out_specs, check_rep=False)
+        in_specs=in_specs, out_specs=out_specs, check_vma=False)
     # (batch, basisb, x0, carry, ts, keys, cidx, creal, frozen) — carry is 3
     chunk = jax.jit(body, donate_argnums=(3,))
     chunk_aot = jax.jit(body)  # donation-free twin for the progcache path

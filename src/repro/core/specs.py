@@ -39,8 +39,14 @@ from typing import Callable, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
-from . import client_batch, comm
-from .bl import _psd_h_tilde, _psd_reconstruct_full, _psd_sum_matrix, proj_mu
+from . import client_batch, comm, glm
+from .bl import (
+    _psd_h_tilde,
+    _psd_reconstruct_full,
+    _psd_sum_matrix,
+    proj_mu_eig,
+    proj_mu_solve,
+)
 from .comm import FLOAT_BITS, CommLedger
 from .compressors import Compressor
 from .rounds import (
@@ -185,7 +191,8 @@ class BL1Spec(MethodSpec):
         lay = env.extra
         x0 = env.x0
         L0 = lay.target_at(x0) if self.init_exact else jnp.zeros(lay.shape, x0.dtype)
-        H0 = R.mean(lay.recon(L0)) + lay.ridge
+        H0 = (R.mean(lay.recon(L0)) if lay.recon_mean is None
+              else lay.recon_mean(L0)) + lay.ridge
         grad_w0 = global_grad(R, env.batch, x0)
         led0 = CommLedger.create(hess_up=self.init_hess_bits,
                                  basis_ship=self.basis_bits)
@@ -199,17 +206,20 @@ class BL1Spec(MethodSpec):
 
         # client-side legs: gradients + Hessian-coefficient learning, then
         # ONE fused uplink reduction for the round (gradient stack, Hessian
-        # shift reconstruction, and the bit accounting share a collective)
+        # shift reconstruction, and the bit accounting share a collective;
+        # in block mode the shift reduces apart, as (n, r, d) factors)
         k_h, k_m, k_xi = jax.random.split(key_t, 3)
         S, L_n, counts = shift_update(
             lambda delta: self.hess_comp.compress(R.client_keys(k_h), delta),
             lay.target_at(z), L, self.alpha)
-        red = R.reduce_tree(
-            {"grad_z": client_batch.grads(env.batch, z),
-             "dH": lay.recon(self.alpha * S),
-             "sbits": comm.price(self.hess_comp.wire, counts)})
+        up = {"grad_z": client_batch.grads(env.batch, z),
+              "sbits": comm.price(self.hess_comp.wire, counts)}
+        if lay.recon_mean is None:
+            up["dH"] = lay.recon(self.alpha * S)
+        red = R.reduce_tree(up)
         grad_z = red["grad_z"]
-        H_n = H + red["dH"]
+        H_n = H + (red["dH"] if lay.recon_mean is None
+                   else lay.recon_mean(self.alpha * S))
         led = led.add(grad_up=jnp.where(xi, self.grad_bits, 0.0),
                       hess_up=red["sbits"])
 
@@ -220,9 +230,9 @@ class BL1Spec(MethodSpec):
         # server model step (μ-projection + Newton solve computed once per
         # fleet, not once per shard) + compressed broadcast
         def server_step(H, grad_z, z, w, grad_w, xi):
-            Hmu = proj_mu(H, self.mu)
+            Hmu, wmu, V = proj_mu_eig(H, self.mu)
             g = jnp.where(xi, grad_z, Hmu @ (z - w) + grad_w)
-            return z - jnp.linalg.solve(Hmu, g)
+            return z - proj_mu_solve(Hmu, wmu, V, g)
 
         x_next = R.once(server_step, H, grad_z, z, w, grad_w, xi)
         v, vbits = self.model_comp(k_m, x_next - z)
@@ -286,7 +296,7 @@ class BL2Spec(MethodSpec):
         # fleet (shard 0) instead of one per shard
         red = R.reduce_tree({"H": Hi, "l": li, "g": gi})
         x_cur = R.once(
-            lambda H, l_avg, g: jnp.linalg.solve(
+            lambda H, l_avg, g: glm.spd_solve(
                 (H + H.T) / 2.0 + l_avg * I, g),
             red["H"], red["l"], red["g"])
         ys = (x_cur, led)  # gap evaluated at x_cur, outside the scan
@@ -384,7 +394,7 @@ class BL3Spec(MethodSpec):
             {"A": "mean", "C": "mean", "g1": "mean", "g2": "mean",
              "beta": "max"})
         x_cur = R.once(
-            lambda beta, A, C, g1m, g2m: jnp.linalg.solve(
+            lambda beta, A, C, g1m, g2m: glm.qr_solve(
                 beta * A - C, beta * g1m - g2m),
             red["beta"], red["A"], red["C"], red["g1"], red["g2"])
         ys = (x_cur, led)  # gap evaluated at x_cur, outside the scan
@@ -500,7 +510,7 @@ class NewtonSpec(MethodSpec):
             coef = client_batch.hess_coeff_target(env.basisb, batch, x)
             Hc = env.basisb.server_reconstruct(coef, batch.lam)
         red = R.reduce_tree({"H": Hc, "g": client_batch.grads(batch, x)})
-        x_n = R.once(lambda H, g: x - jnp.linalg.solve(H, g),
+        x_n = R.once(lambda H, g: x - glm.spd_solve(H, g),
                      red["H"], red["g"])
         return ((x_n, led.add(hess_up=self.hess_bits,
                               grad_up=self.grad_bits)),
@@ -605,8 +615,8 @@ class FedNLBAGSpec(MethodSpec):
         # aggressive q would otherwise excite (η = 1 recovers FedNL when
         # q = 1); projected + solved once per fleet (shard 0)
         z_n = R.once(
-            lambda H_n, ghat: z - self.eta * jnp.linalg.solve(
-                proj_mu(H_n, self.mu), ghat),
+            lambda H_n, ghat: z - self.eta * proj_mu_solve(
+                *proj_mu_eig(H_n, self.mu), ghat),
             H_n, red["ghat"])
         return (z_n, L_n, H_n, gtab_n, led), ys
 

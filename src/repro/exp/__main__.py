@@ -14,7 +14,10 @@ budget (smoke tests / CI) — clamped histories are truncated, so the CLI
 refuses to write them over the committed ``results/`` tree; point
 ``--out``/``--artifacts`` at a scratch directory as CI does.
 ``--progress-every`` streams (round, gap, Mbits) mid-scan for BL cells on
-the single-device backends (sharded cells report at completion).
+the single-device backends (sharded cells report at completion).  Compiles
+persist in jax's compilation cache (`repro.core.progcache.
+enable_compile_cache`: ``JAX_COMPILATION_CACHE_DIR``, or else the
+checkout's ``.jax_cache``).
 """
 from __future__ import annotations
 
@@ -61,6 +64,9 @@ def _cmd_run(args) -> int:
                   "(e.g. --out /tmp/exp-smoke --artifacts /tmp/exp-smoke/exp)",
                   file=sys.stderr)
             return 2
+    from repro.core import progcache
+
+    progcache.enable_compile_cache()
     failures = 0
     for name, exp in zip(names, exps):
         print(f"== {name}: {exp.title}")

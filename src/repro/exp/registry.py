@@ -357,10 +357,12 @@ register_experiment(Experiment(
 # Beyond the paper
 # ==========================================================================
 # fig1-xl: the fig1r1 comparison at a scale the original op-by-op code
-# cannot run — 512 clients at d=1200 (≈ 737 MB of stacked client data, a
-# 5.9 GB/round reconstruction stream) through the client-sharded shard_map
-# backend with §2.3 block-mode (n, r, r) coefficient state and the fused
-# low-memory Newton reference solver.
+# cannot run — 512 clients at d=1200 (≈ 157 MB of stacked client data)
+# through the client-sharded shard_map backend with §2.3 block-mode
+# (n, r, r) coefficient state, the shift reconstruction reduced as (n, r, d)
+# factors (a (n, d, d) stack would be 5.9 GB/round in f64) and the fused
+# low-memory Newton reference solver.  `chip_smoke.py` serves it on one
+# TPU v5e.
 _XL = ProblemSpec(seed=0, n_clients=512, m=32, d=1200, r=32, lam=1e-3,
                   newton_iters=12, solver="fused")
 

@@ -4,42 +4,51 @@ kernel (the BL-DNN rotation hot spot).
 The pytree bases (`repro.core.basis.PerLayerSVDBasis` and the structured
 DCT/Hadamard kinds) rotate every 2-D weight leaf of every client's gradient:
 ``(n, d1, d2)`` stacks hit ``Uᵀ g V`` (forward) and ``U c Vᵀ`` (backward)
-each round.  XLA's batched matmul handles this fine on CPU; on TPU the two
-products want to stay fused in VMEM — one grid step per client, both
-``jnp.dot`` contractions on the MXU without spilling the (d1, d2)
-intermediate.
+each round.  On TPU the two products stay in VMEM: the grid walks
+(client, row tile, column tile) of the output, the left product
+``A[rows] @ gᵢ`` is computed once per (client, row tile) into a VMEM
+scratch at the first column tile and reused by the others, and each step
+multiplies it by one column tile of B.  A step holds one leaf, a row tile
+of A and a column tile of B, so a 1024×1024 leaf fits the default VMEM
+budget (the whole-factor layout it replaces did not).
 
-Parity contract: the kernel computes ``(A @ gᵢ) @ B`` in the SAME
-association order as the engine's default ``A @ g @ B`` (python ``@`` is
-left-associative), and in interpret mode each grid step lowers to the same
-CPU gemms — the outputs are bitwise-identical to the XLA path (pinned by
-tests/test_basis_ship.py), so ``REPRO_BL_PALLAS=1`` swaps rotation backends
-without perturbing trajectories.
+The kernel computes ``(A @ gᵢ) @ B`` in the same association order as the
+engine's default ``A @ g @ B`` (python ``@`` is left-associative), in f32;
+tests/test_basis_ship.py compares it with the XLA path under
+``REPRO_BL_PALLAS=1``.
 """
 from __future__ import annotations
-
-import functools
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from .platform import by_platform
+
+#: output tile edge; dimensions that are not a multiple of it stay whole
+_TILE = 128
 
 
-def _transform_kernel(a_ref, g_ref, b_ref, o_ref):
-    a = a_ref[...]                       # (da, d1) left factor, whole
-    g = g_ref[0]                         # (d1, d2) one client's leaf
-    b = b_ref[...]                       # (d2, db) right factor, whole
-    t = jnp.dot(a, g, preferred_element_type=jnp.float32)
-    o_ref[0] = jnp.dot(t, b, preferred_element_type=jnp.float32)
+def _tile(dim: int) -> int:
+    return _TILE if dim % _TILE == 0 else dim
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def basis_transform(A: jax.Array, g: jax.Array, B: jax.Array, *,
-                    interpret: bool = True) -> jax.Array:
+def _transform_kernel(a_ref, g_ref, b_ref, o_ref, t_ref):
+    @pl.when(pl.program_id(2) == 0)
+    def _left():
+        t_ref[...] = jnp.dot(a_ref[...], g_ref[0],
+                             preferred_element_type=jnp.float32)
+
+    o_ref[0] = jnp.dot(t_ref[...], b_ref[...],
+                       preferred_element_type=jnp.float32)
+
+
+@jax.jit
+def basis_transform(A: jax.Array, g: jax.Array, B: jax.Array) -> jax.Array:
     """``A @ g[i] @ B`` for every client i: (da, d1) × (n, d1, d2) ×
-    (d2, db) → (n, da, db), one grid step per client with both factors
-    VMEM-resident.  f32 only — the bitwise-parity contract is pinned
-    against the f32 XLA batched matmul."""
+    (d2, db) → (n, da, db), tiled over the output (see the module
+    docstring).  f32 only."""
     if g.ndim != 3:
         raise ValueError(f"expected a client-stacked (n, d1, d2) leaf, "
                          f"got shape {g.shape}")
@@ -53,15 +62,22 @@ def basis_transform(A: jax.Array, g: jax.Array, B: jax.Array, *,
         raise ValueError(
             f"factor/leaf shape mismatch: A {A.shape} · g {g.shape} · "
             f"B {B.shape}")
-    return pl.pallas_call(
+    ta, tb = _tile(da), _tile(db)
+    # block index 0 is spelled ``grid index * 0``: a python 0 is int64
+    # under x64, which Mosaic refuses
+    call = lambda interp, A, g, B: pl.pallas_call(
         _transform_kernel,
-        grid=(n,),
+        grid=(n, da // ta, db // tb),
         in_specs=[
-            pl.BlockSpec((da, d1), lambda i: (0, 0)),
-            pl.BlockSpec((1, d1, d2), lambda i: (i, 0, 0)),
-            pl.BlockSpec((d2, db), lambda i: (0, 0)),
+            pl.BlockSpec((ta, d1), lambda i, a, b: (a, a * 0)),
+            pl.BlockSpec((1, d1, d2), lambda i, a, b: (i, i * 0, i * 0)),
+            pl.BlockSpec((d2, tb), lambda i, a, b: (b * 0, b)),
         ],
-        out_specs=pl.BlockSpec((1, da, db), lambda i: (i, 0, 0)),
+        out_specs=pl.BlockSpec((1, ta, tb), lambda i, a, b: (i, a, b)),
         out_shape=jax.ShapeDtypeStruct((n, da, db), jnp.float32),
-        interpret=interpret,
+        scratch_shapes=[pltpu.VMEM((ta, d2), jnp.float32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel", "arbitrary")),
+        interpret=interp,
     )(A, g, B)
+    return by_platform(call, A, g, B)

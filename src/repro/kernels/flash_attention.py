@@ -21,6 +21,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .platform import by_platform
+
 NEG = -1e30
 
 
@@ -64,15 +66,13 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr,
         o_ref[0] = (acc_scr[...] / denom).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("causal", "window", "bq", "bk",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("causal", "window", "bq", "bk"))
 def flash_attention(
     q: jax.Array, k: jax.Array, v: jax.Array, *,
     causal: bool = True,
     window: Optional[int] = None,
     bq: int = 128,
     bk: int = 128,
-    interpret: bool = True,
 ) -> jax.Array:
     BH, Sq, hd = q.shape
     Sk = k.shape[1]
@@ -83,7 +83,7 @@ def flash_attention(
         bk_ -= 1
     grid = (BH, Sq // bq_, Sk // bk_)
     scale = hd ** -0.5
-    return pl.pallas_call(
+    call = lambda interp, q, k, v: pl.pallas_call(
         functools.partial(_flash_kernel, scale=scale, causal=causal,
                           window=window, bq=bq_, bk=bk_, nk=grid[2]),
         grid=grid,
@@ -99,5 +99,6 @@ def flash_attention(
             pltpu.VMEM((bq_,), jnp.float32),
             pltpu.VMEM((bq_, hd), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interp,
     )(q, k, v)
+    return by_platform(call, q, k, v)

@@ -1,13 +1,10 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-`INTERPRET` defaults to True on CPU (this container) so every op runs the
-kernel body through the Pallas interpreter; on a real TPU backend set
-repro.kernels.ops.INTERPRET = False (or env REPRO_PALLAS_COMPILE=1) to lower
-to Mosaic.
+Each kernel is interpreted when its program is lowered for the CPU and
+compiled to Mosaic when it is lowered for a TPU (`platform.by_platform`).
 """
 from __future__ import annotations
 
-import os
 from typing import Optional
 
 import jax
@@ -19,11 +16,8 @@ from .ssd_scan import ssd_scan as _ssd
 from .tiled_matmul import matmul as _matmul
 from .topk_threshold import topk_threshold as _topk
 
-INTERPRET = os.environ.get("REPRO_PALLAS_COMPILE", "0") != "1"
-
-
 def matmul(a, b, out_dtype=jnp.float32, **tiles):
-    return _matmul(a, b, interpret=INTERPRET, out_dtype=out_dtype, **tiles)
+    return _matmul(a, b, out_dtype=out_dtype, **tiles)
 
 
 def basis_project(V, A, **tiles):
@@ -32,9 +26,12 @@ def basis_project(V, A, **tiles):
     Accepts a leading batch dimension (the batched BL engine's stacked-client
     layout): V (n, d, r) with A (n, d, d) → (n, r, r), mapped over the same
     tiled Pallas matmul kernel.  2-D inputs keep the original single-client
-    path.  The kernel accumulates in f32 (MXU) — use the engine's default
-    einsum path when float64 trajectories matter (CPU parity tests).
+    path.  The kernel computes in f32 (MXU), so float64 operands are cast
+    to f32 before it (a TPU kernel takes no 64-bit operands) — use the
+    engine's default einsum path when float64 trajectories matter (CPU
+    parity tests).
     """
+    V, A = V.astype(jnp.float32), A.astype(jnp.float32)
     if A.ndim == 3:
         if V.ndim == 2:
             V = jnp.broadcast_to(V, (A.shape[0],) + V.shape)
@@ -50,10 +47,9 @@ def basis_project(V, A, **tiles):
 
 def basis_transform(A, g, B):
     """A · gᵢ · B over a client-stacked (n, d1, d2) leaf — the pytree-basis
-    rotation (Uᵀ g V / U c Vᵀ), one fused grid step per client.  Interpret
-    mode is bitwise the XLA batched-matmul default (see
-    kernels/basis_transform.py's parity contract)."""
-    return _basis_transform(A, g, B, interpret=INTERPRET)
+    rotation (Uᵀ g V / U c Vᵀ), tiled over the output per client (see
+    kernels/basis_transform.py)."""
+    return _basis_transform(A, g, B)
 
 
 def glm_hessian(A, w, lam, **tiles):
@@ -68,7 +64,7 @@ def topk_compress(x, k: int):
     """Exact Top-K via the bitwise-binary-search threshold kernel (see
     topk_threshold.py) — keeps exactly min(k, numel) entries, ties broken
     by earliest index.  Returns (compressed_dense, kept_count)."""
-    out, _, kept = _topk(x, k, interpret=INTERPRET)
+    out, _, kept = _topk(x, k)
     return out, kept
 
 
@@ -82,11 +78,10 @@ def attention(q, k, v, *, causal=True, window: Optional[int] = None,
     qf = q.transpose(0, 2, 1, 3).reshape(B * H, Sq, hd)
     kf = jnp.repeat(k.transpose(0, 2, 1, 3), rep, axis=1).reshape(B * H, -1, hd)
     vf = jnp.repeat(v.transpose(0, 2, 1, 3), rep, axis=1).reshape(B * H, -1, hd)
-    o = _flash(qf, kf, vf, causal=causal, window=window, bq=bq, bk=bk,
-               interpret=INTERPRET)
+    o = _flash(qf, kf, vf, causal=causal, window=window, bq=bq, bk=bk)
     return o.reshape(B, H, Sq, hd).transpose(0, 2, 1, 3)
 
 
 def ssd(x, dt, A, Bm, Cm, chunk: int = 128):
     """Mamba2 SSD over (BH, S, hd) heads-folded layout."""
-    return _ssd(x, dt, A, Bm, Cm, chunk=chunk, interpret=INTERPRET)
+    return _ssd(x, dt, A, Bm, Cm, chunk=chunk)

@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .platform import by_platform
+
 
 def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, s_scr, *, chunk: int):
     ci = pl.program_id(1)
@@ -59,10 +61,10 @@ def _ssd_kernel(x_ref, dt_ref, a_ref, b_ref, c_ref, y_ref, s_scr, *, chunk: int)
     s_scr[...] = s_scr[...] * jnp.exp(cs[-1]) + s_new
 
 
-@functools.partial(jax.jit, static_argnames=("chunk", "interpret"))
+@functools.partial(jax.jit, static_argnames=("chunk",))
 def ssd_scan(
     x: jax.Array, dt: jax.Array, A: jax.Array, Bm: jax.Array, Cm: jax.Array,
-    *, chunk: int = 128, interpret: bool = True,
+    *, chunk: int = 128,
 ) -> jax.Array:
     BH, S, hd = x.shape
     N = Bm.shape[-1]
@@ -70,7 +72,7 @@ def ssd_scan(
     while S % c:
         c -= 1
     grid = (BH, S // c)
-    return pl.pallas_call(
+    call = lambda interp, *args: pl.pallas_call(
         functools.partial(_ssd_kernel, chunk=c),
         grid=grid,
         in_specs=[
@@ -83,5 +85,6 @@ def ssd_scan(
         out_specs=pl.BlockSpec((1, c, hd), lambda b, i: (b, i, 0)),
         out_shape=jax.ShapeDtypeStruct((BH, S, hd), x.dtype),
         scratch_shapes=[pltpu.VMEM((hd, N), jnp.float32)],
-        interpret=interpret,
-    )(x, dt, A, Bm, Cm)
+        interpret=interp,
+    )(*args)
+    return by_platform(call, x, dt, A, Bm, Cm)

@@ -6,7 +6,7 @@ accumulation in a VMEM scratch across the k-grid (TPU grids iterate the last
 dimension fastest and sequentially, so the scratch carries between k steps).
 Tile sizes default to 128/256 — MXU-aligned (multiples of 128) per the
 hardware-adaptation notes in docs/ARCHITECTURE.md (§Pallas switches).
-Validated on CPU via interpret=True.
+Interpreted on the CPU, compiled to Mosaic on a TPU (`platform.by_platform`).
 """
 from __future__ import annotations
 
@@ -16,6 +16,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from .platform import by_platform
 
 
 def _matmul_kernel(a_ref, b_ref, o_ref, acc_ref, *, nk: int):
@@ -45,7 +47,7 @@ def _pad_axis(x, ax, mult):
     return jnp.pad(x, pads)
 
 
-@functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "interpret", "out_dtype"))
+@functools.partial(jax.jit, static_argnames=("bm", "bn", "bk", "out_dtype"))
 def matmul(
     a: jax.Array,
     b: jax.Array,
@@ -53,7 +55,6 @@ def matmul(
     bm: int = 128,
     bn: int = 128,
     bk: int = 256,
-    interpret: bool = True,
     out_dtype=jnp.float32,
 ) -> jax.Array:
     """C = A @ B with (bm, bn, bk) VMEM tiles; pads to tile multiples."""
@@ -67,7 +68,7 @@ def matmul(
     _, Np = b_p.shape
     grid = (Mp // bm_, Np // bn_, Kp // bk_)
 
-    out = pl.pallas_call(
+    call = lambda interp, a_p, b_p: pl.pallas_call(
         functools.partial(_matmul_kernel, nk=grid[2]),
         grid=grid,
         in_specs=[
@@ -77,6 +78,7 @@ def matmul(
         out_specs=pl.BlockSpec((bm_, bn_), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), out_dtype),
         scratch_shapes=[pltpu.VMEM((bm_, bn_), jnp.float32)],
-        interpret=interpret,
+        interpret=interp,
     )(a_p, b_p)
+    out = by_platform(call, a_p, b_p)
     return out[:M, :N]

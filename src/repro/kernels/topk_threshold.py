@@ -31,6 +31,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from .platform import by_platform
+
 
 def keep_mask(a32: jax.Array, t: jax.Array, k: int) -> jax.Array:
     """Exactly-k selection mask from a per-row threshold, along the last axis.
@@ -39,7 +41,8 @@ def keep_mask(a32: jax.Array, t: jax.Array, k: int) -> jax.Array:
     (shape ``a32.shape[:-1] + (1,)``).  Entries strictly above t are kept;
     the tie group at t is broken by earliest index.  This is the ONE
     tie-break rule both selection backends (Pallas kernel / barrier'd XLA
-    ``top_k``) feed — identical thresholds ⇒ identical masks.
+    ``top_k``) feed — identical thresholds ⇒ identical masks
+    (`keep_mask_search` is the same rule in a form a TPU kernel lowers).
     """
     above = a32 > t
     eq = a32 == t
@@ -48,71 +51,111 @@ def keep_mask(a32: jax.Array, t: jax.Array, k: int) -> jax.Array:
     return above | (eq & (cum <= k - n_above))
 
 
-def _threshold_kernel(a_ref, t_ref, *, k: int):
-    a = a_ref[...]                                     # (1, T) f32, |values|
-    keys = jax.lax.bitcast_convert_type(a, jnp.int32)  # monotone for a ≥ 0
+def keep_mask_search(a32: jax.Array, t: jax.Array, k: int) -> jax.Array:
+    """`keep_mask` without a prefix sum, for kernels: the same mask (the
+    tie group at ``t`` broken by earliest index), found by a binary search
+    for the tie-group cut-off index instead of a cumsum, which the TPU's
+    kernel compiler cannot lower.
 
+    With ``need = k - n_above`` tied entries to keep, the cut-off is the
+    largest ``L`` in [0, T] with count(eq & index < L) ≤ need; the kept
+    ties are those with index < L — exactly the entries whose inclusive
+    tie count is ≤ need.  ⌈log₂(T+1)⌉ compare+reduce passes."""
+    T = a32.shape[-1]
+    above = a32 > t
+    eq = a32 == t
+    need = k - jnp.sum(above, axis=-1, keepdims=True, dtype=jnp.int32)
+    idx = jax.lax.broadcasted_iota(jnp.int32, a32.shape, a32.ndim - 1)
+
+    def body(i, cut):
+        cand = cut | (jnp.int32(1) << (jnp.int32(T.bit_length() - 1) - i))
+        cnt = jnp.sum(eq & (idx < cand), axis=-1, keepdims=True,
+                      dtype=jnp.int32)
+        return jnp.where((cand <= T) & (cnt <= need), cand, cut)
+
+    # int32 loop bounds and counts: under x64 a python-int bound or a
+    # default-dtype sum is int64, which Mosaic refuses
+    cut = jax.lax.fori_loop(jnp.int32(0), jnp.int32(T.bit_length()), body,
+                            jnp.zeros(need.shape, jnp.int32))
+    return above | (eq & (idx < cut))
+
+
+def _row_threshold(keys: jax.Array, k: int) -> jax.Array:
+    """Per-row k-th largest int32 key of non-negative f32 bit patterns:
+    31 greedy count passes, one per non-sign bit, high → low."""
     def body(i, t):
         cand = t | (jnp.int32(1) << (jnp.int32(30) - i))
-        cnt = jnp.sum((keys >= cand).astype(jnp.int32), axis=1, keepdims=True)
+        cnt = jnp.sum(keys >= cand, axis=1, keepdims=True, dtype=jnp.int32)
         return jnp.where(cnt >= k, cand, t)
 
-    t = jax.lax.fori_loop(0, 31, body, jnp.zeros((a.shape[0], 1), jnp.int32))
-    t_ref[...] = jax.lax.bitcast_convert_type(t, jnp.float32)
+    return jax.lax.fori_loop(jnp.int32(0), jnp.int32(31), body,
+                             jnp.zeros((keys.shape[0], 1), jnp.int32))
 
 
-@functools.partial(jax.jit, static_argnames=("k", "interpret"))
-def topk_row_threshold(a32: jax.Array, k: int, *,
-                       interpret: bool = True) -> jax.Array:
+#: rows per grid step of `topk_row_threshold` (the f32 sublane tile)
+_ROWS = 8
+#: lanes of the threshold output block (the lane tile; column 0 is read)
+_LANES = 128
+
+
+def _threshold_kernel(a_ref, t_ref, *, k: int):
+    a = a_ref[...]                                     # (8, T) f32, |values|
+    keys = jax.lax.bitcast_convert_type(a, jnp.int32)  # monotone for a ≥ 0
+    t = jax.lax.bitcast_convert_type(_row_threshold(keys, k), jnp.float32)
+    t_ref[...] = jnp.broadcast_to(t, t_ref.shape)
+
+
+@functools.partial(jax.jit, static_argnames=("k",))
+def topk_row_threshold(a32: jax.Array, k: int) -> jax.Array:
     """Per-row exact k-th largest of non-negative f32 `a32` (rows, T) →
     (rows, 1).  k is clamped to [1, T] — a threshold is undefined for an
     empty kept set; callers wanting k = 0 handle it before selection (see
-    `topk_threshold`)."""
+    `topk_threshold`).  Rows go through the grid 8 at a time (zero rows
+    pad the last block) and each block writes a lane-wide (8, 128)
+    output tile, so every block shape is a whole TPU tile.  Block index
+    maps spell 0 as ``i * 0``: a python 0 is int64 under x64, which
+    Mosaic refuses."""
     rows, T = a32.shape
     kk = max(1, min(k, T))
-    return pl.pallas_call(
+    rows_p = -(-rows // _ROWS) * _ROWS
+    a_p = jnp.pad(a32, ((0, rows_p - rows), (0, 0)))
+    call = lambda interp, a: pl.pallas_call(
         functools.partial(_threshold_kernel, k=kk),
-        grid=(rows,),
-        in_specs=[pl.BlockSpec((1, T), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, 1), jnp.float32),
-        interpret=interpret,
-    )(a32)
+        grid=(rows_p // _ROWS,),
+        in_specs=[pl.BlockSpec((_ROWS, T), lambda i: (i, i * 0))],
+        out_specs=pl.BlockSpec((_ROWS, _LANES), lambda i: (i, i * 0)),
+        out_shape=jax.ShapeDtypeStruct((rows_p, _LANES), jnp.float32),
+        interpret=interp,
+    )(a)
+    return by_platform(call, a_p)[:rows, :1]
 
 
 def _compress_sum_kernel(v_ref, out_ref, s_ref, *, k: int):
     """Fused compress-then-reduce over a whole (n, T) client stack in VMEM:
     per-row threshold search (the same 31-pass bitwise binary search as
-    `_threshold_kernel`, vectorized over rows), the shared tie-break mask,
-    the dense masked values, AND the local cross-client partial sum — one
-    pass, one kernel."""
+    `_threshold_kernel`, vectorized over rows), the shared tie-break rule
+    (`keep_mask_search`), the dense masked values, AND the local
+    cross-client partial sum — one pass, one kernel."""
     v = v_ref[...]                                     # (n, T) f32 values
     a = jnp.abs(v)
     keys = jax.lax.bitcast_convert_type(a, jnp.int32)  # monotone for a ≥ 0
-
-    def body(i, t):
-        cand = t | (jnp.int32(1) << (jnp.int32(30) - i))
-        cnt = jnp.sum((keys >= cand).astype(jnp.int32), axis=1, keepdims=True)
-        return jnp.where(cnt >= k, cand, t)
-
-    t = jax.lax.fori_loop(0, 31, body, jnp.zeros((v.shape[0], 1), jnp.int32))
-    tf = jax.lax.bitcast_convert_type(t, jnp.float32)
-    out = jnp.where(keep_mask(a, tf, k), v, jnp.zeros_like(v))
+    tf = jax.lax.bitcast_convert_type(_row_threshold(keys, k), jnp.float32)
+    out = jnp.where(keep_mask_search(a, tf, k), v, jnp.zeros_like(v))
     out_ref[...] = out
-    s_ref[...] = jnp.sum(out, axis=0)                  # client-axis partial
+    s_ref[...] = jnp.sum(out, axis=0, keepdims=True)   # client-axis partial
 
 
-@functools.partial(jax.jit, static_argnames=("k", "interpret"))
-def topk_compress_sum(v: jax.Array, k: int, *, interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("k",))
+def topk_compress_sum(v: jax.Array, k: int):
     """Exact |·|-Top-K of each row of f32 `v` (n, T) fused with the sum of
     the compressed rows: returns ``(dense (n, T), col_sum (T,))`` with
     ``col_sum == dense.sum(axis=0)``.
 
-    The threshold/tie-break path is shared with `topk_row_threshold` /
-    `keep_mask`, so ``dense`` is bitwise the two-pass selection's output
+    The threshold is `topk_row_threshold`'s and the tie-break mask is
+    `keep_mask`'s, so ``dense`` is bitwise the two-pass selection's output
     and ``col_sum`` is bitwise the XLA reduction of it — the fusion saves
-    a pass over the stack, not an ulp (pinned by
-    tests/test_pallas_parity.py).  k is clamped to [1, T] like
+    a pass over the stack, not an ulp (pinned by tests/test_kernels.py
+    and tests/test_pallas_parity.py).  k is clamped to [1, T] like
     `topk_row_threshold`."""
     if v.dtype != jnp.float32:
         raise TypeError(
@@ -120,16 +163,18 @@ def topk_compress_sum(v: jax.Array, k: int, *, interpret: bool = True):
             f"patterns, got {v.dtype}")
     n, T = v.shape
     kk = max(1, min(k, T))
-    return pl.pallas_call(
+    call = lambda interp, v: pl.pallas_call(
         functools.partial(_compress_sum_kernel, k=kk),
         out_shape=(jax.ShapeDtypeStruct((n, T), jnp.float32),
-                   jax.ShapeDtypeStruct((T,), jnp.float32)),
-        interpret=interpret,
+                   jax.ShapeDtypeStruct((1, T), jnp.float32)),
+        interpret=interp,
     )(v)
+    out, s = by_platform(call, v)
+    return out, s[0]
 
 
-@functools.partial(jax.jit, static_argnames=("k", "interpret"))
-def topk_threshold(x: jax.Array, k: int, *, interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("k",))
+def topk_threshold(x: jax.Array, k: int):
     """Global exact Top-K over a whole tensor (flattened): returns
     ``(compressed_dense, threshold, kept_count)`` with kept_count == min(k,
     numel) exactly (tie group broken by earliest index).  k ≤ 0 keeps
@@ -141,7 +186,7 @@ def topk_threshold(x: jax.Array, k: int, *, interpret: bool = True):
                 jnp.asarray(0, jnp.int32))
     kk = min(k, flat.shape[1])
     a32 = jnp.abs(flat).astype(jnp.float32)
-    t = topk_row_threshold(a32, kk, interpret=interpret)
+    t = topk_row_threshold(a32, kk)
     mask = keep_mask(a32, t, kk)
     out = jnp.where(mask, flat, jnp.zeros_like(flat))
     return out.reshape(shape), t[0, 0], jnp.sum(mask)

@@ -28,8 +28,11 @@ through the AOT program cache (`repro.core.progcache`, rooted at
 to move/disable) *before* checkpoint restore, so a restarted server
 deserializes its executables in milliseconds instead of recompiling —
 time-to-first-round and cache outcomes land in the record's ``meta``
-(``ttfr_s``, ``progcache``).  ``--metrics-out`` additionally streams an
-append-only, crash-safe JSONL line per round (round, gap, degradation
+(``ttfr_s``, ``progcache``).  From the command line every other compile
+(problem construction, gap evaluation) goes through jax's persistent
+compilation cache, which `progcache.enable_compile_cache` places where
+``JAX_COMPILATION_CACHE_DIR`` says, or else in the checkout's
+``.jax_cache``.  ``--metrics-out`` additionally streams an append-only, crash-safe JSONL line per round (round, gap, degradation
 events, per-leg ledger bits — `MetricsSink`).
 
 Because per-round PRNG keys are ``fold_in(root_key, t)`` and every fault
@@ -262,7 +265,7 @@ class MetricsSink:
 
 def _activate_progcache(ckpt_dir: str, progcache_dir: Optional[str],
                         no_progcache: bool, log):
-    """Serve-loop cache policy: ON by default, rooted next to the
+    """Serve-loop AOT cache policy: ON by default, rooted next to the
     checkpoints (``<ckpt_dir>/progcache``) so a warm restart finds both."""
     if no_progcache:
         progcache.deactivate()
@@ -433,9 +436,10 @@ def serve(*, exp_name: str, cell_name: str, seed: int = 0, chunk: int = 25,
     serve record (also written to ``result_path`` when given).
 
     ``progcache_dir`` roots the AOT program cache (default
-    ``<ckpt_dir>/progcache``; ``no_progcache=True`` disables both cache
-    tiers); ``metrics_out`` appends a crash-safe JSONL metrics line per
-    round (`MetricsSink`)."""
+    ``<ckpt_dir>/progcache``; ``no_progcache=True`` disables it); jax's
+    persistent compilation cache is the caller's to turn on
+    (`progcache.enable_compile_cache`, as `main` does).  ``metrics_out``
+    appends a crash-safe JSONL metrics line per round (`MetricsSink`)."""
     if chunk < 1:
         raise SystemExit(f"--chunk must be >= 1, got {chunk}")
     exp = get_experiment(exp_name)
@@ -628,7 +632,8 @@ def main(argv=None):
                     help="AOT program cache directory (default: "
                          "<ckpt-dir>/progcache)")
     ap.add_argument("--no-progcache", action="store_true",
-                    help="disable the program cache (always live-compile)")
+                    help="disable the AOT program cache (live-compile the "
+                         "serve programs)")
     ap.add_argument("--metrics-out", default=None,
                     help="append per-round JSONL metrics (round, gap, "
                          "events, per-leg ledger bits) to this file")
@@ -660,6 +665,7 @@ def main(argv=None):
                          "on restart)")
     args = ap.parse_args(argv)
 
+    progcache.enable_compile_cache()
     exp = get_experiment(args.exp)
     prob = build_problem(exp.problem)
     serve(exp_name=args.exp, cell_name=args.cell, seed=args.seed,

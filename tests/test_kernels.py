@@ -1,5 +1,7 @@
 """Per-kernel allclose tests against the ref.py oracles, swept over shapes
-and dtypes (interpret=True on CPU — deliverable c)."""
+and dtypes (the kernels run interpreted on the CPU; their TPU compiles are
+rehearsed in tests/test_tpu_compile.py)."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -144,8 +146,6 @@ def test_topk_threshold_sweep(shape, k):
 def test_topk_threshold_matches_xla_topk_bitwise():
     """Kernel threshold == `lax.top_k`'s k-th value bitwise — the property
     that makes REPRO_BL_PALLAS=1 selection trajectory-invariant."""
-    import jax
-
     from repro.kernels.topk_threshold import topk_row_threshold
 
     rng = np.random.default_rng(3)
@@ -154,6 +154,35 @@ def test_topk_threshold_matches_xla_topk_bitwise():
         t_kernel = np.asarray(topk_row_threshold(a, k))
         t_xla = np.asarray(jax.lax.top_k(a, k)[0][:, -1:])
         np.testing.assert_array_equal(t_kernel, t_xla)
+
+
+@pytest.mark.parametrize("T", [1, 7, 128, 333])
+def test_keep_mask_search_equals_keep_mask(T):
+    """The kernels' search tie-break selects exactly `keep_mask`'s entries
+    on tie-heavy rows (few distinct magnitudes), for every k."""
+    import jax
+
+    from repro.kernels.topk_threshold import keep_mask, keep_mask_search
+
+    rng = np.random.default_rng(T)
+    a = jnp.asarray(rng.integers(0, 4, (9, T)), jnp.float32)
+    for k in sorted({1, min(2, T), T // 2 + 1, T}):
+        t = jax.lax.top_k(a, k)[0][:, -1:]
+        np.testing.assert_array_equal(np.asarray(keep_mask_search(a, t, k)),
+                                      np.asarray(keep_mask(a, t, k)))
+
+
+def test_topk_compress_sum_breaks_ties_like_keep_mask():
+    from repro.kernels.topk_threshold import keep_mask, topk_compress_sum
+
+    rng = np.random.default_rng(5)
+    v = jnp.asarray(rng.integers(-2, 3, (13, 200)), jnp.float32)
+    for k in (1, 40, 199):
+        dense, _ = topk_compress_sum(v, k)
+        a = jnp.abs(v)
+        t = jax.lax.top_k(a, k)[0][:, -1:]
+        want = jnp.where(keep_mask(a, t, k), v, 0.0)
+        np.testing.assert_array_equal(np.asarray(dense), np.asarray(want))
 
 
 def test_topk_threshold_ties_and_zeros():

@@ -44,7 +44,7 @@ def cache_dir(tmp_path):
     (here and in other files) see the pre-subsystem fast path."""
     root = str(tmp_path / "progcache")
     rounds.clear_aot_memo()
-    progcache.activate(root, persistent_compilation_cache=False)
+    progcache.activate(root)
     yield root
     progcache.deactivate()
     rounds.clear_aot_memo()
@@ -100,7 +100,7 @@ def test_miss_then_hit_bitwise_equal_uncached(problem, tmp_path, sharded):
     ref = _uncached_reference(problem, sharded)
 
     root = str(tmp_path / "pc")
-    cache = progcache.activate(root, persistent_compilation_cache=False)
+    cache = progcache.activate(root)
     try:
         rounds.clear_aot_memo()
         missed = _serve_rounds(problem, sharded=sharded)
@@ -252,15 +252,12 @@ def test_from_env_respects_disable(monkeypatch, tmp_path):
     monkeypatch.setenv("REPRO_PROGCACHE", "0")
     assert progcache.from_env() is None
     monkeypatch.setenv("REPRO_PROGCACHE", "1")
+    xla_dir = jax.config.jax_compilation_cache_dir
     cache = progcache.from_env()
     try:
         assert cache is not None
         assert cache.root == str(tmp_path / "envpc")
+        # activating tier 1 leaves jax's compile cache (tier 2) where it was
+        assert jax.config.jax_compilation_cache_dir == xla_dir
     finally:
         progcache.deactivate()
-        # from_env also pointed jax's tier-2 cache at the tmp dir; undo so
-        # later tests don't persist compiles into a deleted directory
-        jax.config.update("jax_compilation_cache_dir", None)
-        from jax._src import compilation_cache as _cc
-
-        _cc.reset_cache()

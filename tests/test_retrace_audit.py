@@ -107,8 +107,7 @@ def test_warm_cache_dispatch_traces_nothing(problem, tmp_path):
     for both the init and the chunk program."""
     spec, batch, basisb, x0 = problem
     root = jax.random.PRNGKey(1)
-    progcache.activate(str(tmp_path / "pc"),
-                       persistent_compilation_cache=False)
+    progcache.activate(str(tmp_path / "pc"))
     try:
         rounds.clear_aot_memo()
         carry = rounds.init_serve_carry(spec, batch, basisb, x0)

@@ -266,3 +266,76 @@ def test_schema_diff_validates_ckpt_dir(tmp_path):
                        capture_output=True, text=True, timeout=120)
     assert r.returncode == 1
     assert "sha256 mismatch" in r.stdout
+
+
+# ------------------------------------------------------- compile cache / chip
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+_CACHE_SPY = r"""
+import json, sys
+import jax
+dirs = []
+_update = jax.config.update
+def update(name, value):
+    if name == "jax_compilation_cache_dir":
+        dirs.append(value)
+    return _update(name, value)
+jax.config.update = update
+from repro.launch import fed_serve
+from repro.exp.__main__ import main as exp_main
+out = sys.argv[1]
+fed_serve.main(["--exp", "fig4", "--cell", "BL2_tau_half", "--max-rounds",
+                "4", "--chunk", "2", "--ckpt-dir", out + "/ckpt"])
+assert exp_main(["run", "--fig", "fig1r1", "--cell", "BL1", "--max-steps",
+                 "2", "--out", out + "/res", "--artifacts",
+                 out + "/res/exp"]) == 0
+print("CACHE " + json.dumps({"set": dirs,
+                             "dir": jax.config.jax_compilation_cache_dir}))
+"""
+
+
+def test_compile_cache_dir_comes_from_the_environment(tmp_path):
+    """With JAX_COMPILATION_CACHE_DIR set, fed_serve and the repro.exp CLI
+    compile into that directory and no code path sets another."""
+    xla = str(tmp_path / "xla")
+    r = subprocess.run(
+        [sys.executable, "-c", _CACHE_SPY, str(tmp_path)],
+        env=dict(_ENV, JAX_COMPILATION_CACHE_DIR=xla), capture_output=True,
+        text=True, timeout=900, cwd=_REPO)
+    assert r.returncode == 0, r.stderr[-2000:]
+    [line] = [ln for ln in r.stdout.splitlines() if ln.startswith("CACHE ")]
+    got = json.loads(line[len("CACHE "):])
+    assert got == {"set": [], "dir": xla}
+    assert os.listdir(xla), "nothing was compiled into the cache"
+
+
+def test_compile_cache_defaults_to_one_fixed_checkout_dir():
+    script = ("import jax; from repro.core import progcache; "
+              "print(progcache.enable_compile_cache()); "
+              "print(jax.config.jax_compilation_cache_dir)")
+    r = subprocess.run([sys.executable, "-c", script], env=_ENV,
+                       capture_output=True, text=True, timeout=300,
+                       cwd=_REPO)
+    assert r.returncode == 0, r.stderr[-2000:]
+    want = os.path.join(_REPO, ".jax_cache")
+    assert r.stdout.split() == [want, want]
+
+
+@pytest.mark.parametrize("where", ["checkout", "alone"])
+def test_chip_smoke_refuses_without_a_tpu(tmp_path, where):
+    """On the CPU, chip_smoke.py exits non-zero with a "no TPU" message and
+    prints no result — in the checkout and as a lone file — before it
+    builds any problem."""
+    script = os.path.join(_REPO, "chip_smoke.py")
+    cwd = _REPO
+    if where == "alone":
+        cwd = str(tmp_path)
+        script = os.path.join(cwd, "chip_smoke.py")
+        with open(os.path.join(_REPO, "chip_smoke.py")) as src, \
+                open(script, "w") as dst:
+            dst.write(src.read())
+    r = subprocess.run([sys.executable, script], env=_ENV,
+                       capture_output=True, text=True, timeout=300, cwd=cwd)
+    assert r.returncode != 0
+    assert "no TPU" in r.stderr
+    assert r.stdout == ""

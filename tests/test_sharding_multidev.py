@@ -267,8 +267,8 @@ print("STREAM_SHARDED_OK")
 
 
 def _run(script):
-    # JAX_PLATFORMS=cpu: on images with an accelerator plugin an unpinned
-    # subprocess burns minutes probing for hardware before falling back
+    # JAX_PLATFORMS=cpu: the child runs on virtual CPU devices (the
+    # XLA_FLAGS in each script), never on an accelerator
     return subprocess.run([sys.executable, "-c", script], capture_output=True,
                           text=True, timeout=900,
                           env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
