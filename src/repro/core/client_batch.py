@@ -465,6 +465,7 @@ def global_loss(batch: ClientBatch, x: jax.Array) -> jax.Array:
     return jnp.mean(losses(batch, x))
 
 
+@jax.named_scope("oracle")
 def grads(batch: ClientBatch, x: jax.Array) -> jax.Array:
     """Per-client gradients (n, d) at a shared or per-client iterate."""
     xb = _per_client_x(batch, x)
@@ -490,6 +491,7 @@ def hess_data_part(batch: ClientBatch, x: jax.Array) -> jax.Array:
     return jnp.einsum("nmd,nm,nme->nde", batch.A, w, batch.A) / batch.m
 
 
+@jax.named_scope("oracle")
 def hess(batch: ClientBatch, x: jax.Array) -> jax.Array:
     """Per-client full Hessians (n, d, d)."""
     H = hess_data_part(batch, x)

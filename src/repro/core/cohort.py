@@ -45,7 +45,6 @@ bit-exactly mid-epoch or at a boundary.
 from __future__ import annotations
 
 import functools
-import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Optional
 
@@ -53,7 +52,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import client_batch, rounds
+from . import client_batch, rounds, spans
 
 #: fold_in salt separating the cohort-sampler stream from the per-round
 #: key stream (rounds use fold_in(root_key, t) with small t)
@@ -356,26 +355,26 @@ class CohortEngine:
             return
 
         def work():
-            w0 = time.perf_counter()
-            idx = self.cohort_indices(epoch)
-            pidx, real = self._padded(idx)
-            A, b = self.store.gather_data(pidx)
-            if not self.sharded:
-                # vmap backend: commit the H2D transfer on this thread too;
-                # the sharded backend re-lays arrays across the mesh at
-                # dispatch, so only the host gather is hoisted there
-                A, b = jnp.asarray(A), jnp.asarray(b)
-            return idx, pidx, real, A, b, time.perf_counter() - w0
+            with spans.span("cohort.gather", epoch=epoch) as sp:
+                idx = self.cohort_indices(epoch)
+                pidx, real = self._padded(idx)
+                A, b = self.store.gather_data(pidx)
+                if not self.sharded:
+                    # vmap backend: commit the H2D transfer on this thread
+                    # too; the sharded backend re-lays arrays across the
+                    # mesh at dispatch, so only the host gather is hoisted
+                    A, b = jnp.asarray(A), jnp.asarray(b)
+            return idx, pidx, real, A, b, sp.elapsed_s
 
         self._pf_epoch = epoch
         self._pf = self._pool.submit(work)
 
     def _fetch_epoch(self, epoch: int):
         if self._pf is not None and self._pf_epoch == epoch:
-            w0 = time.perf_counter()
-            idx, pidx, real, A, b, work_s = self._pf.result()
+            with spans.span("cohort.prefetch_wait") as sp:
+                idx, pidx, real, A, b, work_s = self._pf.result()
             self._pf = None
-            self.metrics["prefetch_wait_us"] += (time.perf_counter() - w0) * 1e6
+            self.metrics["prefetch_wait_us"] += sp.elapsed_s * 1e6
             self.metrics["prefetch_work_us"] += work_s * 1e6
             self.metrics["epochs_prefetched"] += 1
             return idx, pidx, real, A, b
@@ -541,8 +540,10 @@ class CohortEngine:
             else:
                 e = t // self.rpc
                 if self._cur is None or self._cur["epoch"] != e:
-                    self._unload_current()
-                    self._load_epoch(e)
+                    with spans.span("cohort.unload"):
+                        self._unload_current()
+                    with spans.span("cohort.load", epoch=e):
+                        self._load_epoch(e)
                 cur = self._cur
                 seg = min(end, (e + 1) * self.rpc) - t
                 carry, ys = rounds.run_cohort_chunk(

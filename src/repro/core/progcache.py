@@ -16,8 +16,9 @@ round).  This module makes start-up a *load*:
         <cache_dir>/<name>-<key>.json    manifest (schema, sha256, env, aux)
 
     ``<key>`` is a sha256 digest of the program identity: the caller's key
-    parts (method-spec fingerprint, backend scope, abstract arg
-    shapes/dtypes) plus the full :func:`env_fingerprint` — jax/jaxlib/XLA
+    parts (the package's :func:`source_digest`, method-spec fingerprint,
+    backend scope, abstract arg shapes/dtypes) plus the full
+    :func:`env_fingerprint` — jax/jaxlib/XLA
     versions, backend, device count, and the ``REPRO_BL_PALLAS`` kernel
     flag.  A warm restart deserializes the executable in tens of
     milliseconds instead of recompiling in seconds.
@@ -178,6 +179,30 @@ def _cell_contents(cell):
         return cell.cell_contents
     except ValueError:          # empty cell
         return "<empty-cell>"
+
+
+#: the `repro` package directory, whose source `source_digest` hashes
+PACKAGE_DIR = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@functools.lru_cache(maxsize=None)
+def source_digest(root: str = PACKAGE_DIR) -> str:
+    """sha256 over the Python source under ``root`` (relative paths and
+    bytes, in sorted order) — the code-version tier of a program key.  The
+    structural `fingerprint` names functions by ``module.qualname``, not by
+    their source, so without this an executable compiled from other code
+    of the same names would load as a hit."""
+    h = hashlib.sha256()
+    for dirpath, dirnames, files in os.walk(root):
+        dirnames.sort()
+        for f in sorted(files):
+            if not f.endswith(".py"):
+                continue
+            path = os.path.join(dirpath, f)
+            h.update(os.path.relpath(path, root).encode() + b"\0")
+            with open(path, "rb") as fh:
+                h.update(fh.read())
+    return h.hexdigest()[:32]
 
 
 def entry_key(key_parts: Tuple) -> str:

@@ -179,6 +179,7 @@ class Reducer:
         """Per-client PRNG keys for this shard: (n_local, 2)."""
         return self.shard(jax.random.split(key, self.n))
 
+    @jax.named_scope("reduce")
     def reduce_tree(self, tree, ops="mean"):
         """Reduce a whole uplink pytree across the fleet in one shot.
 
@@ -229,6 +230,7 @@ class Reducer:
         del local_sums
         return self.tree_mean(tree)
 
+    @jax.named_scope("server")
     def once(self, f: Callable, *args):
         """Run server-only math ``f(*args)`` once per fleet.
 
@@ -250,12 +252,15 @@ class VmapReducer(Reducer):
     def n_local(self) -> int:
         return self.n
 
+    @jax.named_scope("reduce")
     def mean(self, x):
         return jnp.mean(x, axis=0)
 
+    @jax.named_scope("reduce")
     def sum(self, x):
         return jnp.sum(x, axis=0)
 
+    @jax.named_scope("reduce")
     def max(self, x):
         return jnp.max(x, axis=0)
 
@@ -296,12 +301,15 @@ class ShardMapReducer(Reducer):
     def _gather(self, x):
         return jax.lax.all_gather(x, self.axis, axis=0, tiled=True)
 
+    @jax.named_scope("reduce")
     def mean(self, x):
         return self.reduce_tree(x, "mean")
 
+    @jax.named_scope("reduce")
     def sum(self, x):
         return self.reduce_tree(x, "sum")
 
+    @jax.named_scope("reduce")
     def max(self, x):
         return self.reduce_tree(x, "max")
 
@@ -358,6 +366,7 @@ class ShardMapReducer(Reducer):
                 off += f.shape[0]
         return out
 
+    @jax.named_scope("reduce")
     def reduce_tree(self, tree, ops="mean"):
         leaves, treedef = jax.tree_util.tree_flatten(tree)
         op_list = ([ops] * len(leaves) if isinstance(ops, str)
@@ -403,6 +412,7 @@ class ShardMapReducer(Reducer):
         return lambda W: jax.lax.psum(
             jnp.einsum("nrd,nre->de", W, V), self.axis) / self.n
 
+    @jax.named_scope("reduce")
     def tree_mean_presummed(self, tree, local_sums):
         if self.exact:
             return self.reduce_tree(tree, "mean")
@@ -418,6 +428,7 @@ class ShardMapReducer(Reducer):
                for i, coll, _ in entries]
         return treedef.unflatten(out)
 
+    @jax.named_scope("server")
     def once(self, f: Callable, *args):
         if not self.plan.server_once:
             return f(*args)
@@ -491,6 +502,7 @@ class CohortReducer:
     def client_keys(self, key):
         return self.inner.client_keys(key)
 
+    @jax.named_scope("server")
     def once(self, f: Callable, *args):
         return self.inner.once(f, *args)
 
@@ -506,6 +518,7 @@ class CohortReducer:
         r = self.real.reshape((-1,) + (1,) * (x.ndim - 1))
         return jnp.where(r, x, jnp.asarray(fill, x.dtype))
 
+    @jax.named_scope("reduce")
     def sum(self, x):
         """Fleet sum of a cohort-supported quantity (absent clients are 0 by
         construction — participation masks, bit counts)."""
@@ -522,6 +535,7 @@ class CohortReducer:
             "CohortReducer cannot take an unnamed fleet max — use "
             "reduce_tree with a named leaf and a frozen fleet stat")
 
+    @jax.named_scope("reduce")
     def reduce_tree(self, tree, ops="mean"):
         if not isinstance(tree, dict):
             raise NotImplementedError(
@@ -648,6 +662,7 @@ def refresh_due(t, rounds_per_refresh: int):
 # ==========================================================================
 # Round-step combinators
 # ==========================================================================
+@jax.named_scope("compress")
 def shift_update(compress: Callable, target: jax.Array, shift: jax.Array,
                  alpha: float) -> Tuple[jax.Array, jax.Array, jax.Array]:
     """One step of the compressed-difference shift recursion (Alg. 1 core):
@@ -698,6 +713,7 @@ def tree_shift_update(compress: Callable, target, shift,
     return S, new_shift, tuple(o[2] for o in outs)
 
 
+@jax.named_scope("compress")
 def shift_update_sum(compress_sum: Callable, target: jax.Array,
                      shift: jax.Array, alpha: float):
     """`shift_update` through a fused compress-then-reduce codec.
@@ -803,6 +819,7 @@ def xi_scalar(key: jax.Array, p: float) -> jax.Array:
     return jax.random.bernoulli(key, p, (1,))[0]
 
 
+@jax.named_scope("compress")
 def downlink_broadcast(R: Reducer, comp, key: jax.Array, z: jax.Array,
                        x_target: jax.Array, eta: float, part: jax.Array):
     """Compressed model-stream downlink to participating clients:
@@ -846,10 +863,14 @@ class CoeffLayout:
     ridge: jax.Array
 
 
+@jax.named_scope("basis")
 def coeff_layout(R: Reducer, batch, basisb, x0: jax.Array,
                  block: bool) -> CoeffLayout:
     d = batch.d
     lam = batch.lam
+    # the layout's contractions run under the basis layer's scope wherever
+    # a spec calls them
+    basis = jax.named_scope("basis")
     if block:
         # §2.3 block mode (data basis only): state stays (n, r, r) and the
         # d×d data Hessian is never materialized (Γ = (AV)ᵀD(AV)/m).
@@ -860,17 +881,17 @@ def coeff_layout(R: Reducer, batch, basisb, x0: jax.Array,
         # (n, d, d) reconstructions would not fit a chip at fig1-xl scale
         outer = R.outer_mean(Vt)
         return CoeffLayout(
-            target_at=lambda z: client_batch.hess_coeff_block(basisb, batch, z, AV),
-            recon=lambda S: client_batch.reconstruct_block(basisb, S),
-            recon_mean=lambda S: outer(jnp.einsum("nsr,nsd->nrd", S, Vt)),
+            target_at=basis(lambda z: client_batch.hess_coeff_block(basisb, batch, z, AV)),
+            recon=basis(lambda S: client_batch.reconstruct_block(basisb, S)),
+            recon_mean=basis(lambda S: outer(jnp.einsum("nsr,nsd->nrd", S, Vt))),
             shape=(R.n_local, rb, rb),
             ridge=lam * jnp.eye(d, dtype=x0.dtype),
         )
     ridge = (lam * jnp.eye(d, dtype=x0.dtype)
              if basisb.kind == "data_outer" else jnp.zeros((d, d), x0.dtype))
     return CoeffLayout(
-        target_at=lambda z: client_batch.hess_coeff_target(basisb, batch, z),
-        recon=basisb.reconstruct,
+        target_at=basis(lambda z: client_batch.hess_coeff_target(basisb, batch, z)),
+        recon=basis(basisb.reconstruct),
         recon_mean=None,
         shape=(R.n_local, d, d),
         ridge=ridge,
@@ -1188,7 +1209,8 @@ class _AotProgram:
         if prog is None:
             prog, _ = cache.load_or_compile(
                 name=self.kind,
-                key_parts=(self.kind, progcache.fingerprint(self._spec),
+                key_parts=(self.kind, progcache.source_digest(),
+                           progcache.fingerprint(self._spec),
                            progcache.fingerprint(self._scope), repr(sig)),
                 lower=lambda: self._lower(*args),
                 aux={"scope": [str(s) for s in self._scope]})

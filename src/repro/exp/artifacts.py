@@ -30,6 +30,8 @@ from typing import Optional
 
 import numpy as np
 
+from repro.core import spans
+
 from .metrics import bits_to_tol
 
 SCHEMA_VERSION = 1
@@ -204,7 +206,10 @@ def save_checkpoint(ckpt_dir: str, *, t: int, carry_leaves, streams: dict,
     engine's client store / fleet totals / frozen epoch stats
     (`CohortEngine.checkpoint_payload`); stacked serves omit it.  Keeps the
     newest ``keep`` checkpoints and prunes the rest.  Returns the manifest
-    path."""
+    path.
+
+    Spans: ``ckpt.write`` (the payload, then the manifest), ``ckpt.digest``
+    (the payload's sha256, read back from disk) and ``ckpt.prune``."""
     os.makedirs(ckpt_dir, exist_ok=True)
     base = _ckpt_base(ckpt_dir, t)
     host_state = host_state or {}
@@ -216,11 +221,14 @@ def save_checkpoint(ckpt_dir: str, *, t: int, carry_leaves, streams: dict,
         payload[f"host/{name}"] = np.asarray(arr)
     payload["root_key"] = np.asarray(root_key)
     tmp = base + ".npz.tmp"
-    with open(tmp, "wb") as f:
-        np.savez(f, **payload)
-        f.flush()
-        os.fsync(f.fileno())
-    _atomic_replace(tmp, base + ".npz")
+    with spans.span("ckpt.write"):
+        with open(tmp, "wb") as f:
+            np.savez(f, **payload)
+            f.flush()
+            os.fsync(f.fileno())
+        _atomic_replace(tmp, base + ".npz")
+    with spans.span("ckpt.digest"):
+        payload_sha256 = _sha256_file(base + ".npz")
     manifest = {
         "schema": CKPT_SCHEMA,
         "config_digest": config_digest,
@@ -231,15 +239,16 @@ def save_checkpoint(ckpt_dir: str, *, t: int, carry_leaves, streams: dict,
                          for x in carry_leaves],
         "streams": sorted(streams),
         "host_state": sorted(host_state),
-        "payload_sha256": _sha256_file(base + ".npz"),
+        "payload_sha256": payload_sha256,
     }
     tmp = base + ".json.tmp"
-    with open(tmp, "w") as f:
-        json.dump(manifest, f, indent=1)
-        f.write("\n")
-        f.flush()
-        os.fsync(f.fileno())
-    _atomic_replace(tmp, base + ".json")
+    with spans.span("ckpt.write"):
+        with open(tmp, "w") as f:
+            json.dump(manifest, f, indent=1)
+            f.write("\n")
+            f.flush()
+            os.fsync(f.fileno())
+        _atomic_replace(tmp, base + ".json")
     prune_checkpoints(ckpt_dir, keep=keep)
     return base + ".json"
 
@@ -260,12 +269,13 @@ def list_checkpoints(ckpt_dir: str):
 
 
 def prune_checkpoints(ckpt_dir: str, keep: int) -> None:
-    for t, manifest in list_checkpoints(ckpt_dir)[:-keep if keep else None]:
-        for ext in (".json", ".npz"):
-            try:
-                os.remove(_ckpt_base(ckpt_dir, t) + ext)
-            except OSError:
-                pass
+    with spans.span("ckpt.prune"):
+        for t, manifest in list_checkpoints(ckpt_dir)[:-keep if keep else None]:
+            for ext in (".json", ".npz"):
+                try:
+                    os.remove(_ckpt_base(ckpt_dir, t) + ext)
+                except OSError:
+                    pass
 
 
 def load_checkpoint(ckpt_dir: str, *, config_digest: Optional[str] = None):

@@ -118,7 +118,8 @@ def _compile_via_progcache(lowered, *key_bits):
         return lowered.compile(), None
     return cache.load_or_compile(
         name="dryrun",
-        key_parts=("dryrun",) + tuple(str(b) for b in key_bits),
+        key_parts=("dryrun", progcache.source_digest())
+        + tuple(str(b) for b in key_bits),
         lower=lambda: lowered)
 
 

@@ -35,6 +35,22 @@ compilation cache, which `progcache.enable_compile_cache` places where
 ``.jax_cache``.  ``--metrics-out`` additionally streams an append-only, crash-safe JSONL line per round (round, gap, degradation
 events, per-leg ledger bits — `MetricsSink`).
 
+The record's ``meta`` is the operator's view of where a serve call spent
+its time, with no profiler needed: ``meta.spans`` holds, per host span
+(`repro.core.spans`), the count and total seconds of those that closed
+during the call — ``serve.warm`` and ``serve.restore`` (start-up, together
+``ttfr_s`` less the first chunk), ``serve.dispatch`` (a chunk's dispatch;
+on the cohort engine its epoch swaps too), ``serve.pull`` (the chunk's
+outputs to the host, which waits for the device), ``serve.sink``
+(``--metrics-out`` lines), ``serve.checkpoint`` and inside it
+``ckpt.payload``, ``ckpt.write``, ``ckpt.digest``, ``ckpt.prune``, and the
+cohort engine's ``cohort.unload``, ``cohort.load``,
+``cohort.prefetch_wait``, ``cohort.gather``.  ``meta.retraces`` counts the
+round programs traced during the call (`rounds.trace_counts`): a warm
+restart traces none, so a non-empty entry there is a recompile.  Under a
+profiler the same spans are annotations on the trace's host plane, on the
+device events' clock.
+
 Because per-round PRNG keys are ``fold_in(root_key, t)`` and every fault
 draw is a pure function of ``(fault seed, t)``, the trajectory is invariant
 to chunk boundaries: kill -9 the process at any point, rerun the same
@@ -65,7 +81,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import batched, comm, faults, progcache, rounds
+from repro.core import batched, comm, faults, progcache, rounds, spans
 from repro.exp import artifacts
 from repro.exp.engine import (
     StreamProblem,
@@ -163,13 +179,23 @@ def _empty_streams(d: int) -> dict:
 
 
 def _append_chunk(streams: dict, ys) -> dict:
+    """The chunk's output streams appended on the host (the ``serve.pull``
+    span: it waits for the chunk's device work)."""
     xs, leds, evs = ys
     cat = lambda name, arr: np.concatenate(
         [streams[name], np.asarray(arr)], axis=0)
-    out = {"eval_x": cat("eval_x", xs), "events": cat("events", evs)}
-    for leg in comm.CommLedger.LEGS:
-        out[f"led_{leg}"] = cat(f"led_{leg}", getattr(leds, leg))
+    with spans.span("serve.pull"):
+        out = {"eval_x": cat("eval_x", xs), "events": cat("events", evs)}
+        for leg in comm.CommLedger.LEGS:
+            out[f"led_{leg}"] = cat(f"led_{leg}", getattr(leds, leg))
     return out
+
+
+def _retraces(before: dict) -> dict:
+    """{program kind: traces} since ``before`` (a `rounds.trace_counts`)."""
+    return {kind: n - before.get(kind, 0)
+            for kind, n in rounds.trace_counts().items()
+            if n > before.get(kind, 0)}
 
 
 def _restore_carry(ck: dict, template) -> object:
@@ -293,6 +319,7 @@ def _serve_cohort(exp, cell, prob: StreamProblem, *, seed: int, chunk: int,
     rerun is bit-exact here too (tests/test_cohort.py)."""
     from repro.core import cohort
 
+    spans_before, traces_before = spans.snapshot(), rounds.trace_counts()
     plan = plan if plan is not None else faults.FaultPlan(n=prob.n)
     if not plan.trivial:
         raise SystemExit(
@@ -312,31 +339,33 @@ def _serve_cohort(exp, cell, prob: StreamProblem, *, seed: int, chunk: int,
     root_key = jax.random.PRNGKey(seed)
     cache = _activate_progcache(ckpt_dir, progcache_dir, no_progcache, log)
     t0_wall = time.perf_counter()      # time-to-first-round starts here
-    eng = cohort.CohortEngine(
-        spec, prob.store, prob.x0, cohort=csize, rounds_per_cohort=rpc,
-        root_key=root_key, basis=basis,
-        sharded=backend == "cohort+sharded")
-    template = eng.carry_template()
-    # resolve the chunk program BEFORE checkpoint restore: on a warm
-    # restart the executable deserializes in milliseconds and the first
-    # round starts compile-free
-    eng.warm_programs(min(chunk, max_rounds))
-    ck = artifacts.load_checkpoint(ckpt_dir, config_digest=digest)
-    resumed_from = None
-    if ck is not None:
-        t = int(ck["t"])
-        carry = _restore_carry(ck, template)
-        eng.restore(t, carry, ck.get("host_state"))
-        streams = {name: np.asarray(ck["streams"][name])
-                   for name in _STREAMS}
-        resumed_from = t
-        log(f"[serve] {exp.name}/{cell.name}: resumed from checkpoint at "
-            f"round {t} (config {digest})")
-    else:
-        t = 0
-        streams = _empty_streams(prob.d)
-        log(f"[serve] {exp.name}/{cell.name}: fresh run (config {digest}, "
-            f"cohort {eng.cohort}/{eng.n})")
+    with spans.span("serve.warm"):
+        eng = cohort.CohortEngine(
+            spec, prob.store, prob.x0, cohort=csize, rounds_per_cohort=rpc,
+            root_key=root_key, basis=basis,
+            sharded=backend == "cohort+sharded")
+        template = eng.carry_template()
+        # resolve the chunk program BEFORE checkpoint restore: on a warm
+        # restart the executable deserializes in milliseconds and the
+        # first round starts compile-free
+        eng.warm_programs(min(chunk, max_rounds))
+    with spans.span("serve.restore"):
+        ck = artifacts.load_checkpoint(ckpt_dir, config_digest=digest)
+        resumed_from = None
+        if ck is not None:
+            t = int(ck["t"])
+            carry = _restore_carry(ck, template)
+            eng.restore(t, carry, ck.get("host_state"))
+            streams = {name: np.asarray(ck["streams"][name])
+                       for name in _STREAMS}
+            resumed_from = t
+            log(f"[serve] {exp.name}/{cell.name}: resumed from checkpoint "
+                f"at round {t} (config {digest})")
+        else:
+            t = 0
+            streams = _empty_streams(prob.d)
+            log(f"[serve] {exp.name}/{cell.name}: fresh run (config "
+                f"{digest}, cohort {eng.cohort}/{eng.n})")
 
     sink = MetricsSink(metrics_out) if metrics_out else None
     f_star = cohort.store_loss(prob.store, prob.x_star) if sink else None
@@ -345,7 +374,8 @@ def _serve_cohort(exp, cell, prob: StreamProblem, *, seed: int, chunk: int,
     try:
         while t < max_rounds:
             steps = min(chunk, max_rounds - t)
-            ys = eng.run_chunk(t, steps)
+            with spans.span("serve.dispatch", t=t, steps=steps):
+                ys = eng.run_chunk(t, steps)
             streams = _append_chunk(streams, ys)
             t += steps
             chunks_run += 1
@@ -354,21 +384,24 @@ def _serve_cohort(exp, cell, prob: StreamProblem, *, seed: int, chunk: int,
             log(f"[serve] rounds {t - steps}..{t - 1} done "
                 f"(epoch {(t - 1) // rpc})")
             if sink is not None:
-                xs_new = np.asarray(streams["eval_x"][-steps:])
-                sink.emit_chunk(
-                    range(t - steps, t),
-                    [cohort.store_loss(prob.store, x) - f_star
-                     for x in xs_new],
-                    streams["events"][-steps:],
-                    {leg: streams[f"led_{leg}"][-steps:]
-                     for leg in comm.CommLedger.LEGS})
+                with spans.span("serve.sink"):
+                    xs_new = np.asarray(streams["eval_x"][-steps:])
+                    sink.emit_chunk(
+                        range(t - steps, t),
+                        [cohort.store_loss(prob.store, x) - f_star
+                         for x in xs_new],
+                        streams["events"][-steps:],
+                        {leg: streams[f"led_{leg}"][-steps:]
+                         for leg in comm.CommLedger.LEGS})
             if crash is not None:
                 crash.maybe_crash(t - 1)
-            leaves, host_state = eng.checkpoint_payload()
-            artifacts.save_checkpoint(
-                ckpt_dir, t=t, carry_leaves=leaves, streams=streams,
-                root_key=np.asarray(root_key), config_digest=digest,
-                keep=keep, host_state=host_state)
+            with spans.span("serve.checkpoint"):
+                with spans.span("ckpt.payload"):
+                    leaves, host_state = eng.checkpoint_payload()
+                artifacts.save_checkpoint(
+                    ckpt_dir, t=t, carry_leaves=leaves, streams=streams,
+                    root_key=np.asarray(root_key), config_digest=digest,
+                    keep=keep, host_state=host_state)
     finally:
         eng.close()
 
@@ -415,6 +448,8 @@ def _serve_cohort(exp, cell, prob: StreamProblem, *, seed: int, chunk: int,
             "n_clients": eng.n,
             "prefetch_overlap": eng.prefetch_overlap,
             "prefetch": dict(eng.metrics),
+            "spans": spans.since(spans_before),
+            "retraces": _retraces(traces_before),
         },
     }
     if result_path:
@@ -442,6 +477,7 @@ def serve(*, exp_name: str, cell_name: str, seed: int = 0, chunk: int = 25,
     appends a crash-safe JSONL metrics line per round (`MetricsSink`)."""
     if chunk < 1:
         raise SystemExit(f"--chunk must be >= 1, got {chunk}")
+    spans_before, traces_before = spans.snapshot(), rounds.trace_counts()
     exp = get_experiment(exp_name)
     cell = exp.cell(cell_name)
     prob = build_problem(exp.problem)
@@ -473,30 +509,34 @@ def serve(*, exp_name: str, cell_name: str, seed: int = 0, chunk: int = 25,
     digest = artifacts.config_digest(config)
     cache = _activate_progcache(ckpt_dir, progcache_dir, no_progcache, log)
     t0_wall = time.perf_counter()      # time-to-first-round starts here
-    template = rounds.init_serve_carry(spec, batch, basisb, x0,
-                                       sharded=sharded)
-    # resolve the chunk program BEFORE checkpoint restore: on a warm
-    # restart the executable deserializes in milliseconds and the first
-    # round starts compile-free
-    rounds.warm_chunk_program(spec, batch, basisb, x0, template,
-                              min(chunk, max_rounds),
-                              jax.random.PRNGKey(seed), sharded=sharded)
-    ck = artifacts.load_checkpoint(ckpt_dir, config_digest=digest)
-    resumed_from = None
-    if ck is not None:
-        t = int(ck["t"])
-        carry = _restore_carry(ck, template)
-        streams = {name: np.asarray(ck["streams"][name]) for name in _STREAMS}
-        root_key = jnp.asarray(ck["root_key"])
-        resumed_from = t
-        log(f"[serve] {exp.name}/{cell.name}: resumed from checkpoint at "
-            f"round {t} (config {digest})")
-    else:
-        t = 0
-        carry = template
-        streams = _empty_streams(prob.d)
-        root_key = jax.random.PRNGKey(seed)
-        log(f"[serve] {exp.name}/{cell.name}: fresh run (config {digest})")
+    with spans.span("serve.warm"):
+        template = rounds.init_serve_carry(spec, batch, basisb, x0,
+                                           sharded=sharded)
+        # resolve the chunk program BEFORE checkpoint restore: on a warm
+        # restart the executable deserializes in milliseconds and the
+        # first round starts compile-free
+        rounds.warm_chunk_program(spec, batch, basisb, x0, template,
+                                  min(chunk, max_rounds),
+                                  jax.random.PRNGKey(seed), sharded=sharded)
+    with spans.span("serve.restore"):
+        ck = artifacts.load_checkpoint(ckpt_dir, config_digest=digest)
+        resumed_from = None
+        if ck is not None:
+            t = int(ck["t"])
+            carry = _restore_carry(ck, template)
+            streams = {name: np.asarray(ck["streams"][name])
+                       for name in _STREAMS}
+            root_key = jnp.asarray(ck["root_key"])
+            resumed_from = t
+            log(f"[serve] {exp.name}/{cell.name}: resumed from checkpoint "
+                f"at round {t} (config {digest})")
+        else:
+            t = 0
+            carry = template
+            streams = _empty_streams(prob.d)
+            root_key = jax.random.PRNGKey(seed)
+            log(f"[serve] {exp.name}/{cell.name}: fresh run (config "
+                f"{digest})")
 
     sink = MetricsSink(metrics_out) if metrics_out else None
     f_star = batched._f_star(batch, x_star) if sink else None
@@ -511,9 +551,10 @@ def serve(*, exp_name: str, cell_name: str, seed: int = 0, chunk: int = 25,
             avail, waited = plan.schedule(t, steps)
         # run_chunk DONATES the carry (its buffers back the next chunk's
         # output) — reassign, and only ever checkpoint the returned carry
-        carry, ys = rounds.run_chunk(spec, batch, basisb, x0, carry, t,
-                                     steps, root_key, avail=avail,
-                                     sharded=sharded)
+        with spans.span("serve.dispatch", t=t, steps=steps):
+            carry, ys = rounds.run_chunk(spec, batch, basisb, x0, carry, t,
+                                         steps, root_key, avail=avail,
+                                         sharded=sharded)
         streams = _append_chunk(streams, ys)
         t += steps
         chunks_run += 1
@@ -521,14 +562,15 @@ def serve(*, exp_name: str, cell_name: str, seed: int = 0, chunk: int = 25,
         if ttfr_s is None:
             ttfr_s = time.perf_counter() - t0_wall
         if sink is not None:
-            gaps = spec.eval_streams(
-                batch, jnp.asarray(streams["eval_x"][-steps:]),
-                f_star)["gap"]
-            sink.emit_chunk(
-                range(t - steps, t), np.asarray(gaps),
-                streams["events"][-steps:],
-                {leg: streams[f"led_{leg}"][-steps:]
-                 for leg in comm.CommLedger.LEGS})
+            with spans.span("serve.sink"):
+                gaps = spec.eval_streams(
+                    batch, jnp.asarray(streams["eval_x"][-steps:]),
+                    f_star)["gap"]
+                sink.emit_chunk(
+                    range(t - steps, t), np.asarray(gaps),
+                    streams["events"][-steps:],
+                    {leg: streams[f"led_{leg}"][-steps:]
+                     for leg in comm.CommLedger.LEGS})
         evs = streams["events"][-steps:]
         n_deg = int(np.count_nonzero(evs))
         log(f"[serve] rounds {t - steps}..{t - 1} done"
@@ -538,12 +580,14 @@ def serve(*, exp_name: str, cell_name: str, seed: int = 0, chunk: int = 25,
             # fires BEFORE the covering checkpoint: the chunk is lost and
             # the resume path must recompute it (the acceptance scenario)
             crash.maybe_crash(t - 1)
-        artifacts.save_checkpoint(
-            ckpt_dir, t=t,
-            carry_leaves=[np.asarray(leaf)
-                          for leaf in jax.tree_util.tree_leaves(carry)],
-            streams=streams, root_key=np.asarray(root_key),
-            config_digest=digest, keep=keep)
+        with spans.span("serve.checkpoint"):
+            with spans.span("ckpt.payload"):
+                leaves = [np.asarray(leaf)
+                          for leaf in jax.tree_util.tree_leaves(carry)]
+            artifacts.save_checkpoint(
+                ckpt_dir, t=t, carry_leaves=leaves, streams=streams,
+                root_key=np.asarray(root_key), config_digest=digest,
+                keep=keep)
 
     evals = spec.eval_streams(batch, jnp.asarray(streams["eval_x"]),
                               batched._f_star(batch, x_star))
@@ -580,6 +624,8 @@ def serve(*, exp_name: str, cell_name: str, seed: int = 0, chunk: int = 25,
             "runtime_s": time.perf_counter() - t0_wall,
             "ttfr_s": ttfr_s,
             "progcache": cache.summary() if cache is not None else None,
+            "spans": spans.since(spans_before),
+            "retraces": _retraces(traces_before),
         },
     }
     if result_path:

@@ -261,3 +261,42 @@ def test_from_env_respects_disable(monkeypatch, tmp_path):
         assert jax.config.jax_compilation_cache_dir == xla_dir
     finally:
         progcache.deactivate()
+
+
+# ==========================================================================
+# The package's source keys every entry
+# ==========================================================================
+def test_changed_source_digest_misses_unchanged_hits(problem, cache_dir, monkeypatch):
+    """An executable compiled from other code is never loaded: entries are
+    keyed on `progcache.source_digest`, so the same spec and shapes under
+    another digest miss, and under the same digest hit."""
+    cache = progcache.active()
+    _serve_rounds(problem)                      # populate
+    rounds.clear_aot_memo()
+    hits, misses = cache.stats["hit"], cache.stats["miss"]
+    _serve_rounds(problem)
+    assert cache.stats["hit"] > hits and cache.stats["miss"] == misses
+
+    monkeypatch.setattr(progcache, "source_digest", lambda: "0" * 32)
+    rounds.clear_aot_memo()
+    hits, misses = cache.stats["hit"], cache.stats["miss"]
+    other = _serve_rounds(problem)
+    assert cache.stats["hit"] == hits and cache.stats["miss"] > misses
+    _assert_streams_equal(other, _uncached_reference(problem, False))
+
+
+def test_source_digest_follows_the_python_source(tmp_path):
+    pkg = tmp_path / "pkg"
+    (pkg / "sub").mkdir(parents=True)
+    (pkg / "a.py").write_text("x = 1\n")
+    (pkg / "sub" / "b.py").write_text("y = 2\n")
+    (pkg / "notes.txt").write_text("not source\n")
+    digest = progcache.source_digest.__wrapped__     # uncached
+    d0 = digest(str(pkg))
+    assert digest(str(pkg)) == d0
+    (pkg / "notes.txt").write_text("edited\n")
+    assert digest(str(pkg)) == d0
+    (pkg / "sub" / "b.py").write_text("y = 3\n")
+    assert digest(str(pkg)) != d0
+    assert progcache.source_digest() == digest(progcache.PACKAGE_DIR)
+    assert os.path.basename(progcache.PACKAGE_DIR) == "repro"
