@@ -31,7 +31,7 @@ from .bl import (
     History,
     _client_hcoef,
     _server_reconstruct,
-    proj_mu_eig,
+    proj_mu_route,
     proj_mu_solve,
 )
 from .compressors import FLOAT_BITS, Compressor, RandK
@@ -181,7 +181,8 @@ def nl1(
     for _ in range(steps):
         hist.append(float(glm.global_loss(clients, x)) - f_star, up, 0.0)
         g = glm.global_grad(clients, x)
-        x = x - proj_mu_solve(*proj_mu_eig(H_from(hcoef), mu), g)
+        x = x - proj_mu_route(
+            H_from(hcoef), mu, lambda P, *f: proj_mu_solve(P, *f, g))[0]
         step_bits = 0.0
         for i, c in enumerate(clients):
             m = c.A.shape[0]

@@ -31,11 +31,12 @@ Conventions
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Optional, Sequence
+from typing import Callable, Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.scipy.linalg import cho_solve
 
 from . import glm
 from .basis import DataOuterBasis, MatrixBasis, basis_transmission_bits
@@ -45,10 +46,14 @@ _BACKENDS = ("auto", "fast", "fast+sharded", "reference")
 
 
 def _sym_eigh(S: jax.Array):
-    """(w, V) of a symmetric matrix.  A TPU takes the Jacobi algorithm: its
-    default, QDWH spectral divide-and-conquer, does not compile in f64 at
-    d=1200 within 15 minutes, Jacobi in about 30 seconds.  Every other
-    platform keeps `jnp.linalg.eigh`."""
+    """(w, V) of a symmetric matrix, for the μ-projection's eigh path.
+
+    The server step runs it only when the projection bites (sym(A) − μI has
+    no Cholesky factor) or when its program spans more than one TPU chip
+    (see `proj_mu_route`).  A TPU takes the Jacobi algorithm: its default,
+    QDWH spectral divide-and-conquer, does not compile in f64 at d=1200
+    within 15 minutes, Jacobi in about 30 seconds.  Every other platform
+    keeps `jnp.linalg.eigh`."""
     def jacobi(S):
         V, w = jax.lax.linalg.eigh(
             S, implementation=jax.lax.linalg.EighImplementation.JACOBI)
@@ -69,22 +74,71 @@ def proj_mu_eig(A: jax.Array, mu: float):
     P is formed as sym(A) + V diag(w_μ − w) Vᵀ, the same matrix in exact
     arithmetic, so it is sym(A) itself wherever the projection does not
     bite, whatever the eigensolver's accuracy (a TPU's Jacobi eigh in
-    emulated f64 is the less accurate one)."""
+    emulated f64 is the less accurate one).  The server steps call it
+    through `proj_mu_route`, which skips it where a Cholesky factor
+    certifies that the projection does not bite; the op-by-op reference
+    (`bl_reference`) calls it every round."""
     S = (A + A.T) / 2.0
     w, V = _sym_eigh(S)
     w_mu = jnp.maximum(w, mu)
     return S + (V * (w_mu - w)) @ V.T, w_mu, V
 
 
-def proj_mu_solve(P: jax.Array, w: jax.Array, V: jax.Array,
-                  b: jax.Array) -> jax.Array:
-    """P⁻¹b for ``(P, w, V) = proj_mu_eig(...)``: a solve through the
-    eigendecomposition plus one step of iterative refinement against P.
-    The server solves against [H]_μ (BL1, FedNL-BAG) take this form: the
-    factors exist already, and on a TPU an f64 Cholesky (or QR) does not
-    compile inside a client-sharded program, where this does."""
-    x = V @ ((V.T @ b) / w)
-    return x + V @ ((V.T @ (b - P @ x)) / w)
+def proj_mu_solve(P: jax.Array, *factors_and_b: jax.Array) -> jax.Array:
+    """P⁻¹b for P = [A]_μ, through the factorization P came with, plus one
+    step of iterative refinement against P, which holds either route to
+    P's own rounding whatever the factorization's accuracy.
+
+    ``proj_mu_solve(P, w, V, b)`` takes ``(P, w, V) = proj_mu_eig(...)``
+    (the TPU's Jacobi eigh converges to 1e-6 only).  ``proj_mu_solve(P, L,
+    b)`` takes a Cholesky factor L of P, which `proj_mu_route` supplies
+    where P = sym(A): triangular solves (at d=1200 on a TPU v5e the
+    refinement costs about 0.5 ms of the route's 173).  The arity picks
+    the route while the program is traced."""
+    *factors, b = factors_and_b
+    if len(factors) == 1:
+        solve = lambda r: cho_solve((factors[0], True), r)
+    else:
+        w, V = factors
+        solve = lambda r: V @ ((V.T @ r) / w)
+    x = solve(b)
+    return x + solve(b - P @ x)
+
+
+def proj_mu_route(A: jax.Array, mu: float, solve: Callable, ndev: int = 1):
+    """``(solve(P, *factors), eigh)`` for P = [A]_μ and the factors that
+    `proj_mu_solve` takes with it; ``eigh`` is a traced bool, True when
+    the round took the eigendecomposition.
+
+    If sym(A) − μI has a Cholesky factor (every diagonal entry finite and
+    positive), then sym(A) ⪰ μI and [A]_μ = sym(A) exactly: ``solve`` gets
+    sym(A) and its own Cholesky factor, and no eigendecomposition runs.
+    Otherwise the projection bites and ``solve`` gets `proj_mu_eig`'s
+    ``(P, w, V)``, as before.  The certificate is a property of the
+    matrix, read every round; there is no setting.
+
+    A TPU compiler refuses an f64 Cholesky in a program partitioned over
+    more than one chip, so a TPU program whose reducer spans ``ndev > 1``
+    devices takes the eigh path every round; every other program (one
+    chip, the CPU on any number of devices) routes by the certificate."""
+    def projected(A):
+        P, w, V = proj_mu_eig(A, mu)
+        return solve(P, w, V), jnp.asarray(True)
+
+    def certified(A):
+        S = (A + A.T) / 2.0
+        return solve(S, jnp.linalg.cholesky(S)), jnp.asarray(False)
+
+    def routed(A):
+        S = (A + A.T) / 2.0
+        C = jnp.linalg.cholesky(S - mu * jnp.eye(S.shape[0], dtype=S.dtype))
+        c = jnp.diagonal(C)
+        ok = jnp.all(jnp.isfinite(c) & (c > 0))
+        return jax.lax.cond(ok, certified, projected, A)
+
+    if ndev > 1:
+        return jax.lax.platform_dependent(A, tpu=projected, default=routed)
+    return routed(A)
 
 
 def proj_mu(A: jax.Array, mu: float) -> jax.Array:
@@ -110,8 +164,9 @@ class History:
     #: spec's ``eval_streams`` emitted besides ``"gap"``; None for GLM
     #: methods.
     metrics: Optional[Dict[str, List[float]]] = None
-    #: optional per-round degradation-event bitmasks (`rounds.EVENT_*`,
-    #: OR-combined ints) — populated by the service loop
+    #: optional per-round event bitmasks (`rounds.EVENT_*`, OR-combined
+    #: ints: degradations, and the server step's eigh route) — populated
+    #: by the service loop
     #: (`repro.launch.fed_serve`); the batch drivers leave it None.
     events: Optional[List[int]] = None
 

@@ -143,6 +143,11 @@ class Reducer:
 
     n: int
 
+    #: devices the client axis spans (the sharded backend's field): a
+    #: TPU program over more than one chip takes the server step's eigh
+    #: path (`bl.proj_mu_route`)
+    ndev = 1
+
     @property
     def n_local(self) -> int:
         raise NotImplementedError
@@ -496,6 +501,10 @@ class CohortReducer:
     def n_total(self) -> int:
         return self.n_global
 
+    @property
+    def ndev(self) -> int:
+        return self.inner.ndev
+
     def shard(self, x):
         return self.inner.shard(x)
 
@@ -624,6 +633,11 @@ EVENT_DEGRADED = 1
 EVENT_FORCED = 2
 #: no client was available at all — the round stalls (nothing participates)
 EVENT_ALL_DOWN = 4
+#: the server step took the eigendecomposition of the μ-projection
+#: (`bl.proj_mu_route`): a route, not a degradation
+EVENT_EIGH = 8
+#: the bits that mark a degraded round
+EVENT_DEGRADATION = EVENT_DEGRADED | EVENT_FORCED | EVENT_ALL_DOWN
 
 
 @dataclasses.dataclass

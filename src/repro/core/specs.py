@@ -11,7 +11,7 @@ the method's hyperparameters and three hooks consumed by the engine driver:
     the fault layer's optional availability mask), returning
     ``(carry, (eval_x, ledger, event))``: the iterate the round is
     evaluated at, the cumulative `comm.CommLedger`, and the round's int32
-    `rounds.EVENT_*` degradation bitmask (the engine turns the eval_x
+    `rounds.EVENT_*` event bitmask (the engine turns the eval_x
     stream into f(x)−f* gaps outside the scan, the ledger stream into
     per-leg bit histories, and the event stream into `History.events` on
     the service loop).
@@ -44,7 +44,7 @@ from .bl import (
     _psd_h_tilde,
     _psd_reconstruct_full,
     _psd_sum_matrix,
-    proj_mu_eig,
+    proj_mu_route,
     proj_mu_solve,
 )
 from .comm import FLOAT_BITS, CommLedger
@@ -52,6 +52,7 @@ from .compressors import Compressor
 from .rounds import (
     EVENT_ALL_DOWN,
     EVENT_DEGRADED,
+    EVENT_EIGH,
     EVENT_NONE,
     Reducer,
     ReducePlan,
@@ -202,7 +203,7 @@ class BL1Spec(MethodSpec):
         key_t = rc.key
         z, w, L, H, grad_w, xi, led = carry
         lay = env.extra
-        ys = (z, led, jnp.int32(EVENT_NONE))  # gap evaluated at z, post-scan
+        led_z = led  # the gap is evaluated at z, post-scan
 
         # client-side legs: gradients + Hessian-coefficient learning, then
         # ONE fused uplink reduction for the round (gradient stack, Hessian
@@ -230,15 +231,17 @@ class BL1Spec(MethodSpec):
         # server model step (μ-projection + Newton solve computed once per
         # fleet, not once per shard) + compressed broadcast
         def server_step(H, grad_z, z, w, grad_w, xi):
-            Hmu, wmu, V = proj_mu_eig(H, self.mu)
-            g = jnp.where(xi, grad_z, Hmu @ (z - w) + grad_w)
-            return z - proj_mu_solve(Hmu, wmu, V, g)
+            def solve(Hmu, *factors):
+                g = jnp.where(xi, grad_z, Hmu @ (z - w) + grad_w)
+                return z - proj_mu_solve(Hmu, *factors, g)
+            return proj_mu_route(H, self.mu, solve, R.ndev)
 
-        x_next = R.once(server_step, H, grad_z, z, w, grad_w, xi)
+        x_next, eigh = R.once(server_step, H, grad_z, z, w, grad_w, xi)
         v, vbits = self.model_comp(k_m, x_next - z)
         led = led.add(model_down=vbits)
         z_n = z + self.eta * v
         xi_n = xi_scalar(k_xi, self.p)
+        ys = (z, led_z, jnp.int32(EVENT_EIGH) * eigh)
         return (z_n, w_n, L_n, H_n, grad_w_n, xi_n, led), ys
 
 
@@ -595,7 +598,7 @@ class FedNLBAGSpec(MethodSpec):
                   + jnp.int32(EVENT_ALL_DOWN) * (n_av == 0)).astype(jnp.int32)
             send = send & rc.avail
         send = R.shard(send)
-        ys = (z, led, ev)  # gap evaluated at z, outside the scan
+        led_z = led  # the gap is evaluated at z, outside the scan
         gtab_n = jnp.where(send[:, None], client_batch.grads(batch, z), gtab)
 
         # FedNL Hessian-coefficient learning (same shift recursion as BL1);
@@ -614,10 +617,14 @@ class FedNLBAGSpec(MethodSpec):
         # damped Newton step: η < 1 tempers the staleness feedback loop an
         # aggressive q would otherwise excite (η = 1 recovers FedNL when
         # q = 1); projected + solved once per fleet (shard 0)
-        z_n = R.once(
-            lambda H_n, ghat: z - self.eta * proj_mu_solve(
-                *proj_mu_eig(H_n, self.mu), ghat),
-            H_n, red["ghat"])
+        def server_step(H_n, ghat):
+            return proj_mu_route(
+                H_n, self.mu,
+                lambda P, *factors: z - self.eta * proj_mu_solve(P, *factors, ghat),
+                R.ndev)
+
+        z_n, eigh = R.once(server_step, H_n, red["ghat"])
+        ys = (z, led_z, ev | jnp.int32(EVENT_EIGH) * eigh)
         return (z_n, L_n, H_n, gtab_n, led), ys
 
 

@@ -47,7 +47,12 @@ outputs to the host, which waits for the device), ``serve.sink``
 cohort engine's ``cohort.unload``, ``cohort.load``,
 ``cohort.prefetch_wait``, ``cohort.gather``.  ``meta.retraces`` counts the
 round programs traced during the call (`rounds.trace_counts`): a warm
-restart traces none, so a non-empty entry there is a recompile.  Under a
+restart traces none, so a non-empty entry there is a recompile.
+``meta.server_eigh`` counts, of the rounds the call served, those whose
+server step took the μ-projection's eigendecomposition
+(`rounds.EVENT_EIGH`; `bl.proj_mu_route` skips it where a Cholesky factor
+certifies that the projection does not bite); the record's
+``degraded_rounds`` leaves that bit out.  Under a
 profiler the same spans are annotations on the trace's host plane, on the
 device events' clock.
 
@@ -289,6 +294,20 @@ class MetricsSink:
         return rounds.StreamHook(every=every, callback=cb)
 
 
+def _degraded(events) -> int:
+    """Rounds whose event bits mark a degradation (`rounds.EVENT_EIGH`
+    marks a route, not one)."""
+    return int(np.count_nonzero(np.asarray(events) & rounds.EVENT_DEGRADATION))
+
+
+def _server_eigh(events, resumed_from: Optional[int]) -> dict:
+    """Of the rounds this call served, how many took the server step's
+    eigendecomposition (`bl.proj_mu_route`)."""
+    served = np.asarray(events)[resumed_from or 0:]
+    return {"rounds": int(np.count_nonzero(served & rounds.EVENT_EIGH)),
+            "of": int(served.size)}
+
+
 def _activate_progcache(ckpt_dir: str, progcache_dir: Optional[str],
                         no_progcache: bool, log):
     """Serve-loop AOT cache policy: ON by default, rooted next to the
@@ -433,7 +452,7 @@ def _serve_cohort(exp, cell, prob: StreamProblem, *, seed: int, chunk: int,
                      for leg in comm.CommLedger.LEGS},
             "events": hist.events,
         },
-        "degraded_rounds": int(np.count_nonzero(streams["events"])),
+        "degraded_rounds": _degraded(streams["events"]),
         "meta": {
             "backend": backend,
             "chunk": chunk,
@@ -448,6 +467,7 @@ def _serve_cohort(exp, cell, prob: StreamProblem, *, seed: int, chunk: int,
             "n_clients": eng.n,
             "prefetch_overlap": eng.prefetch_overlap,
             "prefetch": dict(eng.metrics),
+            "server_eigh": _server_eigh(streams["events"], resumed_from),
             "spans": spans.since(spans_before),
             "retraces": _retraces(traces_before),
         },
@@ -571,8 +591,7 @@ def serve(*, exp_name: str, cell_name: str, seed: int = 0, chunk: int = 25,
                     streams["events"][-steps:],
                     {leg: streams[f"led_{leg}"][-steps:]
                      for leg in comm.CommLedger.LEGS})
-        evs = streams["events"][-steps:]
-        n_deg = int(np.count_nonzero(evs))
+        n_deg = _degraded(streams["events"][-steps:])
         log(f"[serve] rounds {t - steps}..{t - 1} done"
             + (f", {n_deg} degraded" if n_deg else "")
             + (f", straggler wait {waited:.2f}s" if waited else ""))
@@ -612,7 +631,7 @@ def serve(*, exp_name: str, cell_name: str, seed: int = 0, chunk: int = 25,
                      for leg in comm.CommLedger.LEGS},
             "events": hist.events,
         },
-        "degraded_rounds": int(np.count_nonzero(streams["events"])),
+        "degraded_rounds": _degraded(streams["events"]),
         # operational facts, outside the bit-exactness contract (the CI
         # smoke job compares records with "meta" stripped)
         "meta": {
@@ -624,6 +643,7 @@ def serve(*, exp_name: str, cell_name: str, seed: int = 0, chunk: int = 25,
             "runtime_s": time.perf_counter() - t0_wall,
             "ttfr_s": ttfr_s,
             "progcache": cache.summary() if cache is not None else None,
+            "server_eigh": _server_eigh(streams["events"], resumed_from),
             "spans": spans.since(spans_before),
             "retraces": _retraces(traces_before),
         },

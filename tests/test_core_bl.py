@@ -1,6 +1,7 @@
 """System behaviour tests for BL1/BL2/BL3 and baselines against the paper's
 claims: basis exactness, FedNL equivalence, superlinear local rates, and the
 communication-cost ordering of Table 1 / Figures 1–2."""
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -243,3 +244,71 @@ def test_proj_mu_property(d, seed):
     # idempotent on feasible matrices
     P2 = bl.proj_mu(P, mu)
     np.testing.assert_allclose(np.asarray(P2), np.asarray(P), atol=1e-9)
+
+
+# ------------------------ certified μ-projection route ----------------------
+_MU = 1e-3
+
+
+def _sym_with_spectrum(d, seed, eigs):
+    """Q diag(eigs) Qᵀ for a random orthogonal Q, off symmetry by rounding
+    noise (the server's H is symmetric only up to its reductions' order)."""
+    rng = np.random.default_rng(seed)
+    Q, _ = np.linalg.qr(rng.standard_normal((d, d)))
+    A = (Q * np.asarray(eigs)) @ Q.T
+    return jnp.asarray(A + 1e-15 * rng.standard_normal((d, d))), jnp.asarray(
+        rng.standard_normal(d))
+
+
+def _route_solve(A, b, mu):
+    solve = lambda P, *factors: bl.proj_mu_solve(P, *factors, b)
+    return jax.jit(lambda A: bl.proj_mu_route(A, mu, solve))(A)
+
+
+def _eigh_solve(A, b, mu):
+    return jax.jit(lambda A: bl.proj_mu_solve(*bl.proj_mu_eig(A, mu), b))(A)
+
+
+def _numpy_proj_solve(A, b, mu):
+    S = (np.asarray(A) + np.asarray(A).T) / 2
+    w, V = np.linalg.eigh(S)
+    return V @ ((V.T @ np.asarray(b)) / np.maximum(w, mu))
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+@pytest.mark.parametrize("d", [8, 64])
+def test_certified_route_matches_eigh_solve(d, seed):
+    """sym(H) ⪰ μI with margin (fig1-xl's spectrum: λ in [3.5μ, 14μ]): the
+    route skips the eigh, and its Cholesky solve agrees with the eigh
+    solve to 1e-12."""
+    A, b = _sym_with_spectrum(d, seed, np.linspace(3.5 * _MU, 14 * _MU, d))
+    x, eigh = _route_solve(A, b, _MU)
+    assert not bool(eigh)
+    ref = _eigh_solve(A, b, _MU)
+    assert np.linalg.norm(np.asarray(x - ref)) <= 1e-12 * np.linalg.norm(np.asarray(ref))
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+@pytest.mark.parametrize("d", [8, 64])
+def test_route_takes_eigh_bitwise_where_projection_bites(d, seed):
+    """μ above λ_min: the certificate fails, the route takes the eigh
+    branch and returns bitwise what the eigh path returns."""
+    A, b = _sym_with_spectrum(d, seed, np.linspace(0.1 * _MU, 14 * _MU, d))
+    x, eigh = _route_solve(A, b, _MU)
+    assert bool(eigh)
+    np.testing.assert_array_equal(np.asarray(x), np.asarray(_eigh_solve(A, b, _MU)))
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+@pytest.mark.parametrize("d", [8, 64])
+def test_route_at_lambda_min_equal_mu(d, seed):
+    """A rank-deficient data part plus the ridge μ: λ_min(H) is μ itself,
+    and the certificate may go either way; the solve is within 1e-10 of
+    the projected reference whichever it takes."""
+    rng = np.random.default_rng(100 + seed)
+    X = rng.standard_normal((d // 2, d))
+    A = jnp.asarray(X.T @ X / d + _MU * np.eye(d))
+    b = jnp.asarray(rng.standard_normal(d))
+    x, _ = _route_solve(A, b, _MU)
+    ref = _numpy_proj_solve(A, b, _MU)
+    assert np.linalg.norm(np.asarray(x) - ref) <= 1e-10 * np.linalg.norm(ref)

@@ -200,6 +200,49 @@ def test_fed_serve_inprocess_graceful_degradation(tmp_path):
     assert rec["schema"] == artifacts.SERVE_SCHEMA
 
 
+def _bl1_experiment(mu):
+    """A small BL1 cell whose learned H^k is the exact Hessian plus λI
+    (Top-K keeps all r² coefficients, α = 1): sym(H^k) ⪰ λI = μI unless
+    ``mu`` is set above its spectrum."""
+    from repro.exp import registry
+
+    cell = registry.MethodCell(
+        "BL1", "bl1", 6, basis="data_outer",
+        hess_comp=registry.CompressorCfg(kind="topk", k=36),
+        model_comp=registry.CompressorCfg(kind="identity"),
+        params=() if mu is None else (("mu", mu),))
+    return registry.Experiment(
+        name="serve_eigh_route", figure="extra", title="server eigh route",
+        paper_ref="test", cells=(cell,),
+        problem=registry.ProblemSpec(n_clients=6, m=24, d=18, r=6, lam=1e-3,
+                                     newton_iters=12))
+
+
+@pytest.mark.parametrize("mu", [None, 0.5], ids=["certified", "projected"])
+def test_fed_serve_counts_server_eigh_rounds(tmp_path, monkeypatch, mu):
+    """``meta.server_eigh`` counts the rounds whose server step took the
+    eigendecomposition: none where H^k ⪰ μI, every one where μ sits above
+    H^k's spectrum.  The trajectory stays in the reference backend's
+    parity envelope (tests/test_batched_parity.py) either way, and the
+    route bit is not a degradation."""
+    from repro.exp import engine, registry
+    from repro.launch import fed_serve
+
+    exp = _bl1_experiment(mu)
+    monkeypatch.setitem(registry.EXPERIMENT_REGISTRY, exp.name, exp)
+    rec = fed_serve.serve(exp_name=exp.name, cell_name="BL1", chunk=4,
+                          max_rounds=6, ckpt_dir=str(tmp_path),
+                          log=lambda *a: None)
+    eigh = sum(1 for e in rec["history"]["events"] if e & rounds.EVENT_EIGH)
+    assert rec["meta"]["server_eigh"] == {"rounds": eigh, "of": 6}
+    assert eigh == (0 if mu is None else 6)
+    assert rec["degraded_rounds"] == 0
+    ref = engine.run_cell(exp, exp.cell("BL1"), engine.build_problem(exp.problem),
+                          backend="reference")
+    np.testing.assert_allclose(rec["history"]["gaps"], ref.gaps, rtol=1e-9, atol=1e-8)
+    np.testing.assert_allclose(rec["history"]["up_bits"], ref.up_bits, rtol=1e-12)
+
+
 _ENV = {"PYTHONPATH": "src", "PATH": os.environ.get("PATH", "/usr/bin:/bin"),
         "JAX_PLATFORMS": "cpu", "HOME": os.environ.get("HOME", "/tmp")}
 

@@ -116,3 +116,41 @@ def test_proj_mu_solve_compiles_in_a_sharded_program(four_chips):
     H = jax.ShapeDtypeStruct((64, 64), jnp.float64,
                              sharding=NamedSharding(four_chips, P()))
     assert "all-gather" in jax.jit(f).lower(x, H).compile().as_text()
+
+
+def _server_step(ndev=1):
+    def step(H, g):
+        return bl.proj_mu_route(
+            H, 1e-3, lambda P, *factors: bl.proj_mu_solve(P, *factors, g), ndev)
+    return step
+
+
+def test_certified_server_step_compiles_in_f64(one_chip):
+    """BL1's server step at fig1-xl width (d=1200), in float64, on one
+    chip: the Cholesky certificate, the cond, the certified branch's
+    Cholesky solve and the Jacobi eigh branch compile into one program."""
+    H = _sds((1200, 1200), jnp.float64, one_chip)
+    g = _sds((1200,), jnp.float64, one_chip)
+    compiled = _compile(_server_step(), H, g)
+    text = compiled.as_text()
+    assert "conditional" in text and "cholesky" in text
+    assert compiled.memory_analysis() is not None
+
+
+def test_server_route_takes_the_eigh_in_a_sharded_program(four_chips):
+    """A reducer over four chips routes the server step to the eigh path,
+    which compiles there (an f64 Cholesky would be refused)."""
+    def body(x, H):
+        def step(c, _):
+            g = jax.lax.all_gather(c, "clients", axis=0, tiled=True).sum(0)
+            x_new, _ = _server_step(ndev=4)(H, g)
+            return c + x_new[None, :], None
+        return jax.lax.scan(step, x, None, length=2)[0]
+
+    f = jax.shard_map(body, mesh=four_chips, in_specs=(P("clients"), P()),
+                      out_specs=P("clients"), check_vma=False)
+    x = jax.ShapeDtypeStruct((8, 64), jnp.float64,
+                             sharding=NamedSharding(four_chips, P("clients")))
+    H = jax.ShapeDtypeStruct((64, 64), jnp.float64,
+                             sharding=NamedSharding(four_chips, P()))
+    assert "all-gather" in jax.jit(f).lower(x, H).compile().as_text()
