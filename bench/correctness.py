@@ -17,6 +17,11 @@ fewer):
 * ``change_rel``: the same for the change after T rounds, z_T − z_0;
 * ``bits``: the largest absolute difference, over every leg and every round
   recorded, between the ledger's cumulative bits and the reference's.
+
+The norms need the iterates of rounds 0, 1 and T alone, and the job's
+adapter and the reference hand over just those (``{0: z_0, 1: z_1, T:
+z_T}``), so a job whose iterate is a large pytree keeps three flat vectors
+on the host, not T + 1.
 """
 from __future__ import annotations
 
@@ -31,14 +36,18 @@ def compared_rounds(config: dict, served: int) -> int:
     return min(int(config["compare_rounds"]), served)
 
 
-def numbers(gaps, legs: dict, iterates, ref: dict, rounds: int) -> dict:
-    """Program outputs (serve-record gaps and legs, checkpointed iterates)
-    against a reference run of at least ``rounds`` rounds."""
+def numbers(gaps, legs: dict, iterates: dict, ref: dict, rounds: int) -> dict:
+    """Program outputs (serve-record gaps and legs, the checkpointed
+    iterates ``{0: z_0, 1: z_1, rounds: z_rounds}``) against a reference
+    run of at least ``rounds`` rounds, whose ``iterates`` is such a mapping
+    too.  A job with no known optimum reports ``f_star`` 0: its gaps are
+    then losses, and ``gap_rel`` is the relative error of the loss."""
     g, gr = np.asarray(gaps, np.float64), np.asarray(ref["gaps"], np.float64)
-    z, zr = np.asarray(iterates, np.float64), np.asarray(ref["iterates"], np.float64)
-    if rounds < 1 or len(g) < rounds + 1 or len(z) < rounds + 1 or len(gr) < rounds + 1:
+    if rounds < 1 or len(g) < rounds + 1 or len(gr) < rounds + 1:
         raise ValueError(f"the job served {len(g) - 1} rounds and the reference "
                          f"{len(gr) - 1}; the check compares {rounds}")
+    z = {t: np.asarray(iterates[t], np.float64) for t in (0, 1, rounds)}
+    zr = {t: np.asarray(ref["iterates"][t], np.float64) for t in (0, 1, rounds)}
     t = slice(1, rounds + 1)
     floor = GAP_FLOOR * abs(ref["f_star"])
     gap_rel = float(np.max(np.abs(g[t] - gr[t]) / np.maximum(np.abs(gr[t]), floor)))

@@ -21,5 +21,7 @@ def terms(problem: dict, cell: dict) -> dict:
     }
 
 
-def per_round(problem: dict, cell: dict) -> float:
+def per_round(problem: dict, cell: dict, records) -> float:
+    """FLOPs of a round; BL2's server step has one route, whatever the
+    serve ``records`` say."""
     return float(sum(terms(problem, cell).values()))

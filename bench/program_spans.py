@@ -13,7 +13,8 @@ Two kinds of marks, on one clock:
 
 Each reduction returns None where the trace holds no such span or scope (a
 program that does not mark itself), so a reader built on it reports
-nothing rather than zero.
+nothing rather than zero.  The program's counters come with its serve
+records, not the trace (`server_eigh_share`).
 """
 from __future__ import annotations
 
@@ -59,6 +60,30 @@ def per_checkpoint_ms(trace, window, name: str) -> Optional[float]:
     if not checkpoints or not parts:
         return None
     return _total_ns(parts) / 1e6 / len(checkpoints)
+
+
+def per_span_ms(trace, window, name: str) -> Optional[float]:
+    """Mean time (ms) of the spans ``name`` that start in the window; None
+    without one."""
+    if trace is None or window is None:
+        return None
+    found = program_spans(trace, *window, name=name)
+    if not found:
+        return None
+    return _total_ns(found) / 1e6 / len(found)
+
+
+def server_eigh_share(records) -> Optional[float]:
+    """Share of the rounds the serve calls served whose server step took
+    the μ-projection's eigendecomposition (`bl.proj_mu_route`): the records'
+    own ``meta.server_eigh`` counts; None where no record keeps one."""
+    took = served = 0
+    for rec in records:
+        count = rec.get("meta", {}).get("server_eigh")
+        if count is not None:
+            took += count["rounds"]
+            served += count["of"]
+    return took / served if served > 0 else None
 
 
 def cohort_swap_ms(trace, window) -> Optional[float]:

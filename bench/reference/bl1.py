@@ -99,8 +99,9 @@ def topk(delta, k: int):
 
 def run(seed: int, problem: dict, cell: dict, rounds: int, *,
         dtype=np.float64, fault: str | None = None) -> dict:
-    """BL1 for ``rounds`` rounds; returns the iterates z_0..z_rounds, the
-    gaps f(z_t) − f*, and the per-leg cumulative bits before each round.
+    """BL1 for ``rounds`` rounds; returns the iterates ``{0: z_0, 1: z_1,
+    rounds: z_rounds}``, the gaps f(z_t) − f* of every round, and the
+    per-leg cumulative bits before each round.
     ``seed`` draws the fleet; the rounds draw nothing (p = 1, Top-K and the
     identity downlink are deterministic).
 
@@ -174,4 +175,5 @@ def run(seed: int, problem: dict, cell: dict, rounds: int, *,
             "basis_ship": np.full_like(t, d * rb * FLOAT_BITS),
         }
 
-    return {"iterates": zs, "gaps": gaps, "legs": legs, "f_star": float(f_star)}
+    return {"iterates": {t: zs[t] for t in (0, 1, rounds)}, "gaps": gaps, "legs": legs,
+            "f_star": float(f_star)}

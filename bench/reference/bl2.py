@@ -140,9 +140,10 @@ class Sampler:
 
 def run(seed: int, problem: dict, cell: dict, rounds: int, *,
         dtype=np.float64, fault: str | None = None) -> dict:
-    """BL2 for ``rounds`` rounds; returns the server's iterates x_0..x_rounds
-    (x_t is solved at the start of round t), the gaps f(x_t) − f*, and the
-    per-leg cumulative bits before each round.
+    """BL2 for ``rounds`` rounds; returns the server's iterates ``{0: x_0,
+    1: x_1, rounds: x_rounds}`` (x_t is solved at the start of round t),
+    the gaps f(x_t) − f* of every round, and the per-leg cumulative bits
+    before each round.
 
     ``seed`` draws the fleet and is the serve loop's root PRNG seed, which
     draws the cohorts and the participants.
@@ -226,4 +227,5 @@ def run(seed: int, problem: dict, cell: dict, rounds: int, *,
             "basis_ship": np.zeros_like(c),
         }
 
-    return {"iterates": xs, "gaps": gaps, "legs": legs, "f_star": float(f_star)}
+    return {"iterates": {t: xs[t] for t in (0, 1, rounds)}, "gaps": gaps, "legs": legs,
+            "f_star": float(f_star)}

@@ -18,6 +18,22 @@ configuration's; they reach into the window) and its bit ledger with the
 configuration's plain reference (``bench/reference``) and prints each number
 beside its limit.
 
+A configuration names its job's kind under ``"job"`` (default ``"glm"``).
+A new kind of job joins the benchmark as files alone, beside its
+configuration file and its BENCHMARK.json entries:
+
+* ``bench/jobs/<kind>.py``, the adapter `system.Served` loads:
+  ``experiment(config, seed)``, ``build(exp)``,
+  ``warm_closing(served, totals, chunk)`` and ``iterates(served, rounds)``
+  (``jobs/glm.py`` says what each does);
+* ``bench/reference/<name>.py``: ``run(seed, problem, cell, rounds, *,
+  dtype, fault)`` → ``iterates`` (``{0, 1, rounds}``), ``gaps`` of every
+  round, ``legs(t)`` and ``f_star`` (0 where no optimum is known: the gaps
+  are then losses);
+* ``bench/flops/<name>.py``: ``per_round(problem, cell, records)``, the
+  FLOPs a round needs, or None where the serve records do not say which
+  route the rounds took.
+
 The last line of standard output is one JSON object with ``correct``,
 ``attempted``, ``failed``, ``metrics``, ``device`` (and ``breakdown`` when
 traced) and, last, ``checks``.  Without a TPU, or with fewer chips than the
@@ -173,7 +189,8 @@ def execute(cell: dict, seed: int, seconds: float, trace: bool, *,
     clock = CompileClock()
     served = system.Served(
         config, seed, ckpt_dir=os.path.join(cache_dir, "runs", wl["name"]),
-        progcache_dir=os.path.join(cache_dir, "progcache", config["name"]), log=log)
+        progcache_dir=os.path.join(cache_dir, "progcache", config["name"]),
+        job=_module("jobs", config.get("job", "glm")), log=log)
     trace_dir = os.path.join(cache_dir, "trace", wl["name"]) if trace else None
     window = Window(trace_dir)
     marks = {}
@@ -195,10 +212,13 @@ def execute(cell: dict, seed: int, seconds: float, trace: bool, *,
             f"{clock.describe(window_clock, marks['setup_clock'])}")
         for rec in outcome.records[-1:]:
             log(f"serve meta: {json.dumps(rec['meta'], default=str)}")
-        iterates = served.iterates()
+        last = outcome.records[-1]["history"]
+        # a record's history holds z_0.. of every round served, the newest
+        # round's own update not among them
+        rounds = correctness.compared_rounds(config, len(last["gaps"]) - 1)
+        iterates = served.iterates(rounds)
     finally:
         served.close()
-    last = outcome.records[-1]["history"]
 
     result = {"correct": False, "attempted": outcome.attempted,
               "failed": outcome.failed, "metrics": {}, "device": {
@@ -224,7 +244,7 @@ def execute(cell: dict, seed: int, seconds: float, trace: bool, *,
             records=outcome.records,
             window_s=outcome.window_s,
             flops_per_round=_module("flops", config["flops"]).per_round(
-                config["problem"], config["cell"]),
+                config["problem"], config["cell"], outcome.records),
             peak=peaks.peaks(dev.device_kind))
         for m in cell["per_layer"]:
             value = _module("metrics", m["name"]).read(view)
@@ -245,7 +265,6 @@ def execute(cell: dict, seed: int, seconds: float, trace: bool, *,
 
     reference = _module("reference", config["reference"])
     t_ref = time.perf_counter()
-    rounds = correctness.compared_rounds(config, len(iterates) - 1)
     ref = reference.run(seed, config["problem"], config["cell"], rounds)
     values = correctness.numbers(last["gaps"], last["legs"], iterates, ref, rounds)
     correct, checks = correctness.judge(values, config["limits"])

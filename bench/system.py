@@ -1,17 +1,15 @@
 """The benchmark's one handle on the system under test: the served path,
 `repro.launch.fed_serve.serve`, on a registered copy of the configuration's
-experiment.  The run's seed draws the fleet (the problem's data) and is the
-serve loop's root PRNG seed, as `fed_serve --seed` is."""
+experiment, which the job adapter of the configuration's kind builds
+(``bench/jobs``).  The run's seed draws the problem's data and is the serve
+loop's root PRNG seed, as `fed_serve --seed` is."""
 from __future__ import annotations
 
 import dataclasses
-import glob
 import os
 import re
 import shutil
 import sys
-
-import numpy as np
 
 
 #: the serve loop's report that a chunk's rounds are done
@@ -27,56 +25,33 @@ def import_program(root: str) -> None:
 @dataclasses.dataclass
 class Served:
     """One served job: its experiment, checkpoint and program-cache
-    directories, and the serve calls the traffic makes on it."""
+    directories, and the serve calls the traffic makes on it.  ``job`` is
+    the adapter of the configuration's kind (``bench/jobs/<kind>.py``),
+    which knows how the job is built and what its checkpoints hold."""
 
     config: dict
     seed: int
     ckpt_dir: str
     progcache_dir: str
+    job: object
     log: object = None
 
     def __post_init__(self):
-        from repro.exp import registry
-
-        p, c = self.config["problem"], self.config["cell"]
-        self.exp_name = f"bench.{self.config['name']}.{self.seed}"
-        self.cell_name = c["name"]
-        comp = lambda cfg: None if cfg is None else registry.CompressorCfg(**cfg)
-        exp = registry.Experiment(
-            name=self.exp_name, figure="extra",
-            title=f"benchmark copy of {self.config['registry']}",
-            paper_ref=self.config["source"],
-            problem=registry.ProblemSpec(**p, seed=self.seed),
-            cells=(registry.MethodCell(
-                c["name"], c["method"], 0, basis=c.get("basis"),
-                hess_comp=comp(c.get("hess_comp")),
-                model_comp=comp(c.get("model_comp")),
-                params=tuple(sorted(c.get("params", {}).items())),
-                backend=c["backend"]),),
-            tags=("bench",))
-        known = registry.EXPERIMENT_REGISTRY.get(self.exp_name)
-        if known is None:
-            registry.register_experiment(exp)
-        elif known != exp:
-            raise ValueError(f"{self.exp_name} is registered with another configuration")
-        self.exp = exp
+        self.exp = self.job.experiment(self.config, self.seed)
         shutil.rmtree(self.ckpt_dir, ignore_errors=True)
         os.makedirs(self.ckpt_dir)
         os.makedirs(self.progcache_dir, exist_ok=True)
 
     def build(self):
-        """The problem (data, bases' inputs, reference optimum x*); memoized
-        by the program, so every later serve call reuses it."""
-        from repro.exp import build_problem
-
-        prob = build_problem(self.exp.problem)
-        np.asarray(prob.x_star)
-        return prob
+        """The job's problem; memoized by the program, so every later serve
+        call reuses it."""
+        return self.job.build(self.exp)
 
     def serve(self, max_rounds: int, chunk: int, on_chunk=None) -> dict:
-        """Serve the job to ``max_rounds``; ``on_chunk(t)`` is
-        called on the serve loop's report that its rounds up to t are done
-        (before that chunk's checkpoint)."""
+        """Serve the job to ``max_rounds``, resuming from the newest
+        checkpoint if there is one; ``on_chunk(t)`` is called on the serve
+        loop's report that its rounds up to t are done (before that chunk's
+        checkpoint)."""
         from repro.launch import fed_serve
 
         def log(msg, *rest):
@@ -87,44 +62,27 @@ class Served:
                 on_chunk(int(m.group(1)) + 1)
 
         return fed_serve.serve(
-            exp_name=self.exp_name, cell_name=self.cell_name, seed=self.seed,
+            exp_name=self.exp.name, cell_name=self.exp.cells[0].name, seed=self.seed,
             chunk=chunk, max_rounds=max_rounds, ckpt_dir=self.ckpt_dir,
             progcache_dir=self.progcache_dir, log=log)
 
-    def warm_closing(self, total: int, chunk: int) -> None:
-        """Compile what the window's serve call computes besides its chunks,
-        for the history length ``total`` it ends with: the rounds' index
-        range of a chunk that does not start at round 0, the closing gap
-        evaluation (on the device for the stacked engine; the cohort engine
-        evaluates on the host) and the ledger's uplink total."""
-        import jax.numpy as jnp
+    def forget_programs(self) -> None:
+        """Drop the serve programs this process holds, so that the next
+        serve call loads them from the program cache, as a restarted
+        process does."""
+        from repro.core import rounds
 
-        from repro.core import client_batch, comm
-        from repro.exp.engine import StreamProblem
-        from repro.launch import fed_serve
+        rounds.clear_aot_memo()
 
-        prob = self.build()
-        np.asarray(jnp.arange(chunk, 2 * chunk))
-        stacked = not isinstance(prob, StreamProblem)
-        if stacked:
-            spec, batch, _ = fed_serve.build_setup(
-                self.exp, self.exp.cell(self.cell_name), prob)
-            f_star = client_batch.global_loss(batch, prob.x_star)
-        zeros = np.zeros(total)
-        np.asarray(comm.CommLedger(*(jnp.asarray(zeros)
-                                     for _ in comm.CommLedger.LEGS)).uplink)
-        if stacked:
-            np.asarray(spec.eval_streams(
-                batch, jnp.zeros((total, prob.d), prob.x0.dtype), f_star)["gap"])
+    def warm_closing(self, totals, chunk: int) -> None:
+        """Compile what a serve call computes besides its chunks, for each
+        history length in ``totals`` that a call ends with."""
+        self.job.warm_closing(self, totals, chunk)
 
-    def iterates(self) -> np.ndarray:
-        """The evaluation iterates of every round served so far, as the
-        newest checkpoint holds them."""
-        paths = sorted(glob.glob(os.path.join(self.ckpt_dir, "ckpt-*.npz")))
-        if not paths:
-            raise FileNotFoundError(f"no checkpoint in {self.ckpt_dir}")
-        with np.load(paths[-1]) as z:
-            return np.asarray(z["stream/eval_x"], np.float64)
+    def iterates(self, rounds: int) -> dict:
+        """``{0: z_0, 1: z_1, rounds: z_rounds}``, the evaluation iterates
+        the newest checkpoint holds (flat float64 vectors)."""
+        return self.job.iterates(self, rounds)
 
     def close(self) -> None:
         shutil.rmtree(self.ckpt_dir, ignore_errors=True)

@@ -23,7 +23,10 @@ TINY = {
 # again, so what a cohort's scatter wrote is read back
 REVISITED = ({"n_clients": 256, "m": 8, "d": 8, "r": 8}, {"k": 16},
              {"tau": 64, "cohort": 32, "rounds_per_cohort": 2})
-WORKLOADS = ["xl-bl1.steady", "xxl-bl2.stream"]
+WORKLOADS = ["xl-bl1.steady", "xxl-bl2.stream", "xl-bl1.restart"]
+#: run length (s) against the cell's 45: three restarts, so that the check
+#: crosses them; one chunk elsewhere
+SECONDS = {"xl-bl1.restart": 12.0}
 
 
 def _cell(workload, sizes=None):
@@ -99,7 +102,7 @@ FAULTS = {"frozen_step": _frozen_step, "half_batch": _half_batch,
           "altered_answer": _altered_answer}
 
 
-def _execute(cell, tmp_path, seed):
+def _execute(cell, tmp_path, seed, seconds=1.0):
     import jax
 
     from repro.core import progcache, rounds
@@ -107,24 +110,68 @@ def _execute(cell, tmp_path, seed):
     jax.clear_caches()
     rounds.clear_aot_memo()
     progcache.deactivate()
-    return run.execute(cell, seed, 1.0, False, cache_dir=str(tmp_path), log=lambda *a: None)
+    return run.execute(cell, seed, seconds, False, cache_dir=str(tmp_path), log=lambda *a: None)
 
 
 @pytest.mark.parametrize("workload", WORKLOADS)
 def test_sound_run_is_correct(workload, tmp_path):
-    result, checks = _execute(_cell(workload), tmp_path, 2 ** 31 + 5)
+    result, checks = _execute(_cell(workload), tmp_path, 2 ** 31 + 5, SECONDS.get(workload, 1.0))
     assert result["correct"], checks
     assert list(result)[-1] == "checks"
     assert result["failed"] == 0 and result["attempted"] >= 3
 
 
-@pytest.mark.parametrize("workload", ["xl-bl1.steady", "xxl-bl2.stream"])
+@pytest.mark.parametrize("workload", WORKLOADS)
 @pytest.mark.parametrize("fault", sorted(FAULTS))
 def test_planted_fault_is_not_correct(fault, workload, tmp_path, monkeypatch):
     cell = _cell(workload)
     FAULTS[fault](monkeypatch, cell["config"]["cell"]["method"])
-    result, checks = _execute(cell, tmp_path, 2 ** 31 + 5)
+    result, checks = _execute(cell, tmp_path, 2 ** 31 + 5, SECONDS.get(workload, 1.0))
     assert not result["correct"], checks
+
+
+def test_resume_that_drops_the_carry_is_not_correct(tmp_path, monkeypatch):
+    """A restart that resumes the checkpoint's round count and history but
+    starts from a fresh carry (the checkpointed state not put back)."""
+    from repro.launch import fed_serve
+
+    monkeypatch.setattr(fed_serve, "_restore_carry", lambda ck, template: template)
+    result, checks = _execute(_cell("xl-bl1.restart"), tmp_path, 2 ** 31 + 5,
+                              SECONDS["xl-bl1.restart"])
+    assert not result["correct"], checks
+
+
+def _full_matrix_numbers(gaps, legs, z, ref_gaps, zr, ref_legs, f_star, rounds):
+    """The numbers as taken from whole (rounds + 1, d) trajectories."""
+    t = slice(1, rounds + 1)
+    floor = correctness.GAP_FLOOR * abs(f_star)
+    gap_rel = float(np.max(np.abs(gaps[t] - ref_gaps[t]) / np.maximum(np.abs(ref_gaps[t]), floor)))
+    norm_gap = lambda k: float(abs(np.linalg.norm(z[k] - z[0]) - np.linalg.norm(zr[k] - zr[0]))
+                               / np.linalg.norm(zr[k] - zr[0]))
+    want = ref_legs(np.arange(len(gaps)))
+    bits = max(float(np.max(np.abs(legs[leg] - want[leg]))) for leg in want)
+    return {"gap_rel": gap_rel, "step_rel": norm_gap(1), "change_rel": norm_gap(rounds),
+            "bits": bits}
+
+
+def test_numbers_on_rounds_0_1_T_match_the_whole_trajectory():
+    cfg = _cell("xl-bl1.steady", ({"n_clients": 16, "m": 8, "d": 48, "r": 8}, {"k": 64}, {}))
+    cfg = cfg["config"]
+    ref = run._module("reference", cfg["reference"])
+    rounds, seed = 5, 2 ** 31 + 3
+    problem, cell = cfg["problem"], cfg["cell"]
+    # each run's last iterate is z_t of the same trajectory: the rounds draw nothing
+    sound = [ref.run(seed, problem, cell, t) for t in range(1, rounds + 1)]
+    f32 = [ref.run(seed, problem, cell, t, dtype=np.float32) for t in range(1, rounds + 1)]
+    whole = lambda runs: np.stack([runs[0]["iterates"][0]] + [r["iterates"][t + 1]
+                                                              for t, r in enumerate(runs)])
+    against, control = sound[-1], f32[-1]
+    legs = control["legs"](np.arange(rounds + 1))
+    got = correctness.numbers(control["gaps"], legs, control["iterates"], against, rounds)
+    want = _full_matrix_numbers(control["gaps"], legs, whole(f32), against["gaps"], whole(sound),
+                                against["legs"], against["f_star"], rounds)
+    assert got == want
+    assert got["step_rel"] > 0 and got["change_rel"] > 0
 
 
 def _scatter_dropped(monkeypatch):

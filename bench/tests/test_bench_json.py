@@ -96,6 +96,7 @@ def test_cells_configs_and_metrics_are_consistent():
         assert c["source"].startswith("https://") and _line(c["source"])
         for sub in ("reference", "flops"):
             assert os.path.exists(os.path.join(benchpath.BENCH, sub, cfg[sub] + ".py"))
+        assert os.path.exists(os.path.join(benchpath.BENCH, "jobs", cfg.get("job", "glm") + ".py"))
         assert set(cfg["limits"]) >= {"gap_rel", "step_rel", "change_rel", "bits"}
     assert len({c["file"] for c in configs.values()}) == len(configs)
     layer_of = {}
