@@ -341,18 +341,37 @@ class PerLayerSVDBasis:
     Completeness matters: `full_matrices=True` in the construction — a
     truncated V would silently project out every gradient component outside
     the weight's row space.
+
+    A leaf of more dimensions (a layer stack's leading axis, an attention
+    weight's ``(d, heads, head_dim)``) is rotated slice by slice on a
+    *matrix view* (``views``): its first ``n_stack`` axes index slices, the
+    next ``n_rows`` axes are the rows and the rest the columns — ``wq``
+    ``(G, d, heads, hd)`` is G matrices ``(d, heads·hd)``, ``wo``
+    ``(G, heads, hd, d)`` is G matrices ``(heads·hd, d)`` — and U, V stack
+    one factor per slice.
     """
 
     #: per-leaf entries ordered like ``jax.tree.leaves(params)``:
-    #: ``(U, V)`` for rotated 2-D leaves, ``None`` for pass-through leaves.
+    #: ``(U, V)`` for rotated leaves, ``None`` for pass-through leaves.
     UV: tuple
+    #: static, per leaf: None (a 2-D leaf, or one passed through) or the
+    #: matrix view ``(leaf shape, n_stack, n_rows)`` of a stacked leaf
+    views: Optional[tuple] = None
 
     def tree_flatten(self):
-        return (self.UV,), None
+        return (self.UV,), self.views
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        return cls(UV=children[0])
+        return cls(UV=children[0], views=aux)
+
+    @property
+    def slices_rotated(self) -> int:
+        """Matrices rotated: one per 2-D leaf, one per slice of a viewed
+        leaf."""
+        views = self.views or (None,) * len(self.UV)
+        return sum(1 if view is None else int(np.prod(view[0][:view[1]]))
+                   for uv, view in zip(self.UV, views) if uv is not None)
 
     def _map(self, fn, tree):
         leaves, treedef = jax.tree_util.tree_flatten(tree)
@@ -360,9 +379,18 @@ class PerLayerSVDBasis:
             raise ValueError(
                 f"tree has {len(leaves)} leaves but basis covers "
                 f"{len(self.UV)} — built from a different parameter tree?")
-        return treedef.unflatten(
-            [leaf if uv is None else fn(uv[0], uv[1], leaf)
-             for uv, leaf in zip(self.UV, leaves)])
+        views = self.views or (None,) * len(leaves)
+        out = []
+        for uv, view, leaf in zip(self.UV, views, leaves):
+            if uv is None:
+                out.append(leaf)
+            elif view is None:
+                out.append(fn(uv[0], uv[1], leaf))
+            else:
+                lead = leaf.shape[:leaf.ndim - len(view[0])]
+                mats = leaf.reshape(lead + _matrix_view(*view))
+                out.append(fn(uv[0], uv[1], mats).reshape(leaf.shape))
+        return treedef.unflatten(out)
 
     def rotate(self, tree):
         """Leaf-wise forward transform U_ℓᵀ g V_ℓ (complete basis ⇒ the
@@ -389,39 +417,83 @@ class PerLayerSVDBasis:
                 ) -> Tuple["PerLayerSVDBasis", float]:
         """The basis as it arrives after a compressed shipment: every
         rotated leaf's (U_ℓ, V_ℓ) quantized per `ship`
-        (`quantize_ship_factor`) and the summed exact bits of the shipment.
-        The default spec (f32, dense) is the identity on these f32 factors
-        and prices exactly ``ship_floats() × 32`` — legacy billing."""
+        (`quantize_ship_factor`, slice by slice for a stacked leaf) and the
+        summed exact bits of the shipment.  The default spec (f32, dense)
+        is the identity on these f32 factors and prices exactly
+        ``ship_floats() × 32`` — legacy billing."""
+        def factor(M):
+            if M.ndim == 2:
+                return quantize_ship_factor(M, ship)
+            parts = [quantize_ship_factor(m, ship) for m in M]
+            return (jnp.stack([q for q, _ in parts]),
+                    sum(b for _, b in parts))
+
         new_uv, bits = [], 0.0
         for uv in self.UV:
             if uv is None:
                 new_uv.append(None)
                 continue
-            U, bu = quantize_ship_factor(uv[0], ship)
-            V, bv = quantize_ship_factor(uv[1], ship)
+            U, bu = factor(uv[0])
+            V, bv = factor(uv[1])
             new_uv.append((U, V))
             bits += bu + bv
-        return type(self)(UV=tuple(new_uv)), bits
+        return type(self)(UV=tuple(new_uv), views=self.views), bits
 
 
-def per_layer_svd_basis(params, use_basis: bool = True,
-                        min_dim: int = 2) -> PerLayerSVDBasis:
+def _matrix_view(shape, n_stack: int, n_rows: int) -> tuple:
+    """(slices, rows, cols) of a leaf's matrix view."""
+    shape = tuple(int(s) for s in shape)
+    return (int(np.prod(shape[:n_stack])),
+            int(np.prod(shape[n_stack:n_stack + n_rows])),
+            int(np.prod(shape[n_stack + n_rows:])))
+
+
+def per_layer_svd_basis(params, use_basis: bool = True, min_dim: int = 2,
+                        views=None) -> PerLayerSVDBasis:
     """Build the `PerLayerSVDBasis` of a parameter pytree's initialization.
 
     Every 2-D leaf with both dims ≥ `min_dim` gets (U, V) from its full
-    SVD; everything else passes through.  ``use_basis=False`` returns the
-    identity basis (no rotations, zero shipment) — the no-basis control in
-    the basis-vs-compressor experiments.
+    SVD; 1-D leaves pass through.  ``views`` (aligned with
+    ``jax.tree.leaves(params)``) gives a leaf of more dimensions its matrix
+    view ``(n_stack, n_rows)`` (see `PerLayerSVDBasis`), and each slice of
+    it gets (U, V) from its own full SVD, taken by NumPy on the host; a leaf of 3 or more dimensions
+    without one is an error, since it cannot be rotated and would silently
+    ride in the standard basis.  ``use_basis=False`` returns the identity
+    basis (no rotations, zero shipment) — the no-basis control in the
+    basis-vs-compressor experiments.
     """
-    out = []
-    for p in jax.tree_util.tree_leaves(params):
-        if use_basis and p.ndim == 2 and min(p.shape) >= min_dim:
+    leaves = jax.tree_util.tree_leaves(params)
+    views = tuple(views) if views is not None else (None,) * len(leaves)
+    if len(views) != len(leaves):
+        raise ValueError(f"{len(views)} views for {len(leaves)} leaves")
+    out, kept = [], []
+    for p, view in zip(leaves, views):
+        if not use_basis:
+            out.append(None)
+            kept.append(None)
+            continue
+        if view is not None:
+            # on the host: the TPU compiler fails on a 4096² SVD
+            shape = tuple(int(s) for s in p.shape)
+            mats = np.asarray(p, np.float32).reshape(_matrix_view(shape, *view))
+            u, _, vt = np.linalg.svd(mats, full_matrices=True)
+            out.append((jnp.asarray(u), jnp.asarray(np.swapaxes(vt, -1, -2))))
+            kept.append((shape,) + tuple(view))
+            continue
+        if p.ndim >= 3:
+            raise ValueError(
+                f"per_layer_svd cannot rotate a leaf of shape {p.shape} "
+                "without a matrix view (views=)")
+        if p.ndim == 2 and min(p.shape) >= min_dim:
             u, _, vt = jnp.linalg.svd(p.astype(jnp.float32),
                                       full_matrices=True)
             out.append((u, vt.T))
         else:
             out.append(None)
-    return PerLayerSVDBasis(UV=tuple(out))
+        kept.append(None)
+    has_view = any(v is not None for v in kept)
+    return PerLayerSVDBasis(UV=tuple(out),
+                            views=tuple(kept) if has_view else None)
 
 
 @jax.tree_util.register_pytree_node_class
@@ -636,11 +708,13 @@ def _dct_bases(clients, x0=None):
 
 
 @register_basis("per_layer_svd", pytree=True)
-def _per_layer_svd_bases(params, x0=None, use_basis: bool = True):
+def _per_layer_svd_bases(params, x0=None, use_basis: bool = True,
+                         views=None):
     """Pytree basis of a DNN parameter tree (the BL-DNN workload): one
-    complete per-layer SVD rotation per 2-D weight, shared by the whole
-    fleet.  Shipment (Σ_ℓ |U_ℓ|+|V_ℓ| floats) bills on ``basis_ship``."""
-    return per_layer_svd_basis(params, use_basis=use_basis)
+    complete per-layer SVD rotation per weight matrix (or per slice of a
+    stacked leaf's matrix view), shared by the whole fleet.  Shipment
+    (Σ_ℓ |U_ℓ|+|V_ℓ| floats) bills on ``basis_ship``."""
+    return per_layer_svd_basis(params, use_basis=use_basis, views=views)
 
 
 @register_basis("dct_tree", pytree=True)

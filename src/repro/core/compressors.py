@@ -154,14 +154,33 @@ class Identity(Compressor):
 # --------------------------------------------------------------------------
 # shared |·|-Top-K selection (one implementation, two backends)
 # --------------------------------------------------------------------------
+#: rows at least this long select by the bitwise threshold search: XLA's
+#: ``top_k`` sorts, which on a TPU v5e takes 389 ms for 8 rows of 16.8M
+#: entries (k = 5%), where the search's compare-and-count passes and the
+#: mask take 32 ms
+SEARCH_ROWS_FROM = 1 << 20
+
+
 def _selection_threshold(a32: jax.Array, k: int) -> jax.Array:
     """k-th largest per row of non-negative f32 `a32` (..., T) → (..., 1).
 
-    Backends return bitwise-identical thresholds; see module docstring."""
+    Backends return bitwise-identical thresholds; see module docstring.
+    Rows of `SEARCH_ROWS_FROM` entries or more take the bitwise binary
+    search over f32 bit patterns (`kernels.topk_threshold._row_threshold`,
+    the Pallas kernel's algorithm, as XLA operations), which returns the
+    same threshold."""
     if os.environ.get("REPRO_BL_PALLAS", "0") == "1":
         from repro.kernels.topk_threshold import topk_row_threshold
 
         t = topk_row_threshold(a32.reshape((-1,) + a32.shape[-1:]), k)
+        return t.reshape(a32.shape[:-1] + (1,))
+    if a32.shape[-1] >= SEARCH_ROWS_FROM:
+        from repro.kernels.topk_threshold import _row_threshold
+
+        keys = jax.lax.bitcast_convert_type(
+            a32.reshape((-1,) + a32.shape[-1:]), jnp.int32)
+        t = jax.lax.bitcast_convert_type(
+            _row_threshold(keys, max(1, min(k, a32.shape[-1]))), jnp.float32)
         return t.reshape(a32.shape[:-1] + (1,))
     vals, idx = jax.lax.top_k(a32, k)
     # keep both outputs alive: with the indices dead, XLA rewrites top_k into
@@ -181,15 +200,21 @@ def topk_keep_mask(v: jax.Array, k: int) -> jax.Array:
     threshold, then earliest-index entries inside the threshold tie group
     (sub-f32-ulp value differences inside the group are broken by index).
     Scatter-free on purpose: mask + `where` instead of `.at[idx].set`.
+    Long rows (`SEARCH_ROWS_FROM`) break the tie group by a binary search
+    for its cut-off (`keep_mask_search`, the same mask) instead of a
+    prefix sum over the row.
 
     Public building block for Top-K-style selection outside the compressor
     classes (exactly-k semantics, tie handling and the Pallas/XLA backend
     switch in one place).
     """
-    from repro.kernels.topk_threshold import keep_mask
+    from repro.kernels.topk_threshold import keep_mask, keep_mask_search
 
     a32 = jnp.abs(v).astype(jnp.float32)
-    return keep_mask(a32, _selection_threshold(a32, k), k)
+    t = _selection_threshold(a32, k)
+    if a32.shape[-1] >= SEARCH_ROWS_FROM:
+        return keep_mask_search(a32, t, k)
+    return keep_mask(a32, t, k)
 
 
 #: historical private name — new code should import `topk_keep_mask`.

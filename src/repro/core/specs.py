@@ -705,6 +705,20 @@ class BLDNNSpec(MethodSpec):
     the registered experiment's bits-to-tolerance IS bits-to-accuracy —
     plus a ``"loss"`` stream).  Both are static spec fields: specs holding
     different functions compile separate engine programs.
+
+    ``frozen`` is fine-tuning with a frozen trunk: ``env.x0`` is
+    ``(params0, trunk)``, only ``params`` is federated, and
+    ``loss_fn(params, trunk, client_data) -> (loss, (assigned, dropped))``
+    also returns the oracle's expert-layer counts.  The oracle then runs
+    one client at a time (`jax.lax.map`: a client's activations, not the
+    fleet's, are live at once), and a round's evaluation is what the oracle
+    computed anyway — the fleet-mean loss at the round's params and the
+    summed counts, a dict of scalars — instead of the params themselves
+    (``eval_fn`` is unused).
+
+    Device scopes: ``oracle`` (gradients), ``basis`` (rotate, unrotate),
+    ``compress`` (the shift recursions), ``reduce`` and ``server`` (the
+    preconditioned step).
     """
 
     loss_fn: Callable
@@ -725,6 +739,8 @@ class BLDNNSpec(MethodSpec):
     basis_ship_bits: Optional[float] = None
     #: amortized re-shipment schedule; default is the legacy ship-once.
     refresh: BasisRefreshPolicy = BasisRefreshPolicy()
+    #: frozen-trunk fine-tuning (see the class docstring)
+    frozen: bool = False
 
     basis_replicated = True       # PerLayerSVDBasis is fleet-global
 
@@ -751,7 +767,7 @@ class BLDNNSpec(MethodSpec):
         return env.basisb.ship_floats() * self.WIRE_FLOAT_BITS
 
     def init(self, R, env):
-        params = env.x0
+        params = env.x0[0] if self.frozen else env.x0
         stacked = lambda p: jnp.zeros((R.n_local,) + p.shape, jnp.float32)
         shift = jax.tree.map(stacked, params)   # complete basis ⇒ coeff
         fshift = jax.tree.map(stacked, params)  # shapes == param shapes
@@ -766,20 +782,39 @@ class BLDNNSpec(MethodSpec):
             carry = carry + (jnp.zeros((), jnp.float64),)
         return carry
 
+    def _oracle(self, R, env, params):
+        """Per-client gradients (n_local, ...) and the round's evaluation."""
+        data = env.batch.data                     # leaves (n_local, ...)
+        if not self.frozen:
+            g = jax.vmap(jax.grad(self.loss_fn), in_axes=(None, 0))(
+                params, data)
+            return g, params
+        trunk = env.x0[1]
+        grad = jax.value_and_grad(self.loss_fn, has_aux=True)
+
+        def one(client):
+            (loss, counts), g = grad(params, trunk, client)
+            return g, loss, counts
+
+        g, losses, (assigned, dropped) = jax.lax.map(one, data)
+        ev = {"loss": R.mean(losses.astype(jnp.float64)),
+              "moe_assigned": R.sum(assigned.astype(jnp.float64)),
+              "moe_dropped": R.sum(dropped.astype(jnp.float64))}
+        return g, ev
+
     def step(self, R, env, carry, rc):
         key_t = rc.key
         amortized = self.refresh.amortized
-        if amortized:
-            params, shift, fshift, server_f, led, drift = carry
-        else:
-            params, shift, fshift, server_f, led = carry
-        ys = (params, led, jnp.int32(EVENT_NONE))  # evaluated post-scan
-        data = env.batch.data                     # leaves (n_local, ...)
+        params, shift, fshift, server_f, led = carry[:5]
+        drift = carry[5] if amortized else None
         basis = env.basisb
 
         # per-client gradients, rotated into the per-layer basis
-        g = jax.vmap(jax.grad(self.loss_fn), in_axes=(None, 0))(params, data)
-        coeff = g if basis is None else basis.rotate(g)
+        with jax.named_scope("oracle"):
+            g, ev = self._oracle(R, env, params)
+        ys = (ev, led, jnp.int32(EVENT_NONE))     # evaluated post-scan
+        with jax.named_scope("basis"):
+            coeff = g if basis is None else basis.rotate(g)
 
         k_g, k_f = jax.random.split(key_t)
         n_leaves = len(jax.tree_util.tree_leaves(params))
@@ -830,21 +865,26 @@ class BLDNNSpec(MethodSpec):
                 jnp.where(total > 0.0, 1.0 - kept / safe, 0.0), 0.0)
         red = R.reduce_tree(agg)
         coeff_mean = red["coeff"]
-        g_hat = coeff_mean if basis is None else basis.unrotate(coeff_mean)
+        with jax.named_scope("basis"):
+            g_hat = coeff_mean if basis is None else basis.unrotate(coeff_mean)
 
-        if self.precondition:
-            fmeans = R.tree_mean_presummed(Fc, fsums)
-            server_f_n = jax.tree.map(
-                lambda sf, fm: sf + self.fisher_alpha * fm, server_f, fmeans)
-            update = jax.tree.map(
-                lambda gh, sf: gh / (jnp.sqrt(jnp.maximum(sf, 0.0)) + self.eps),
-                g_hat, server_f_n)
-        else:
-            server_f_n, update = server_f, g_hat
+        with jax.named_scope("server"):
+            if self.precondition:
+                fmeans = R.tree_mean_presummed(Fc, fsums)
+                server_f_n = jax.tree.map(
+                    lambda sf, fm: sf + self.fisher_alpha * fm, server_f,
+                    fmeans)
+                update = jax.tree.map(
+                    lambda gh, sf: gh / (jnp.sqrt(jnp.maximum(sf, 0.0))
+                                         + self.eps),
+                    g_hat, server_f_n)
+            else:
+                server_f_n, update = server_f, g_hat
 
-        params_n = jax.tree.map(
-            lambda p, u: (p.astype(jnp.float32) - self.lr * u).astype(p.dtype),
-            params, update)
+            params_n = jax.tree.map(
+                lambda p, u: (p.astype(jnp.float32) - self.lr * u).astype(p.dtype),
+                params, update)
+        extra = ()
         if amortized:
             # re-ship at refresh boundaries (pure in the absolute round
             # index — see rounds.refresh_due) when LAST round's drift has
@@ -855,13 +895,18 @@ class BLDNNSpec(MethodSpec):
             led = led.add(grad_up=red["gbits"], hess_up=red["fbits"],
                           basis_ship=jnp.where(fire, self._ship_bits(env),
                                                0.0))
-            return (params_n, shift_n, fshift_n, server_f_n, led,
-                    red["drift"]), ys
-        led = led.add(grad_up=red["gbits"], hess_up=red["fbits"])
-        return (params_n, shift_n, fshift_n, server_f_n, led), ys
+            extra = (red["drift"],)
+        else:
+            led = led.add(grad_up=red["gbits"], hess_up=red["fbits"])
+        return (params_n, shift_n, fshift_n, server_f_n, led) + extra, ys
 
     def eval_streams(self, batch, xs_t, f_star):
         """Vmapped whole-trajectory evaluation of `eval_fn` (one shared
         program on every backend); ``f_star`` is unused — DNN training has
-        no reference optimum, the gap stream is the training error rate."""
+        no reference optimum, the gap stream is the training error rate.
+        A frozen-trunk spec's rounds were evaluated by the oracle: its gap
+        stream is the fleet-mean training loss."""
+        if self.frozen:
+            return {"gap": jnp.asarray(xs_t["loss"]),
+                    "loss": jnp.asarray(xs_t["loss"])}
         return jax.jit(jax.vmap(lambda p: self.eval_fn(p, batch.data)))(xs_t)

@@ -35,6 +35,7 @@ from .registry import (
     CompressorCfg,
     DNNProblemSpec,
     Experiment,
+    LMFinetuneSpec,
     MethodCell,
     ProblemSpec,
 )
@@ -106,10 +107,20 @@ class DNNProblem:
     params0: object                  # parameter pytree
     loss_fn: object
     eval_fn: object
+    #: fine-tuning (`LMFinetuneSpec`): the frozen trunk and the federated
+    #: leaves' matrix views for the per-layer SVD basis
+    trunk: object = None
+    views: Optional[tuple] = None
 
     @property
     def n(self) -> int:
         return self.batch.n
+
+    @property
+    def x0(self):
+        """The engine's round-0 input: the params, with the trunk beside
+        them when it is frozen (`specs.BLDNNSpec.frozen`)."""
+        return self.params0 if self.trunk is None else (self.params0, self.trunk)
 
 
 @dataclasses.dataclass
@@ -138,6 +149,18 @@ class StreamProblem:
 def build_problem(spec: ProblemSpec) -> Problem:
     """Materialize a `ProblemSpec` or `DNNProblemSpec` (memoized — figures
     share regimes)."""
+    if isinstance(spec, LMFinetuneSpec):
+        from repro.fed import bldnn
+
+        cfg, batch, params0, trunk = bldnn.make_lm_finetune(
+            seed=spec.seed, model=spec.model, n_layers=spec.n_layers,
+            n_held=spec.n_held, first_held=spec.first_held, vocab=spec.vocab,
+            n_clients=spec.n_clients, seq=spec.seq, zipf=spec.zipf,
+            overrides=spec.overrides)
+        return DNNProblem(spec=spec, batch=batch, params0=params0,
+                          loss_fn=bldnn.make_lm_loss_fn(cfg), eval_fn=None,
+                          trunk=trunk,
+                          views=tuple(bldnn.ATTN_VIEWS[k] for k in sorted(params0)))
     if isinstance(spec, DNNProblemSpec):
         from repro.fed import bldnn
 
@@ -313,6 +336,11 @@ def run_cell(exp: Experiment, cell: MethodCell, prob: Problem, *,
         if not isinstance(prob, DNNProblem):
             raise ValueError(
                 f"cell {cell.name!r} needs a DNNProblemSpec problem")
+        if prob.trunk is not None:
+            raise ValueError(
+                f"cell {cell.name!r} fine-tunes with a frozen trunk, which "
+                "the serve loop runs: python -m repro.launch.fed_serve "
+                f"--exp <experiment> --cell {cell.name}")
         if cell.hess_comp is None:
             raise ValueError("bldnn cells configure the (gradient+Fisher) "
                              "compressor via hess_comp")

@@ -97,6 +97,34 @@ class DNNProblemSpec:
 
 
 @dataclasses.dataclass(frozen=True)
+class LMFinetuneSpec:
+    """Problem regime for BL-DNN fine-tuning of a `repro.configs` language
+    model with its trunk frozen (`repro.fed.bldnn.make_lm_finetune`): the
+    first attention layer of the pattern is federated, the rest is frozen.
+
+    The model is cut to ``n_layers`` of its published pattern, to the
+    experts ``[first_held, first_held + n_held)`` of each expert layer (one
+    chip's share under expert parallelism) and to a vocabulary slice of
+    ``vocab`` ids; each of ``n_clients`` clients holds one sequence of
+    ``seq`` next-token predictions whose ids are Zipf(``zipf``) ranks
+    mapped through a permutation of its own.  Weights and ids are drawn
+    from ``seed``.  ``overrides`` shrinks widths for the CPU tests only
+    (`repro.fed.bldnn.lm_model_config`)."""
+
+    kind: str = "lm_finetune"
+    model: str = "granite_40_h_small"
+    seed: int = 0
+    n_layers: int = 10
+    n_held: int = 9
+    first_held: int = 0
+    vocab: int = 12_544
+    n_clients: int = 4
+    seq: int = 4096
+    zipf: float = 1.1
+    overrides: Tuple[Tuple[str, Any], ...] = ()
+
+
+@dataclasses.dataclass(frozen=True)
 class CompressorCfg:
     """Declarative compressor config; built per-problem by
     `repro.exp.engine.build_compressor` (some kinds derive parameters from
@@ -508,6 +536,27 @@ register_experiment(Experiment(
                    hess_comp=_DNN_TOPK,
                    params=(("top_k_frac", 0.1), ("lr", 0.05))),
     ),
+))
+
+# granite-bldnn: cross-silo BL-DNN fine-tuning of a Granite-4.0-H-Small cut
+# (one period of its layer pattern, 9 of 72 experts per layer, an eighth of
+# the vocabulary) with its trunk frozen and the period's attention layer
+# federated — the served path's pytree job (`repro.launch.fed_serve`).
+register_experiment(Experiment(
+    name="granite-bldnn",
+    figure="extra",
+    title="BL-DNN fine-tuning of a Granite-4.0-H-Small cut, trunk frozen "
+          "(beyond paper)",
+    paper_ref="§2.3 mechanism on a hybrid LM's attention layer "
+              "(no paper counterpart)",
+    problem=LMFinetuneSpec(),
+    tol=0.0,
+    cells=(
+        MethodCell("BLDNN", "bldnn", 12, basis="per_layer_svd",
+                   hess_comp=_DNN_TOPK, params=(("top_k_frac", 0.05),),
+                   backend="fast"),
+    ),
+    tags=("lm", "xl"),
 ))
 
 # fig1-bag: FedNL-BAG (Bernoulli-lazy gradient aggregation, arXiv

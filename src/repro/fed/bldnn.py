@@ -35,7 +35,11 @@ identical histories (tests/test_fed.py).
 This module is the workload wiring: an MLP classifier assembled from
 `repro.models.layers`, a synthetic fine-tuning-style classification fleet,
 per-leaf compressor construction, and the public `run_bldnn` entry point
-the `fig-dnn` experiment (`repro.exp.registry`) dispatches to.
+the `fig-dnn` experiment (`repro.exp.registry`) dispatches to.  It also
+builds the served job (`make_lm_finetune`, `serve_setup`): fine-tuning a
+`repro.configs` language model, cut to one chip's share, with its trunk
+frozen and one attention layer federated (`specs.BLDNNSpec.frozen`), the
+``granite-bldnn`` experiment that `repro.launch.fed_serve` serves.
 """
 from __future__ import annotations
 
@@ -312,3 +316,185 @@ def run_bldnn(loss_fn, eval_fn, params0: Params, batch: TreeBatch,
         sharded=(backend == "fast+sharded"), exact=exact, stream=stream)
     return batched._history(evals, leds)
 
+
+
+# --------------------------------------------------------------------------
+# fine-tuning a `repro.models` LM with a frozen trunk (the served BL-DNN job)
+# --------------------------------------------------------------------------
+#: leaves that a federated attention layer trains (flatten order: sorted)
+ATTN_LEAVES = ("wk", "wo", "wq", "wv")
+#: matrix views (n_stack, n_rows) of the stacked attention leaves:
+#: ``(G, d, heads, hd)`` as G × ``(d, heads·hd)``, ``wo`` ``(G, heads, hd, d)``
+#: as G × ``(heads·hd, d)``
+ATTN_VIEWS = {"wk": (1, 1), "wo": (1, 2), "wq": (1, 1), "wv": (1, 1)}
+
+
+def lm_model_config(model: str, n_layers: int, n_held: int, first_held: int,
+                    vocab: int, overrides=()):
+    """The cut `ModelConfig`: ``n_layers`` of the published pattern, the
+    layer's expert share ``[first_held, first_held + n_held)`` and a
+    vocabulary slice of ``vocab`` ids; ``overrides`` ((key, value) pairs,
+    ``moe.``/``ssm.`` prefixes for the nested groups) shrink widths for the
+    CPU tests only."""
+    from repro.configs import get_config
+
+    cfg = get_config(model)
+    top, moe, ssm = {}, {}, {}
+    for key, value in overrides:
+        group, _, name = key.rpartition(".")
+        {"": top, "moe": moe, "ssm": ssm}[group][name] = value
+    moe = dataclasses.replace(cfg.moe, n_held=n_held, first_held=first_held,
+                              **moe)
+    return dataclasses.replace(
+        cfg, n_layers=n_layers, vocab_size=vocab, moe=moe,
+        ssm=dataclasses.replace(cfg.ssm, **ssm), **top)
+
+
+def _leaf_std(name: str, shape) -> float:
+    """Init scale of a weight matrix: fan_in^-1/2 over its input axes."""
+    if name.endswith("attn/wo"):
+        return float(shape[-3] * shape[-2]) ** -0.5
+    if name.endswith(("attn/wq", "attn/wk", "attn/wv")):
+        return float(shape[-3]) ** -0.5
+    return float(shape[-2]) ** -0.5
+
+
+def seeded_leaf(key, name: str, shape, dtype):
+    """One parameter leaf by name, drawn from its own key: norm scales and
+    D ones, A_log = log(1..H), dt_bias zeros, the embedding N(0, 0.02²), the
+    conv N(0, 0.5²) and its bias N(0, 0.1²), every other weight
+    N(0, 1/fan_in); drawn in float32, then cast."""
+    last = name.rsplit("/", 1)[-1]
+    if last in ("scale", "D"):
+        return jnp.ones(shape, dtype)
+    if last == "dt_bias":
+        return jnp.zeros(shape, dtype)
+    if last == "A_log":
+        return jnp.broadcast_to(
+            jnp.log(jnp.arange(1, shape[-1] + 1, dtype=jnp.float32)),
+            shape).astype(dtype)
+    std = {"embed": 0.02, "conv_w": 0.5, "conv_b": 0.1}.get(last)
+    if std is None:
+        std = _leaf_std(name, shape)
+    return (jax.random.normal(key, shape, jnp.float32) * std).astype(dtype)
+
+
+def seeded_params(seed: int, cfg, dtype=jnp.bfloat16, keep=()) -> Params:
+    """`repro.models` parameters of ``cfg`` drawn leaf by leaf, each from
+    ``fold_in(PRNGKey(s), crc32(path))`` with s the seed reduced to 31 bits
+    by NumPy's generator — a rule a plain reference can follow with no
+    part of this program.  Leaves whose path is in ``keep`` stay float32."""
+    import zlib
+
+    from repro.models import model as M
+
+    base = jax.random.PRNGKey(int(np.random.default_rng(seed).integers(2 ** 31)))
+    flat, treedef = jax.tree_util.tree_flatten_with_path(
+        M.param_shapes(cfg, dtype))
+    leaves = []
+    for path, sds in flat:
+        name = "/".join(str(k.key) for k in path)
+        key = jax.random.fold_in(base, zlib.crc32(name.encode()))
+        leaf_dtype = jnp.float32 if name in keep else sds.dtype
+        leaves.append(seeded_leaf(key, name, sds.shape, leaf_dtype))
+    return treedef.unflatten(leaves)
+
+
+def zipf_tokens(seed: int, n_clients: int, length: int, vocab: int,
+                exponent: float) -> np.ndarray:
+    """(n_clients, length) token ids: ranks drawn Zipf(``exponent``) over
+    the vocabulary, mapped through a permutation of its own per client
+    (non-IID clients)."""
+    rng = np.random.default_rng([seed, 1])
+    p = np.arange(1, vocab + 1, dtype=np.float64) ** -exponent
+    p /= p.sum()
+    out = np.empty((n_clients, length), np.int32)
+    for i in range(n_clients):
+        perm = rng.permutation(vocab)
+        out[i] = perm[rng.choice(vocab, size=length, p=p)]
+    return out
+
+
+def _attn_path(cfg) -> str:
+    """The federated attention layer: the pattern's first attention mixer."""
+    i = next(i for i, s in enumerate(cfg.group) if s.mixer == "attn")
+    return f"layers/l{i}/attn"
+
+
+def split_trunk(full: Params, cfg) -> Tuple[Params, Params]:
+    """(federated attention leaves, trunk without them)."""
+    _, li, _ = _attn_path(cfg).split("/")
+    layer = dict(full["layers"][li])
+    fed = layer.pop("attn")
+    trunk = {**full, "layers": {**full["layers"], li: layer}}
+    return fed, trunk
+
+
+def merge_trunk(fed: Params, trunk: Params, cfg) -> Params:
+    """The whole model: the federated leaves, cast to the trunk's dtype,
+    back in their layer."""
+    _, li, _ = _attn_path(cfg).split("/")
+    dtype = trunk["embed"].dtype
+    attn = jax.tree.map(lambda w: w.astype(dtype), fed)
+    return {**trunk, "layers": {**trunk["layers"],
+                                li: {**trunk["layers"][li], "attn": attn}}}
+
+
+def make_lm_loss_fn(cfg):
+    """Per-client next-token loss over the vocabulary slice:
+    ``(fed, trunk, {"ids": (S+1,)}) -> (mean CE, (assigned, dropped))``.
+    Activations in the trunk's dtype (bf16), norms, softmaxes, the SSM
+    scan and the loss in float32; each layer is rematerialized on its own
+    in the backward pass."""
+    from repro.models import model as M
+
+    def loss_fn(fed, trunk, data):
+        ids = data["ids"]
+        logits, _, _, counts = M.forward(
+            merge_trunk(fed, trunk, cfg), cfg, None, ids[None, :-1],
+            remat=False, remat_layers=True, return_counts=True)
+        logp = jax.nn.log_softmax(logits[0].astype(jnp.float32), axis=-1)
+        nll = -jnp.take_along_axis(logp, ids[1:, None], axis=-1)
+        return jnp.mean(nll), counts
+    return loss_fn
+
+
+def make_lm_finetune(seed: int, model: str, n_layers: int, n_held: int,
+                     first_held: int, vocab: int, n_clients: int, seq: int,
+                     zipf: float, overrides=()):
+    """(cfg, batch, params0, trunk): the cut model drawn from the seed
+    (`seeded_params`; the federated attention leaves in float32, the trunk
+    in bfloat16) and each client's ``seq + 1`` token ids (`zipf_tokens`)."""
+    cfg = lm_model_config(model, n_layers, n_held, first_held, vocab,
+                          overrides)
+    attn = _attn_path(cfg)
+    full = seeded_params(seed, cfg,
+                         keep=tuple(f"{attn}/{w}" for w in ATTN_LEAVES))
+    params0, trunk = split_trunk(full, cfg)
+    ids = zipf_tokens(seed, n_clients, seq + 1, vocab, zipf)
+    return cfg, tree_batch({"ids": jnp.asarray(ids)}), params0, trunk
+
+
+def serve_setup(cell, prob):
+    """(spec, batch, basis) of a frozen-trunk BL-DNN cell for the serve
+    loop: the per-layer SVD basis of the federated leaves on their matrix
+    views, shipped per the cell's wire, and its exact price."""
+    if prob.trunk is None:
+        raise SystemExit(
+            f"fed_serve serves BL-DNN fine-tuning with a frozen trunk (an "
+            f"lm_finetune problem); cell {cell.name!r} has none")
+    params = cell.params_dict()
+    params.pop("seed", None)        # the serve PRNG root comes from --seed
+    cfg = BLDNNConfig(compressor=cell.hess_comp.kind,
+                      use_basis=cell.basis is not None,
+                      basis_kind=cell.basis or "per_layer_svd", **params)
+    basis = None
+    if cfg.use_basis:
+        basis = make_bases(cfg.basis_kind, prob.params0, views=prob.views)
+        basis, ship_bits = basis.shipped(comm.BasisShipSpec(
+            float_bits=cfg.ship_float_bits, col_frac=cfg.ship_col_frac))
+    spec = dataclasses.replace(
+        build_spec(prob.loss_fn, None, prob.params0, cfg,
+                   basis_ship_bits=ship_bits if basis is not None else None),
+        frozen=True)
+    return spec, prob.batch, basis

@@ -68,7 +68,12 @@ is computed but before its covering checkpoint lands (omit the flag on
 restart, or it crashes at the same boundary forever).
 
 Supported methods: the GLM specs with client-stacked state (bl1, bl2, bl3,
-fednl_bag).  Fault injection additionally requires the method to react to
+fednl_bag), and BL-DNN fine-tuning with a frozen trunk (bldnn on an
+``lm_finetune`` problem), whose rounds the oracle evaluates: its streams
+keep each round's loss and expert-layer counts (``eval/<name>``), not the
+iterate, and its record's ``meta`` adds ``moe_assignments_held`` (token–
+expert pairs computed per round), ``moe_dropped`` and
+``basis_slices_rotated``.  Fault injection additionally requires the method to react to
 availability (`MethodSpec.supports_faults`: bl2/bl3 partial participation,
 fednl_bag lazy aggregation) — serving bl1 works, but injecting faults into
 it is refused rather than silently ignored.
@@ -96,9 +101,10 @@ from repro.exp.engine import (
 )
 from repro.exp.registry import get_experiment
 
-#: methods the serve loop can drive (GLM specs; the DNN spec's pytree
-#: eval stream needs a different stream accumulator)
-SERVE_METHODS = ("bl1", "bl2", "bl3", "fednl_bag")
+#: methods the serve loop can drive: the GLM specs, and bldnn on a
+#: frozen-trunk fine-tuning problem (whose per-round evaluation is a dict
+#: of scalars; a pytree iterate per round would not fit the streams)
+SERVE_METHODS = ("bl1", "bl2", "bl3", "fednl_bag", "bldnn")
 
 #: checkpoint stream names: eval iterates, events, one per ledger leg
 _STREAMS = ("eval_x", "events") + tuple(
@@ -114,6 +120,10 @@ def build_setup(exp, cell, prob):
         raise SystemExit(
             f"fed_serve drives methods {', '.join(SERVE_METHODS)}; cell "
             f"{cell.name!r} uses {m!r} (run it via `python -m repro.exp`)")
+    if m == "bldnn":
+        from repro.fed import bldnn
+
+        return bldnn.serve_setup(cell, prob)
     params = cell.params_dict()
     params.pop("seed", None)        # the serve PRNG root comes from --seed
     n, d = prob.n, prob.d
@@ -176,24 +186,57 @@ def _resolve_cohort_backend(cell, override: Optional[str]) -> str:
     return backend
 
 
-def _empty_streams(d: int) -> dict:
+def _empty_streams(d: Optional[int]) -> dict:
+    """Streams of no rounds; ``d`` None: the rounds' evaluations are dicts
+    of scalars, whose ``eval/<name>`` streams the first chunk starts."""
     z64 = lambda: np.zeros((0,), np.float64)
-    return {"eval_x": np.zeros((0, d), np.float64),
-            "events": np.zeros((0,), np.int32),
-            **{f"led_{leg}": z64() for leg in comm.CommLedger.LEGS}}
+    out = {"events": np.zeros((0,), np.int32),
+           **{f"led_{leg}": z64() for leg in comm.CommLedger.LEGS}}
+    if d is not None:
+        out["eval_x"] = np.zeros((0, d), np.float64)
+    return out
 
 
 def _append_chunk(streams: dict, ys) -> dict:
     """The chunk's output streams appended on the host (the ``serve.pull``
     span: it waits for the chunk's device work)."""
     xs, leds, evs = ys
-    cat = lambda name, arr: np.concatenate(
-        [streams[name], np.asarray(arr)], axis=0)
+    new = {"events": evs,
+           **{f"led_{leg}": getattr(leds, leg) for leg in comm.CommLedger.LEGS}}
+    if isinstance(xs, dict):
+        new.update({f"eval/{k}": v for k, v in xs.items()})
+    else:
+        new["eval_x"] = xs
     with spans.span("serve.pull"):
-        out = {"eval_x": cat("eval_x", xs), "events": cat("events", evs)}
-        for leg in comm.CommLedger.LEGS:
-            out[f"led_{leg}"] = cat(f"led_{leg}", getattr(leds, leg))
+        out = {}
+        for name, arr in new.items():
+            arr = np.asarray(arr)
+            out[name] = (np.concatenate([streams[name], arr], axis=0)
+                         if name in streams else arr)
     return out
+
+
+def _evaluated(streams: dict, last: Optional[int] = None):
+    """What `MethodSpec.eval_streams` takes: the eval iterates, or the
+    dict of per-round evaluations (of the ``last`` rounds, where given)."""
+    cut = (lambda a: a) if last is None else (lambda a: a[-last:])
+    if "eval_x" in streams:
+        return jnp.asarray(cut(streams["eval_x"]))
+    return {name.split("/", 1)[1]: jnp.asarray(cut(arr))
+            for name, arr in streams.items() if name.startswith("eval/")}
+
+
+def _tree_counters(streams: dict, basisb, resumed_from: Optional[int]) -> dict:
+    """A frozen-trunk BL-DNN call's counters: token–expert pairs computed
+    per round and pairs dropped, over the rounds this call served, and the
+    matrices the basis rotates."""
+    served = lambda name: np.asarray(streams.get(name, np.zeros(0)))[
+        resumed_from or 0:]
+    held = served("eval/moe_assigned")
+    return {"moe_assignments_held": float(held.mean()) if held.size else 0.0,
+            "moe_dropped": float(served("eval/moe_dropped").sum()),
+            "basis_slices_rotated": (basisb.slices_rotated
+                                     if basisb is not None else 0)}
 
 
 def _retraces(before: dict) -> dict:
@@ -523,7 +566,10 @@ def serve(*, exp_name: str, cell_name: str, seed: int = 0, chunk: int = 25,
     sharded = backend == "fast+sharded"
     crash = (faults.CrashInjector(crash_after_round)
              if crash_after_round is not None else None)
-    x0, x_star = prob.x0, prob.x_star
+    tree = cell.method == "bldnn"
+    x0 = prob.x0
+    f_star_of = (lambda: 0.0) if tree else (
+        lambda: batched._f_star(batch, prob.x_star))
 
     config = serve_config(exp, cell, seed, backend, plan)
     digest = artifacts.config_digest(config)
@@ -544,8 +590,8 @@ def serve(*, exp_name: str, cell_name: str, seed: int = 0, chunk: int = 25,
         if ck is not None:
             t = int(ck["t"])
             carry = _restore_carry(ck, template)
-            streams = {name: np.asarray(ck["streams"][name])
-                       for name in _STREAMS}
+            streams = {name: np.asarray(arr)
+                       for name, arr in ck["streams"].items()}
             root_key = jnp.asarray(ck["root_key"])
             resumed_from = t
             log(f"[serve] {exp.name}/{cell.name}: resumed from checkpoint "
@@ -553,13 +599,16 @@ def serve(*, exp_name: str, cell_name: str, seed: int = 0, chunk: int = 25,
         else:
             t = 0
             carry = template
-            streams = _empty_streams(prob.d)
+            streams = _empty_streams(None if tree else prob.d)
             root_key = jax.random.PRNGKey(seed)
             log(f"[serve] {exp.name}/{cell.name}: fresh run (config "
                 f"{digest})")
+        # the template is the fresh carry or a shape pattern: holding it
+        # past here would keep one more copy of the carry on the device
+        del template
 
     sink = MetricsSink(metrics_out) if metrics_out else None
-    f_star = batched._f_star(batch, x_star) if sink else None
+    f_star = f_star_of() if sink else None
     chunks_run = 0
     waited_total = 0.0
     ttfr_s = None
@@ -584,8 +633,7 @@ def serve(*, exp_name: str, cell_name: str, seed: int = 0, chunk: int = 25,
         if sink is not None:
             with spans.span("serve.sink"):
                 gaps = spec.eval_streams(
-                    batch, jnp.asarray(streams["eval_x"][-steps:]),
-                    f_star)["gap"]
+                    batch, _evaluated(streams, steps), f_star)["gap"]
                 sink.emit_chunk(
                     range(t - steps, t), np.asarray(gaps),
                     streams["events"][-steps:],
@@ -608,8 +656,7 @@ def serve(*, exp_name: str, cell_name: str, seed: int = 0, chunk: int = 25,
                 root_key=np.asarray(root_key), config_digest=digest,
                 keep=keep)
 
-    evals = spec.eval_streams(batch, jnp.asarray(streams["eval_x"]),
-                              batched._f_star(batch, x_star))
+    evals = spec.eval_streams(batch, _evaluated(streams), f_star_of())
     led_streams = comm.CommLedger(
         *(jnp.asarray(streams[f"led_{leg}"])
           for leg in comm.CommLedger.LEGS))
@@ -646,6 +693,7 @@ def serve(*, exp_name: str, cell_name: str, seed: int = 0, chunk: int = 25,
             "server_eigh": _server_eigh(streams["events"], resumed_from),
             "spans": spans.since(spans_before),
             "retraces": _retraces(traces_before),
+            **(_tree_counters(streams, basisb, resumed_from) if tree else {}),
         },
     }
     if result_path:

@@ -32,6 +32,22 @@ class MoEConfig:
     d_expert: int = 0          # per-expert FFN width (0 ⇒ use d_ff)
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
+    #: width of one always-on shared SwiGLU expert (Granite 4.0-H's
+    #: ``shared_intermediate_size``); 0 ⇒ ``n_shared`` experts of d_expert
+    d_shared: int = 0
+    #: dropless routing (`layers.moe_dropless`): the top-k gates are a
+    #: softmax over the top-k router logits, every token–expert pair is
+    #: computed (no capacity), and this layer holds experts
+    #: ``[first_held, first_held + n_held)`` of ``n_experts`` — the chip's
+    #: share under expert parallelism (0 ⇒ all of them)
+    dropless: bool = False
+    n_held: int = 0
+    first_held: int = 0
+
+    @property
+    def held(self) -> int:
+        """Experts whose weights this layer holds."""
+        return self.n_held or self.n_experts
 
 
 @dataclasses.dataclass(frozen=True)
@@ -41,6 +57,7 @@ class SSMConfig:
     expand: int = 2
     conv_width: int = 4
     chunk: int = 256           # SSD chunk length
+    conv_bias: bool = False    # bias on the depthwise conv (Granite 4.0-H)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -69,6 +86,15 @@ class ModelConfig:
     #: VLM stub: prepend this many precomputed patch embeddings to the text
     n_prefix_embeds: int = 0
     norm_eps: float = 1e-6
+    #: False ⇒ no positional encoding in attention (NoPE, Granite 4.0-H)
+    rope: bool = True
+    #: Granite's scalars: embeddings × embedding_multiplier, every residual
+    #: branch × residual_multiplier, attention scores × attention_multiplier
+    #: (None ⇒ head_dim^-0.5), logits ÷ logits_scaling
+    embedding_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    attention_multiplier: Optional[float] = None
+    logits_scaling: float = 1.0
     #: supports sub-quadratic long-context decode (SSM/hybrid/sliding-window)
     subquadratic: bool = False
     max_seq: int = 131_072
@@ -121,6 +147,9 @@ class ModelConfig:
                 top_k=min(self.moe.top_k, 2),
                 n_shared=min(self.moe.n_shared, 1),
                 d_expert=min(self.moe.d_expert, 128) if self.moe.d_expert else 0,
+                d_shared=min(self.moe.d_shared, 128) if self.moe.d_shared else 0,
+                n_held=min(self.moe.n_held, 2) if self.moe.n_held else 0,
+                first_held=0,
             )
         ssm = None
         if self.ssm is not None:
@@ -150,6 +179,11 @@ class ModelConfig:
             enc_seq=min(self.enc_seq, 16),
             n_prefix_embeds=min(self.n_prefix_embeds, 4),
             norm_eps=self.norm_eps,
+            rope=self.rope,
+            embedding_multiplier=self.embedding_multiplier,
+            residual_multiplier=self.residual_multiplier,
+            attention_multiplier=self.attention_multiplier,
+            logits_scaling=self.logits_scaling,
             subquadratic=self.subquadratic,
             max_seq=256,
         )

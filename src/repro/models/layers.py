@@ -127,7 +127,8 @@ def _dus_seq(buf, val, pos):
 
 
 def _blocked_attn(q, k, v, mask_fn, block: int, rules, q_pos0=0,
-                  window: Optional[int] = None):
+                  window: Optional[int] = None, scale: Optional[float] = None,
+                  remat: bool = False):
     """Grouped-query blocked attention (no KV head materialization).
 
     q: (B, Sq, KVH, rep, hd);  k, v: (B, Sk, KVH, hd).
@@ -138,11 +139,13 @@ def _blocked_attn(q, k, v, mask_fn, block: int, rules, q_pos0=0,
     Sliding-window layers (static `window`) slice each query block's K/V to
     the `block + window` stripe it can actually see instead of masking the
     full sequence — ~Sk/(block+window)× less attention compute/memory
-    (§Perf gemma3 iteration 3).
+    (§Perf gemma3 iteration 3).  ``scale`` multiplies the scores (None ⇒
+    hd^-0.5).  ``remat`` recomputes each block's scores in the backward
+    pass, which then holds one block's scores at a time.
     """
     B, Sq, KVH, rep, hd = q.shape
     Sk = k.shape[1]
-    scale = hd ** -0.5
+    scale = hd ** -0.5 if scale is None else scale
     block = min(block, Sq)
     while Sq % block:  # largest divisor of Sq ≤ requested block
         block -= 1
@@ -179,6 +182,8 @@ def _blocked_attn(q, k, v, mask_fn, block: int, rules, q_pos0=0,
         o = jnp.einsum("bgrqk,bkgd->bqgrd", pr.astype(vv.dtype), vv)
         return carry, o
 
+    if remat:
+        body = jax.checkpoint(body)
     if UNROLL_FOR_COSTS:
         outs = [body(None, (jnp.asarray(i), qb[i]))[1] for i in range(n_blocks)]
         ob = jnp.stack(outs)
@@ -189,7 +194,8 @@ def _blocked_attn(q, k, v, mask_fn, block: int, rules, q_pos0=0,
 
 
 def _seq_parallel_attn(qg, k, v, mask_fn, block: int, rules,
-                       window: Optional[int] = None):
+                       window: Optional[int] = None,
+                       scale: Optional[float] = None):
     """Context-parallel blocked attention over the `model` axis (§Perf).
 
     Used when n_heads doesn't divide the model axis (gemma3: 8 heads,
@@ -212,7 +218,7 @@ def _seq_parallel_attn(qg, k, v, mask_fn, block: int, rules,
         vf = jax.lax.all_gather(v_l, "model", axis=1, tiled=True)
         off = jax.lax.axis_index("model") * Sq_loc
         return _blocked_attn(qg_l, kf, vf, mask_fn, block, None, q_pos0=off,
-                             window=window)
+                             window=window, scale=scale)
 
     o = shard_map(
         local, mesh=mesh,
@@ -237,6 +243,7 @@ def attention(
     kv_override: Optional[Tuple[jax.Array, jax.Array]] = None,
     causal: bool = True,
     q_block: int = 1024,
+    remat_blocks: bool = False,
 ) -> Tuple[jax.Array, Optional[Tuple[jax.Array, jax.Array]]]:
     """GQA attention.
 
@@ -251,10 +258,16 @@ def attention(
     * cross-attention: kv_override=(k, v) precomputed from encoder output
       (no RoPE, no mask).
     * window: static python int — sliding-window size (None ⇒ full).
+    * ``cfg.rope`` False: no positional encoding (NoPE); scores are scaled
+      by ``cfg.attention_multiplier`` where it is set.
+    * remat_blocks: the backward pass recomputes each query block's scores
+      (`_blocked_attn`).
     """
     B, Sq, D = x.shape
     nh, nkv, hd = cfg.n_heads, cfg.n_kv_heads, cfg.hd
     rep = nh // nkv
+    scale = (hd ** -0.5 if cfg.attention_multiplier is None
+             else cfg.attention_multiplier)
 
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"])
     q = shard(rules, q, "batch", None, "heads", None)
@@ -264,7 +277,7 @@ def attention(
         new_cache = None
         mask = lambda qi, ki: jnp.ones((qi.shape[0], ki.shape[1]), bool)
         qg = q.reshape(B, Sq, nkv, rep, hd)
-        o = _blocked_attn(qg, k, v, mask, q_block, rules)
+        o = _blocked_attn(qg, k, v, mask, q_block, rules, scale=scale)
         out = jnp.einsum("bqhd,hdm->bqm", o, p["wo"])
         return shard(rules, out, "batch", None, None), None
 
@@ -272,8 +285,9 @@ def attention(
     v = jnp.einsum("bsd,dhk->bshk", x, p["wv"])
     k = shard(rules, k, "batch", None, "kv_heads", None)
     v = shard(rules, v, "batch", None, "kv_heads", None)
-    q = apply_rope(q, pos, cfg.rope_theta, cfg.mrope)
-    k = apply_rope(k, pos, cfg.rope_theta, cfg.mrope)
+    if cfg.rope:
+        q = apply_rope(q, pos, cfg.rope_theta, cfg.mrope)
+        k = apply_rope(k, pos, cfg.rope_theta, cfg.mrope)
     qg = q.reshape(B, Sq, nkv, rep, hd)
 
     new_cache = None
@@ -308,7 +322,7 @@ def attention(
             valid = k_idx <= cache_pos
             if window is not None:
                 valid = valid & (k_idx > cache_pos - window)
-        s = jnp.einsum("bqgrd,bkgd->bgrqk", qg.astype(jnp.float32) * hd**-0.5,
+        s = jnp.einsum("bqgrd,bkgd->bgrqk", qg.astype(jnp.float32) * scale,
                        K.astype(jnp.float32))
         s = jnp.where(valid[None, None, None, None, :], s, -1e30)
         pr = jax.nn.softmax(s, axis=-1)
@@ -327,9 +341,10 @@ def attention(
                 and Sq >= 4 * n_model and kv_override is None):
             # heads unshardable → sequence-parallel attention (see above)
             o = _seq_parallel_attn(qg, k, v, mask, q_block, rules,
-                                   window=window)
+                                   window=window, scale=scale)
         else:
-            o = _blocked_attn(qg, k, v, mask, q_block, rules, window=window)
+            o = _blocked_attn(qg, k, v, mask, q_block, rules, window=window,
+                              scale=scale, remat=remat_blocks)
 
     out = jnp.einsum("bqhd,hdm->bqm", o, p["wo"])
     return shard(rules, out, "batch", None, None), new_cache
@@ -377,7 +392,13 @@ def init_moe(key, cfg: ModelConfig, dtype):
         "wg": _init(ks[2], (mc.n_experts, d, fe), d**-0.5, dtype),
         "wo": _init(ks[3], (mc.n_experts, fe, d), fe**-0.5, dtype),
     }
-    if mc.n_shared:
+    if mc.dropless:
+        # the held experts only; the router keeps all n_experts outputs
+        for name in ("wi", "wg", "wo"):
+            p[name] = p[name][mc.first_held:mc.first_held + mc.held]
+    if mc.d_shared:
+        p["shared"] = init_mlp(ks[4], d, mc.d_shared, True, dtype)
+    elif mc.n_shared:
         p["shared"] = init_mlp(ks[4], d, fe * mc.n_shared, True, dtype)
     return p
 
@@ -530,6 +551,78 @@ def moe(p, x, cfg: ModelConfig, rules) -> Tuple[jax.Array, jax.Array]:
     return out.reshape(B, S, D), aux
 
 
+def _held_rows(local, n_held: int, rows: int):
+    """The first ``rows`` of the token–expert pairs sorted by held-expert
+    id (pairs for experts not held here sort last): (order, group sizes
+    clipped to ``rows``)."""
+    order = jnp.argsort(local, stable=True)[:rows]
+    sizes = jnp.bincount(local, length=n_held + 1)[:n_held].astype(jnp.int32)
+    # a group that would run past `rows` is cut at it (only where the
+    # pairs outnumber the rows, which `moe_dropless` never lets happen)
+    ends = jnp.minimum(jnp.cumsum(sizes), rows)
+    return order, jnp.diff(ends, prepend=0).astype(jnp.int32)
+
+
+def moe_dropless(p, x, cfg: ModelConfig) -> Tuple[jax.Array, Dict[str, jax.Array]]:
+    """Dropless token-choice MoE over the experts this layer holds
+    (Granite 4.0-H's ``block_sparse_moe`` as one expert-parallel share).
+
+    The router keeps all ``n_experts`` outputs; each token takes its top-k
+    router logits, and its gates are a softmax over those k logits.  The
+    layer holds experts ``[first_held, first_held + n_held)`` and returns,
+    for every token, the gated sum of its held experts' SwiGLU outputs,
+    ``Σ_{e ∈ top-k ∩ held} g_e · W2_e (silu(W1_e x) ⊙ W3_e x)``: what the
+    absent experts add is another chip's part of the sum.  No token is
+    dropped: the pairs routed here are sorted by expert and run through
+    grouped matmuls (`jax.lax.ragged_dot`) over a buffer of ``rows`` pairs,
+    sized for the expected load with room to spare; a step whose load
+    exceeds it takes a second branch whose buffer holds every pair that
+    can be routed here (each token picks at most min(k, n_held) of them).
+    The shared expert is not part of this layer (`_apply_layer`).
+
+    Returns ``(out, counts)``: counts of the token–expert pairs routed to
+    held experts (``assigned``) and of those left uncomputed
+    (``dropped``, zero by construction)."""
+    mc = cfg.moe
+    B, S, D = x.shape
+    E, K, held = mc.n_experts, mc.top_k, mc.held
+    T = B * S
+    xt = x.reshape(T, D)
+    logits = xt.astype(jnp.float32) @ p["router"].astype(jnp.float32)
+    top, ids = jax.lax.top_k(logits, K)
+    gates = jax.nn.softmax(top, axis=-1)                    # (T, K)
+    local = (ids - mc.first_held).reshape(-1)
+    mine = (local >= 0) & (local < held)
+    local = jnp.where(mine, local, held)                     # held ⇒ not here
+    assigned = jnp.sum(mine.astype(jnp.int32))
+    flat_gates = jnp.where(mine, gates.reshape(-1), 0.0)
+
+    def run(rows: int):
+        order, sizes = _held_rows(local, held, rows)
+        tok = order // K
+        # rows past the groups are left undefined by a TPU's ragged_dot,
+        # forward and backward: select them out on both sides
+        live = (jnp.arange(rows) < jnp.sum(sizes))[:, None]
+        xe = jnp.where(live, xt[tok], 0)                      # (rows, D)
+        h = jax.lax.ragged_dot(xe, p["wi"], sizes)
+        g = jax.lax.ragged_dot(xe, p["wg"], sizes)
+        ye = jax.lax.ragged_dot((jax.nn.silu(g) * h).astype(xe.dtype),
+                                p["wo"], sizes)
+        ye = jnp.where(live, ye.astype(jnp.float32), 0.0) * flat_gates[order][:, None]
+        out = jnp.zeros((T, D), jnp.float32).at[tok].add(ye)
+        return out.astype(x.dtype), assigned - jnp.sum(sizes)
+
+    full = T * min(K, held)
+    expected = -(-2 * T * K * held // E)                  # twice the mean load
+    rows = min(full, max(128, -(-expected // 128) * 128))
+    if rows == full:
+        out, dropped = run(full)
+    else:
+        out, dropped = jax.lax.cond(assigned <= rows, lambda: run(rows),
+                                    lambda: run(full))
+    return out.reshape(B, S, D), {"assigned": assigned, "dropped": dropped}
+
+
 # --------------------------------------------------------------------------
 # Mamba2 (SSD)
 # --------------------------------------------------------------------------
@@ -543,6 +636,8 @@ def init_mamba(key, cfg: ModelConfig, dtype):
     return {
         "in_proj": _init(ks[0], (d, 2 * di + 2 * sc.d_state + nh), d**-0.5, dtype),
         "conv_w": _init(ks[1], (sc.conv_width, conv_dim), 0.5, dtype),
+        **({"conv_b": _init(ks[2], (conv_dim,), 0.1, dtype)}
+           if sc.conv_bias else {}),
         "A_log": jnp.zeros((nh,), jnp.float32) + jnp.log(jnp.arange(1, nh + 1, dtype=jnp.float32)),
         "D": jnp.ones((nh,), jnp.float32),
         "dt_bias": jnp.zeros((nh,), jnp.float32),
@@ -653,6 +748,8 @@ def mamba(
         seq[:, i : i + S, :] * p["conv_w"][i][None, None, :]
         for i in range(sc.conv_width)
     )
+    if sc.conv_bias:
+        conv = conv + p["conv_b"]
     conv = jax.nn.silu(conv)
     xc, Bc, Cc = jnp.split(conv, [di, di + N], axis=-1)
     xh = xc.reshape(B, S, H, hd)

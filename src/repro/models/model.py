@@ -12,6 +12,7 @@ the token embeddings and M-RoPE positions are used.
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -125,22 +126,37 @@ def _apply_layer(
     cache: Optional[Params],
     cache_pos,
     enc_out: Optional[jax.Array],
+    remat_blocks: bool = False,
 ) -> Tuple[jax.Array, Optional[Params], jax.Array]:
-    aux = jnp.zeros((), jnp.float32)
+    """One layer; returns ``(h, new_cache, aux)`` with ``aux`` the
+    (router aux loss, token–expert pairs routed to held experts, pairs
+    dropped) of a dropless expert layer, zeros elsewhere.
+
+    Residual branches are scaled by ``cfg.residual_multiplier``
+    (Granite 4.0-H: ``h ← h + 0.22·mixer(rmsnorm(h))`` and
+    ``h ← h + 0.22·(moe(rmsnorm(h)) + shared(rmsnorm(h)))``); the mixer runs
+    under the device scope ``attn`` or ``mamba`` and the expert layer under
+    ``moe``."""
+    zero = jnp.zeros((), jnp.int32)
+    aux = (jnp.zeros((), jnp.float32), zero, zero)
+    res = (lambda y: y) if cfg.residual_multiplier == 1.0 else (
+        lambda y: y * jnp.asarray(cfg.residual_multiplier, y.dtype))
     x = L.rmsnorm(lp["ln1"], h, cfg.norm_eps)
     if spec.mixer == "attn":
         kv = (cache["k"], cache["v"]) if cache is not None else None
-        out, new_kv = L.attention(
-            lp["attn"], x, cfg, rules, pos,
-            window=spec.window,
-            cache=kv, cache_pos=cache_pos,
-        )
+        with jax.named_scope("attn"):
+            out, new_kv = L.attention(
+                lp["attn"], x, cfg, rules, pos,
+                window=spec.window,
+                cache=kv, cache_pos=cache_pos, remat_blocks=remat_blocks,
+            )
         new_cache = {"k": new_kv[0], "v": new_kv[1]} if (cache is not None) else None
     else:
-        out, new_state = L.mamba(lp["mamba"], x, cfg, rules,
-                                 cache=cache if cache is not None else None)
+        with jax.named_scope("mamba"):
+            out, new_state = L.mamba(lp["mamba"], x, cfg, rules,
+                                     cache=cache if cache is not None else None)
         new_cache = new_state if cache is not None else None
-    h = h + out
+    h = h + res(out)
 
     if enc_out is not None and spec.mixer == "attn" and "xattn" in lp:
         xq = L.rmsnorm(lp["ln_x"], h, cfg.norm_eps)
@@ -155,9 +171,16 @@ def _apply_layer(
         h = h + L.mlp(lp["mlp"], x2, cfg.mlp_gated, rules)
     elif spec.ffn == "moe":
         x2 = L.rmsnorm(lp["ln2"], h, cfg.norm_eps)
-        out, a = L.moe(lp["moe"], x2, cfg, rules)
-        h = h + out
-        aux = aux + a
+        with jax.named_scope("moe"):
+            if cfg.moe.dropless:
+                out, counts = L.moe_dropless(lp["moe"], x2, cfg)
+                if "shared" in lp["moe"]:
+                    out = out + L.mlp(lp["moe"]["shared"], x2, True, rules)
+                aux = (aux[0], counts["assigned"], counts["dropped"])
+            else:
+                out, a = L.moe(lp["moe"], x2, cfg, rules)
+                aux = (aux[0] + a, zero, zero)
+        h = h + res(out)
     return h, new_cache, aux
 
 
@@ -195,15 +218,28 @@ def forward(
     frames: Optional[jax.Array] = None,   # (B, enc_seq, D) audio stub
     remat: bool = True,
     return_hidden: bool = False,          # skip unembed (fused-CE train path)
-) -> Tuple[jax.Array, Optional[Params], jax.Array]:
-    """Returns (logits, new_cache, aux_loss).
+    remat_layers: bool = False,
+    return_counts: bool = False,
+):
+    """Returns (logits, new_cache, aux_loss), and with ``return_counts``
+    also the dropless expert layers' summed ``(assigned, dropped)``
+    token–expert pair counts.
 
     Modes: train (cache=None), prefill (cache given, S>1, cache_pos=0),
     decode (cache given, S==1, cache_pos=scalar position).
+    ``remat_layers`` rematerializes each layer on its own in the backward
+    pass (instead of a whole group), and each attention query block's
+    scores, so a backward pass holds one layer's activations and one
+    block's scores at a time.  Embeddings are scaled by
+    ``cfg.embedding_multiplier`` (or by √d_model for tied embeddings
+    without one); the head (final norm, unembedding, ÷ logits_scaling)
+    runs under the device scope ``head``.
     """
     B, S = tokens.shape
     h = jnp.take(p["embed"], tokens, axis=0)
-    if cfg.tie_embeddings:
+    if cfg.embedding_multiplier != 1.0:
+        h = h * jnp.asarray(cfg.embedding_multiplier, h.dtype)
+    elif cfg.tie_embeddings:
         h = h * jnp.asarray(cfg.d_model**0.5, h.dtype)
     if prefix_embeds is not None:
         h = jnp.concatenate([prefix_embeds.astype(h.dtype), h], axis=1)
@@ -226,19 +262,25 @@ def forward(
     def group_body(h, xs):
         gp, gcache = xs
         new_caches = {}
-        aux_total = jnp.zeros((), jnp.float32)
+        aux_total = (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.int32),
+                     jnp.zeros((), jnp.int32))
         for i, spec in enumerate(cfg.group):
             lp = gp[f"l{i}"]
             lc = gcache[f"l{i}"] if gcache is not None else None
-            h, nc, aux = _apply_layer(lp, spec, cfg, rules, h, pos, lc,
-                                      cache_pos, enc_out)
+            layer = functools.partial(_apply_layer, spec=spec, cfg=cfg,
+                                      rules=rules, cache_pos=cache_pos,
+                                      enc_out=enc_out,
+                                      remat_blocks=remat_layers)
+            if remat_layers and cache is None:
+                layer = jax.checkpoint(layer)
+            h, nc, aux = layer(lp, h=h, pos=pos, cache=lc)
             h = L.shard_residual(rules, h)
             new_caches[f"l{i}"] = nc
-            aux_total = aux_total + aux
+            aux_total = tuple(a + b for a, b in zip(aux_total, aux))
         return h, (new_caches if gcache is not None else None, aux_total)
 
     body = group_body
-    if remat and cache is None:
+    if remat and not remat_layers and cache is None:
         body = jax.checkpoint(group_body,
                               policy=jax.checkpoint_policies.nothing_saveable)
 
@@ -251,7 +293,7 @@ def forward(
             h, (nc, aux_g) = body(h, (gp, gc))
             auxs_l.append(aux_g)
             caches_l.append(nc)
-        auxs = jnp.stack(auxs_l)
+        auxs = tuple(jnp.stack(a) for a in zip(*auxs_l))
         new_cache = (jax.tree.map(lambda *xs: jnp.stack(xs), *caches_l)
                      if cache is not None else None)
     elif cache is not None:
@@ -262,14 +304,24 @@ def forward(
 
     if cache is not None and not decode:
         h = h[:, -1:, :]  # prefill: only last-position logits are needed
-    h = L.rmsnorm(p["final_norm"], h, cfg.norm_eps)
-    if return_hidden:
-        return h, new_cache, jnp.sum(auxs)
-    unemb = p["embed"].T if cfg.tie_embeddings else p["unembed"]
-    logits = jnp.einsum("bsd,dv->bsv", h, unemb)
-    logits = L.shard(rules, logits, "batch", None, "vocab")
-    if cfg.padded_vocab != cfg.vocab_size:
-        # mask padding slots so softmax/argmax never see them
-        pad = jnp.arange(cfg.padded_vocab) >= cfg.vocab_size
-        logits = logits + jnp.where(pad, -1e30, 0.0).astype(logits.dtype)
-    return logits, new_cache, jnp.sum(auxs)
+    counts = (jnp.sum(auxs[1]), jnp.sum(auxs[2]))
+    with jax.named_scope("head"):
+        h = L.rmsnorm(p["final_norm"], h, cfg.norm_eps)
+        if return_hidden:
+            out = (h, new_cache, jnp.sum(auxs[0]))
+            return out + (counts,) if return_counts else out
+        unemb = p["embed"].T if cfg.tie_embeddings else p["unembed"]
+        if cfg.logits_scaling != 1.0:
+            # Granite divides the logits by 16: keep them in f32
+            logits = jnp.einsum("bsd,dv->bsv", h, unemb,
+                                preferred_element_type=jnp.float32)
+            logits = logits / cfg.logits_scaling
+        else:
+            logits = jnp.einsum("bsd,dv->bsv", h, unemb)
+        logits = L.shard(rules, logits, "batch", None, "vocab")
+        if cfg.padded_vocab != cfg.vocab_size:
+            # mask padding slots so softmax/argmax never see them
+            pad = jnp.arange(cfg.padded_vocab) >= cfg.vocab_size
+            logits = logits + jnp.where(pad, -1e30, 0.0).astype(logits.dtype)
+    out = (logits, new_cache, jnp.sum(auxs[0]))
+    return out + (counts,) if return_counts else out
