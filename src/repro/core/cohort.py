@@ -266,10 +266,12 @@ class CohortEngine:
                 n, carry_last)
             for name, val in over.items():
                 self._server[name] = jnp.asarray(val)
+        # totals and frozen stats stay arrays (0-d, not numpy scalars, for a
+        # per-client scalar leaf), so that `checkpoint_view` can borrow them
         for agg, (leaf, op) in self._aggs.items():
             if op == "mean":
-                self._totals[agg] = np.sum(
-                    state[leaf].astype(np.float64), axis=0)
+                self._totals[agg] = np.asarray(np.sum(
+                    state[leaf].astype(np.float64), axis=0))
 
     def _split_carry_contract(self, spec, names, carry, batch, basisb, x0):
         if not isinstance(carry, tuple) or len(names) != len(carry):
@@ -409,12 +411,13 @@ class CohortEngine:
         for agg, (leaf, op) in self._aggs.items():
             rows = self.store.state[leaf][idx].astype(np.float64)
             if op == "mean":
-                frozen_np[agg] = self._totals[agg] - rows.sum(axis=0)
+                frozen_np[agg] = np.asarray(self._totals[agg]
+                                            - rows.sum(axis=0))
             else:  # max over the ABSENT clients (streaming ⇒ some exist)
                 mask = np.ones(self.n, bool)
                 mask[idx] = False
-                frozen_np[agg] = np.max(
-                    self.store.state[leaf][mask].astype(np.float64), axis=0)
+                frozen_np[agg] = np.asarray(np.max(
+                    self.store.state[leaf][mask].astype(np.float64), axis=0))
         self._cur = {
             "epoch": int(epoch), "idx": idx,
             "cidx": jnp.asarray(pidx, jnp.int32),
@@ -442,9 +445,9 @@ class CohortEngine:
         for agg, (leaf, op) in self._aggs.items():
             if op == "mean":
                 # totals = frozen (absent, unchanged) + updated cohort rows
-                self._totals[agg] = (cur["frozen_np"][agg]
-                                     + new_rows[leaf].astype(np.float64)
-                                     .sum(axis=0))
+                self._totals[agg] = np.asarray(
+                    cur["frozen_np"][agg]
+                    + new_rows[leaf].astype(np.float64).sum(axis=0))
         self._cur = None
 
     def server_state(self, name: str):
@@ -577,28 +580,41 @@ class CohortEngine:
         return tuple(elems)
 
     def checkpoint_payload(self):
-        """(carry_leaves, host_state) for `artifacts.save_checkpoint`.
+        """(carry_leaves, host_state) for `artifacts.save_checkpoint`, as
+        copies the caller may hold while the engine runs on.
 
         The store rows of the CURRENT cohort are its epoch-start values
         (scatter-back is lazy), the device carry holds their live values,
         and ``frozen`` is the epoch's frozen fleet contribution — together
-        exactly the state `restore` needs for a bit-exact mid-epoch resume."""
+        exactly the state `restore` needs for a bit-exact mid-epoch resume.
+        Copies, not views: the store rows mutate in place at the next epoch
+        unload, so a view would change under a payload held past the next
+        `run_chunk`."""
+        leaves, host = self.checkpoint_view()
+        return leaves, {k: np.array(v) for k, v in host.items()}
+
+    def checkpoint_view(self):
+        """`checkpoint_payload` without the copy of the host plane, for a
+        caller that consumes it before the engine runs on (the serve loop
+        writes its checkpoint before its next chunk).
+
+        ``host_state`` holds views (`np.ndarray.view`) of the store, the
+        fleet totals and the frozen epoch stats, valid until the next
+        `run_chunk` or `restore`: the unload that the next chunk may start
+        writes the cohort's rows into the store, and `restore` replaces it.
+        The carry leaves are host copies of the device carry either way:
+        its buffers are DONATED to the next chunk program."""
         if self._cur is None:
             raise RuntimeError("no rounds have run — nothing to checkpoint")
-        # copies, not views: the device carry's buffers are DONATED to the
-        # next chunk program, and the store rows mutate in place at the next
-        # epoch unload — a zero-copy np.asarray would silently corrupt the
-        # payload the moment the run continues past the checkpoint
         leaves = [np.array(l)
                   for l in jax.tree_util.tree_leaves(self._cur["carry"])]
         if self.full:
             return leaves, {}
-        host = {f"store/{k}": v.copy() for k, v in self.store.state.items()}
-        host.update({f"totals/{k}": np.array(v)
-                     for k, v in self._totals.items()})
-        host.update({f"frozen/{k}": np.array(v)
+        host = {f"store/{k}": v for k, v in self.store.state.items()}
+        host.update({f"totals/{k}": v for k, v in self._totals.items()})
+        host.update({f"frozen/{k}": v
                      for k, v in self._cur["frozen_np"].items()})
-        return leaves, host
+        return leaves, {k: v.view() for k, v in host.items()}
 
     def restore(self, t: int, carry, host_state: Optional[dict]):
         """Adopt a checkpoint taken at round ``t`` (``carry`` already

@@ -25,6 +25,8 @@ import dataclasses
 import hashlib
 import json
 import os
+import queue
+import threading
 import zipfile
 from typing import Optional
 
@@ -192,9 +194,79 @@ def _atomic_replace(tmp: str, dst: str) -> None:
         pass
 
 
+class _Sha256Tee:
+    """A write-only, non-seekable stream over an open file that hands every
+    chunk it writes to a hashing thread, so the file's sha256 is taken from
+    the bytes as they leave, not read back.
+
+    The queue holds at most four chunks (numpy writes arrays in chunks of
+    up to 16 MiB); a mutable chunk is copied before it is queued.
+    hashlib, zlib's CRC and the file write each release the GIL on large
+    buffers, so the hash runs under the write.  ``nbytes`` counts the bytes
+    written.  Leaving the ``with`` block stops and joins the thread."""
+
+    def __init__(self, f):
+        self._f = f
+        self._queue: queue.Queue = queue.Queue(maxsize=4)
+        self._hash = hashlib.sha256()
+        self._done = False
+        self.nbytes = 0
+        self._thread = threading.Thread(target=self._drain,
+                                         name="ckpt.sha256", daemon=True)
+        self._thread.start()
+
+    def _drain(self) -> None:
+        while (chunk := self._queue.get()) is not None:
+            self._hash.update(chunk)
+
+    def write(self, data) -> int:
+        if not isinstance(data, bytes):
+            data = bytes(data)
+        self._queue.put(data)
+        self._f.write(data)
+        self.nbytes += len(data)
+        return len(data)
+
+    def flush(self) -> None:
+        self._f.flush()
+
+    def hexdigest(self) -> str:
+        """The sha256 of every byte written, once the thread has hashed the
+        last of them."""
+        self._finish()
+        return self._hash.hexdigest()
+
+    def _finish(self) -> None:
+        if not self._done:
+            self._done = True
+            self._queue.put(None)
+            self._thread.join()
+
+    def __enter__(self) -> "_Sha256Tee":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self._finish()
+
+
+def _write_npz(out, entries) -> None:
+    """``(name, array-like)`` pairs as numpy's ``.npz`` (what `np.savez`
+    writes: a stored zip of ``<name>.npy`` members, zip64) through ``out``,
+    each converted to an array as its turn comes.  ``out`` is not seekable,
+    so zipfile follows each member with a data descriptor instead of
+    seeking back to patch its header: every byte leaves once, in file
+    order, and `np.load` reads the file as it reads `np.savez`'s."""
+    with zipfile.ZipFile(out, mode="w", compression=zipfile.ZIP_STORED,
+                         allowZip64=True) as zf:
+        for name, value in entries:
+            with zf.open(name + ".npy", mode="w", force_zip64=True) as member:
+                np.lib.format.write_array(member, np.asanyarray(value))
+
+
 def save_checkpoint(ckpt_dir: str, *, t: int, carry_leaves, streams: dict,
                     root_key, config_digest: str, keep: int = 3,
-                    host_state: Optional[dict] = None) -> str:
+                    host_state: Optional[dict] = None,
+                    stats: Optional[dict] = None) -> str:
     """Atomically write the service loop's full server state at round ``t``.
 
     ``carry_leaves`` is the flattened scan carry (numpy/JAX arrays, in the
@@ -204,31 +276,39 @@ def save_checkpoint(ckpt_dir: str, *, t: int, carry_leaves, streams: dict,
     the checkpoint to one serve configuration.  ``host_state`` (ckpt@2) is
     an optional dict of named host-side arrays — the cohort-streaming
     engine's client store / fleet totals / frozen epoch stats
-    (`CohortEngine.checkpoint_payload`); stacked serves omit it.  Keeps the
+    (`CohortEngine.checkpoint_view`); stacked serves omit it.  Keeps the
     newest ``keep`` checkpoints and prunes the rest.  Returns the manifest
     path.
 
-    Spans: ``ckpt.write`` (the payload, then the manifest), ``ckpt.digest``
-    (the payload's sha256, read back from disk) and ``ckpt.prune``."""
+    The payload is written once, from the arrays' own buffers: each array
+    streams into the ``.npz`` in turn, and a hashing thread takes the
+    payload's sha256 from the same bytes as they are written
+    (`_Sha256Tee`).  The digest is the sha256 of the file's bytes, as
+    `load_checkpoint` recomputes it from disk.  The payload is fsync'ed and
+    atomically renamed, then the manifest; both renames fsync the directory.
+    Everything is on disk when the call returns.  ``stats``, where given,
+    is a dict whose ``count``, ``bytes`` (payload bytes written and hashed)
+    and ``hash_wait_s`` the call adds to.
+
+    Spans: ``ckpt.write`` (the streamed payload and its fsync; then the
+    payload's rename and the manifest), ``ckpt.digest`` (the wait for the
+    hashing thread after the payload's last byte: the part of the hash the
+    write did not hide) and ``ckpt.prune``."""
     os.makedirs(ckpt_dir, exist_ok=True)
     base = _ckpt_base(ckpt_dir, t)
     host_state = host_state or {}
-    payload = {f"carry/{i}": np.asarray(leaf)
-               for i, leaf in enumerate(carry_leaves)}
-    for name, arr in streams.items():
-        payload[f"stream/{name}"] = np.asarray(arr)
-    for name, arr in host_state.items():
-        payload[f"host/{name}"] = np.asarray(arr)
-    payload["root_key"] = np.asarray(root_key)
+    entries = [(f"carry/{i}", leaf) for i, leaf in enumerate(carry_leaves)]
+    entries += [(f"stream/{name}", arr) for name, arr in streams.items()]
+    entries += [(f"host/{name}", arr) for name, arr in host_state.items()]
+    entries.append(("root_key", root_key))
     tmp = base + ".npz.tmp"
-    with spans.span("ckpt.write"):
-        with open(tmp, "wb") as f:
-            np.savez(f, **payload)
+    with open(tmp, "wb") as f, _Sha256Tee(f) as out:
+        with spans.span("ckpt.write"):
+            _write_npz(out, entries)
             f.flush()
             os.fsync(f.fileno())
-        _atomic_replace(tmp, base + ".npz")
-    with spans.span("ckpt.digest"):
-        payload_sha256 = _sha256_file(base + ".npz")
+        with spans.span("ckpt.digest") as digest_span:
+            payload_sha256 = out.hexdigest()
     manifest = {
         "schema": CKPT_SCHEMA,
         "config_digest": config_digest,
@@ -241,14 +321,19 @@ def save_checkpoint(ckpt_dir: str, *, t: int, carry_leaves, streams: dict,
         "host_state": sorted(host_state),
         "payload_sha256": payload_sha256,
     }
-    tmp = base + ".json.tmp"
     with spans.span("ckpt.write"):
+        _atomic_replace(tmp, base + ".npz")
+        tmp = base + ".json.tmp"
         with open(tmp, "w") as f:
             json.dump(manifest, f, indent=1)
             f.write("\n")
             f.flush()
             os.fsync(f.fileno())
         _atomic_replace(tmp, base + ".json")
+    if stats is not None:
+        stats["count"] += 1
+        stats["bytes"] += out.nbytes
+        stats["hash_wait_s"] += digest_span.elapsed_s
     prune_checkpoints(ckpt_dir, keep=keep)
     return base + ".json"
 

@@ -45,9 +45,14 @@ outputs to the host, which waits for the device), ``serve.sink``
 (``--metrics-out`` lines), ``serve.checkpoint`` and inside it
 ``ckpt.payload``, ``ckpt.write``, ``ckpt.digest``, ``ckpt.prune``, and the
 cohort engine's ``cohort.unload``, ``cohort.load``,
-``cohort.prefetch_wait``, ``cohort.gather``.  ``meta.retraces`` counts the
-round programs traced during the call (`rounds.trace_counts`): a warm
-restart traces none, so a non-empty entry there is a recompile.
+``cohort.prefetch_wait``, ``cohort.gather``.  ``meta.ckpt`` counts the
+call's checkpoints (``count``), the payload bytes written and hashed as they
+left (``bytes``), the host bytes the payload step copied before the write
+(``copied_bytes``: 0 on the cohort engine, which hands the write views of
+its host plane) and the total wait for the hash after each payload's last
+byte (``hash_wait_s``, the ``ckpt.digest`` spans).  ``meta.retraces``
+counts the round programs traced during the call (`rounds.trace_counts`):
+a warm restart traces none, so a non-empty entry there is a recompile.
 ``meta.server_eigh`` counts, of the rounds the call served, those whose
 server step took the μ-projection's eigendecomposition
 (`rounds.EVENT_EIGH`; `bl.proj_mu_route` skips it where a Cholesky factor
@@ -351,6 +356,20 @@ def _server_eigh(events, resumed_from: Optional[int]) -> dict:
             "of": int(served.size)}
 
 
+def _ckpt_counter() -> dict:
+    """The record's ``meta.ckpt``: checkpoints written, payload bytes written
+    and hashed in-stream, host bytes the payload step copied before the
+    write, and the total wait for the hash after the payload's last byte."""
+    return {"count": 0, "bytes": 0, "copied_bytes": 0, "hash_wait_s": 0.0}
+
+
+def _copied_bytes(host_state: dict) -> int:
+    """Bytes of a checkpoint's host plane that the payload step copied: an
+    array that owns its memory was made for the checkpoint, a view
+    (`CohortEngine.checkpoint_view`) borrows the engine's."""
+    return sum(int(v.nbytes) for v in host_state.values() if v.flags.owndata)
+
+
 def _activate_progcache(ckpt_dir: str, progcache_dir: Optional[str],
                         no_progcache: bool, log):
     """Serve-loop AOT cache policy: ON by default, rooted next to the
@@ -433,6 +452,7 @@ def _serve_cohort(exp, cell, prob: StreamProblem, *, seed: int, chunk: int,
     f_star = cohort.store_loss(prob.store, prob.x_star) if sink else None
     chunks_run = 0
     ttfr_s = None
+    ckpt = _ckpt_counter()
     try:
         while t < max_rounds:
             steps = min(chunk, max_rounds - t)
@@ -459,11 +479,13 @@ def _serve_cohort(exp, cell, prob: StreamProblem, *, seed: int, chunk: int,
                 crash.maybe_crash(t - 1)
             with spans.span("serve.checkpoint"):
                 with spans.span("ckpt.payload"):
-                    leaves, host_state = eng.checkpoint_payload()
+                    # views of the host plane: written before the next chunk
+                    leaves, host_state = eng.checkpoint_view()
+                ckpt["copied_bytes"] += _copied_bytes(host_state)
                 artifacts.save_checkpoint(
                     ckpt_dir, t=t, carry_leaves=leaves, streams=streams,
                     root_key=np.asarray(root_key), config_digest=digest,
-                    keep=keep, host_state=host_state)
+                    keep=keep, host_state=host_state, stats=ckpt)
     finally:
         eng.close()
 
@@ -511,6 +533,7 @@ def _serve_cohort(exp, cell, prob: StreamProblem, *, seed: int, chunk: int,
             "prefetch_overlap": eng.prefetch_overlap,
             "prefetch": dict(eng.metrics),
             "server_eigh": _server_eigh(streams["events"], resumed_from),
+            "ckpt": ckpt,
             "spans": spans.since(spans_before),
             "retraces": _retraces(traces_before),
         },
@@ -612,6 +635,7 @@ def serve(*, exp_name: str, cell_name: str, seed: int = 0, chunk: int = 25,
     chunks_run = 0
     waited_total = 0.0
     ttfr_s = None
+    ckpt = _ckpt_counter()
     while t < max_rounds:
         steps = min(chunk, max_rounds - t)
         if plan.trivial:
@@ -654,7 +678,7 @@ def serve(*, exp_name: str, cell_name: str, seed: int = 0, chunk: int = 25,
             artifacts.save_checkpoint(
                 ckpt_dir, t=t, carry_leaves=leaves, streams=streams,
                 root_key=np.asarray(root_key), config_digest=digest,
-                keep=keep)
+                keep=keep, stats=ckpt)
 
     evals = spec.eval_streams(batch, _evaluated(streams), f_star_of())
     led_streams = comm.CommLedger(
@@ -691,6 +715,7 @@ def serve(*, exp_name: str, cell_name: str, seed: int = 0, chunk: int = 25,
             "ttfr_s": ttfr_s,
             "progcache": cache.summary() if cache is not None else None,
             "server_eigh": _server_eigh(streams["events"], resumed_from),
+            "ckpt": ckpt,
             "spans": spans.since(spans_before),
             "retraces": _retraces(traces_before),
             **(_tree_counters(streams, basisb, resumed_from) if tree else {}),
