@@ -292,8 +292,8 @@ basis = per_layer_svd_basis(prob.params0)
 root = jax.random.PRNGKey(0)
 
 def scan_run():
-    # chunked driver without the trajectory eval (run_chunk donates its
-    # carry, so each rep pays the cheap carry init too)
+    # chunked driver without the trajectory eval (each rep starts from a
+    # fresh carry, so it pays the cheap carry init too)
     c = rounds.init_serve_carry(spec, prob.batch, basis, prob.params0)
     c, ys = rounds.run_chunk(spec, prob.batch, basis, prob.params0, c, 0,
                              STEPS, root)
@@ -514,18 +514,30 @@ for n in NS:
     print(f"OVERLAP n{n} {eng.prefetch_overlap:.4f}", flush=True)
     eng.close()
 
-# cohort==fleet bitwise parity vs the stacked engine, both reducers
+# cohort==fleet: the engine factory's pick, bitwise the stacked engine,
+# both reducers
+from repro.exp import engine, registry
+
 for sharded, tag in ((False, "vmap"), (True, "sharded")):
     n = N_PARITY
-    spec = bl2(n, n // 2)
+    cell = registry.MethodCell(
+        "BL2", "bl2", 6, basis="standard",
+        hess_comp=registry.CompressorCfg(kind="topk", k=2 * d),
+        model_comp=registry.CompressorCfg(kind="identity"), backend="cohort",
+        params=(("tau", n // 2), ("cohort", n), ("rounds_per_cohort", 2)))
+    pspec = registry.ProblemSpec(kind="synthetic_stream", n_clients=n, m=m,
+                                 d=d, lam=1e-3)
+    exp = registry.Experiment(name="cohort-fleet", figure="extra",
+                              title="cohort == fleet", paper_ref="bench",
+                              cells=(cell,), problem=pspec)
     store = client_batch.synthetic_store(0, n, m, d, lam=1e-3)
+    prob = engine.StreamProblem(spec=pspec, store=store, x0=x0, x_star=None)
+    eng = engine.make_engine(exp, cell, prob, root_key=key, sharded=sharded)
     batch = store.gather_batch(np.arange(n))
     bb = cohort.standard_basisb(d, n)
-    c0 = rounds.init_serve_carry(spec, batch, bb, x0, sharded=sharded)
-    _, ys1 = rounds.run_chunk(spec, batch, bb, x0, c0, 0, 6, key,
+    c0 = rounds.init_serve_carry(eng.spec, batch, bb, x0, sharded=sharded)
+    _, ys1 = rounds.run_chunk(eng.spec, batch, bb, x0, c0, 0, 6, key,
                               sharded=sharded)
-    eng = cohort.CohortEngine(spec, store, x0, cohort=n, rounds_per_cohort=2,
-                              root_key=key, basis="standard", sharded=sharded)
     ys2 = eng.run_chunk(0, 6)
     eng.close()
     eq = all(np.array_equal(np.asarray(a), np.asarray(b))

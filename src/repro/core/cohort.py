@@ -16,9 +16,9 @@ streams instead:
     the schedule is invariant to how rounds are batched into chunks,
     exactly like the serve driver's ``fold_in(root_key, t)`` round keys;
   * only the cohort's rows are gathered onto the device and run through
-    the cohort chunk program (`rounds.run_cohort_chunk`), with the next
-    epoch's gather + host→device transfer **double-buffered** on a
-    prefetch thread behind the current chunk's jitted scan;
+    the cohort kind of the chunk program (`rounds.chunk_program`), with
+    the next epoch's gather + host→device transfer **double-buffered** on
+    a prefetch thread behind the current chunk's jitted scan;
   * absent clients' state stays frozen per Alg. 2–3 — their contribution
     to each fleet aggregate (Σᵢ Hᵢ, Σᵢ gᵢ, max βᵢ ...) is maintained
     *incrementally* on the host (`MethodSpec.cohort_aggregates`): per
@@ -26,14 +26,15 @@ streams instead:
     running fleet totals to get the ``frozen`` contribution, and adds the
     updated rows back at epoch end.  Per-round work is therefore O(cohort),
     not O(n) — per-round wall time is flat in the fleet size (the
-    ``cohort_stream`` bench pins ≤1.15× from n=1k to n=100k).
+    ``cohort_stream`` bench read ≤1.15× from n=1k to n=100k, a CPU
+    timing from before the chip).
 
-When ``cohort >= n`` the engine drops into **full mode**: the whole fleet
-is gathered once (an identity gather) and rounds dispatch to the EXISTING
-stacked chunk program — same jitted program, same fold_in keys, same
-reducers — so the cohort==fleet configuration is bitwise-identical to the
-stacked engine on both backends (the parity pin that licenses this
-refactor, asserted by tests/test_cohort.py and the bench record).
+The engine needs a cohort smaller than the fleet.  A store-backed cell
+whose cohort covers the fleet runs on `rounds.StackedEngine` over the
+whole fleet gathered onto the device: `repro.exp.engine.make_engine` is
+the one place that chooses, and the serve loop drives either engine
+through the same contract (`carry_template`, `warm_programs`,
+`run_chunk`, `checkpoint_view`, `restore`, `close`).
 
 Checkpointing: the device carry (cohort rows + server state) is the usual
 flattened-leaves payload; the host side (store state, aggregate totals,
@@ -113,8 +114,8 @@ def store_newton_solve(store: client_batch.ClientStore, x0, iters: int = 20,
 @functools.partial(jax.jit, static_argnames=("spec", "R"))
 def _slab_extras(spec, R, batch, basisb, x0, carry):
     """`MethodSpec.cohort_init_extras` for one slab (separate program from
-    the init itself so single-slab init reuses the EXACT stacked
-    `rounds._init_jit` program — the full-mode bitwise parity pin)."""
+    the init itself, so that the init is the EXACT stacked
+    `rounds._init_jit` program)."""
     env = rounds.Env(batch=batch, basisb=basisb, x0=x0,
                      extra=spec.prepare(R, batch, basisb, x0))
     return spec.cohort_init_extras(R, env, carry)
@@ -128,9 +129,9 @@ class CohortEngine:
       store: the host-resident fleet (`client_batch.ClientStore`); its
         ``state`` plane is (re)initialized by the engine.
       x0: initial iterate (d,).
-      cohort: clients sampled per epoch.  ``cohort >= store.n`` selects
-        full mode (identity gather + the stacked chunk program — bitwise
-        the stacked engine).
+      cohort: clients sampled per epoch, at least 1 and fewer than
+        ``store.n`` (a cohort that covers the fleet is the stacked
+        engine's job).
       rounds_per_cohort: rounds a sampled cohort stays resident (the epoch
         length); higher amortizes the gather, lower refreshes participation
         across more of the fleet.
@@ -149,7 +150,7 @@ class CohortEngine:
     def __init__(self, spec, store: client_batch.ClientStore, x0, *,
                  cohort: int, rounds_per_cohort: int, root_key,
                  basis: Optional[str] = "standard", sharded: bool = False,
-                 exact: bool = True, slab: int = 4096, prefetch: bool = True):
+                 slab: int = 4096, prefetch: bool = True):
         if rounds_per_cohort < 1:
             raise ValueError(
                 f"rounds_per_cohort must be >= 1, got {rounds_per_cohort}")
@@ -163,52 +164,60 @@ class CohortEngine:
         self.rpc = int(rounds_per_cohort)
         self.root_key = root_key
         self.sharded = bool(sharded)
-        self.exact = bool(exact)
         self.slab = int(slab)
-        self.full = int(cohort) >= self.n
-        self.cohort = self.n if self.full else int(cohort)
-        if not self.full and not getattr(spec, "supports_cohort", False):
+        self.cohort = int(cohort)
+        if not getattr(spec, "supports_cohort", False):
             raise ValueError(
                 f"{type(spec).__name__} is not cohort-capable "
                 "(MethodSpec.supports_cohort is False) — absent clients' "
-                "fleet contributions cannot be frozen; run it stacked or "
-                "with cohort >= n")
-        # padded capacity: every shard holds the same number of slots
-        cap = self.cohort
-        if self.sharded and not self.full:
-            ndev = jax.local_device_count()
-            cap = ((cap + ndev - 1) // ndev) * ndev
-        self.cap = cap
+                "fleet contributions cannot be frozen; run it stacked")
         if basis not in (None, "standard"):
             raise ValueError(
                 f"cohort streaming supports the 'standard' convention basis "
                 f"or None, got {basis!r} (per-client basis arrays would "
                 "have to stream with the cohort — not implemented)")
+        if self.cohort >= self.n:
+            raise ValueError(
+                f"cohort {self.cohort} covers the fleet of {self.n}: run "
+                "it on the stacked engine (rounds.StackedEngine, which "
+                "repro.exp.engine.make_engine picks for such a cell)")
+        # padded capacity: every shard holds the same number of slots
+        cap = self.cohort
+        if self.sharded:
+            ndev = jax.local_device_count()
+            cap = ((cap + ndev - 1) // ndev) * ndev
+        self.cap = cap
         self._basis_kind = basis
         self._basis_cap = (None if basis is None
                            else standard_basisb(self.d, self.cap))
-        self._basis_full = (None if basis is None
-                            else standard_basisb(self.d, self.n))
         self._seed64 = self._sampler_seed()
-        self._aggs = dict(spec.cohort_aggregates()) if not self.full else {}
+        self._aggs = dict(spec.cohort_aggregates())
         self._totals: dict = {}
         self._server: dict = {}
         self._cur: Optional[dict] = None
         self._treedef = None
         self._is_client = None
+        self._names = None
         self.metrics = {"prefetch_wait_us": 0.0, "prefetch_work_us": 0.0,
                         "h2d_bytes": 0, "epochs_prefetched": 0,
                         "epochs_loaded": 0}
-        self._prefetch_on = bool(prefetch) and not self.full
+        self._prefetch_on = bool(prefetch)
         self._pool = (ThreadPoolExecutor(max_workers=1)
                       if self._prefetch_on else None)
         self._pf = None
         self._pf_epoch = -1
-        self._init_fleet()
 
     # ------------------------------------------------------------------
     # fleet init: slab-wise stacked init → host store + server state
     # ------------------------------------------------------------------
+    def _ready(self):
+        """Initialise the fleet on first use (`carry_template`, `run_chunk`
+        or `restore`): the serve loop builds the engine before its
+        ``serve.warm`` span and warms it inside, as it does the stacked
+        engine's init."""
+        if self._names is None:
+            self._init_fleet()
+
     def _make_basis(self, n: int):
         return (None if self._basis_kind is None
                 else standard_basisb(self.d, n))
@@ -228,10 +237,9 @@ class CohortEngine:
             basisb = self._make_basis(sn)
             R = rounds.VmapReducer(n=sn)
             # the SAME cached program the stacked serve path inits with —
-            # at one slab (== full mode at test scale) the carry is
-            # bitwise the stacked engine's carry; `serve_init` also shares
-            # the stacked path's AOT cache entries when a program cache is
-            # active
+            # at one slab the carry is bitwise the stacked engine's carry;
+            # `serve_init` also shares the stacked path's AOT cache entries
+            # when a program cache is active
             carry = rounds.serve_init(spec, R, batch, basisb, x0)
             if self._is_client is None:
                 self._split_carry_contract(spec, names, carry, batch,
@@ -323,8 +331,6 @@ class CohortEngine:
         """Epoch's sorted cohort (unique global indices) — a pure function
         of (root key, epoch): Philox keyed by ``(seed64 << 64) + epoch``,
         so the schedule never depends on chunking or on trajectory state."""
-        if self.full:
-            return np.arange(self.n, dtype=np.int64)
         n, c = self.n, self.cohort
         rng = np.random.Generator(
             np.random.Philox(key=(self._seed64 << 64) + int(epoch)))
@@ -450,33 +456,6 @@ class CohortEngine:
                     + new_rows[leaf].astype(np.float64).sum(axis=0))
         self._cur = None
 
-    def server_state(self, name: str):
-        """Live value of a server carry element.  While an epoch is
-        resident its server elements live in the (donated) device carry —
-        ``self._server`` may hold deleted buffers until the next unload —
-        so reads must go through the current carry."""
-        i = self._names.index(name)
-        if self._is_client[i]:
-            raise ValueError(f"{name!r} is client-stacked, not server state")
-        if self._cur is not None:
-            return self._cur["carry"][i]
-        return self._server[name]
-
-    def _full_carry(self):
-        elems = []
-        for name, cl in zip(self._names, self._is_client):
-            elems.append(jnp.asarray(self.store.state[name]) if cl
-                         else self._server[name])
-        return tuple(elems)
-
-    def _ensure_full_loaded(self):
-        if self._cur is not None:
-            return
-        batch = self.store.gather_batch(np.arange(self.n))
-        self._cur = {"epoch": None, "idx": np.arange(self.n),
-                     "batch": batch, "carry": self._full_carry(),
-                     "frozen_np": {}}
-
     # ------------------------------------------------------------------
     # program warming (repro.core.progcache)
     # ------------------------------------------------------------------
@@ -490,19 +469,13 @@ class CohortEngine:
         active."""
         if rounds.progcache.active() is None:
             return False
-        chunk = int(chunk)
-        rows = self.n if self.full else self.cap
+        carry = self.carry_template()
         batch = client_batch.ClientBatch(
-            A=jnp.zeros((rows,) + self.store.A.shape[1:],
+            A=jnp.zeros((self.cap,) + self.store.A.shape[1:],
                         self.store.A.dtype),
-            b=jnp.zeros((rows,) + self.store.b.shape[1:],
+            b=jnp.zeros((self.cap,) + self.store.b.shape[1:],
                         self.store.b.dtype),
             lam=self.store.lam)
-        carry = self.carry_template()
-        if self.full:
-            return rounds.warm_chunk_program(
-                self.spec, batch, self._basis_full, self.x0, carry, chunk,
-                self.root_key, sharded=self.sharded, exact=self.exact)
         # frozen templates mirror `_load_epoch`'s jnp.asarray(float64)
         # conversion so the warm signature matches the dispatch signature
         frozen = {}
@@ -512,50 +485,48 @@ class CohortEngine:
             frozen[agg] = jnp.asarray(np.zeros(shape, np.float64))
         # run_chunk cuts segments at epoch boundaries, so the first (and
         # dominant) segment length is min(chunk, rounds_per_cohort)
-        return rounds.warm_cohort_chunk_program(
+        prog, args = rounds.chunk_program(
             self.spec, batch, self._basis_cap, self.x0, carry,
-            min(chunk, self.rpc), self.root_key,
-            cidx=np.zeros(self.cap, np.int32),
-            creal=np.ones(self.cap, bool), frozen=frozen, n_global=self.n,
-            sharded=self.sharded, exact=self.exact)
+            0, min(int(chunk), self.rpc), self.root_key,
+            cohort=(np.zeros(self.cap, np.int32), np.ones(self.cap, bool),
+                    frozen, self.n),
+            sharded=self.sharded)
+        prog.resolve(*args)
+        return True
 
     # ------------------------------------------------------------------
     # driver
     # ------------------------------------------------------------------
-    def run_chunk(self, t0: int, steps: int):
+    def run_chunk(self, t0: int, steps: int, avail=None):
         """Run rounds [t0, t0+steps) and return the history streams
         ``(eval_x, CommLedger-of-streams, events)`` — the same tuple as
         `rounds.run_chunk`.  Segments are cut at epoch boundaries
         internally; any chunking of calls produces the same streams
-        (chunk-boundary invariance, pinned by tests)."""
+        (chunk-boundary invariance, pinned by tests).  ``avail`` must be
+        None: client absence is the engine's own participation draw."""
+        if avail is not None:
+            raise ValueError(
+                "cohort streaming does not take an availability schedule "
+                "— fault plans address the stacked fleet by index")
+        self._ready()
         outs = []
         t = int(t0)
         end = t + int(steps)
         while t < end:
-            if self.full:
-                self._ensure_full_loaded()
-                cur = self._cur
-                seg = end - t
-                carry, ys = rounds.run_chunk(
-                    self.spec, cur["batch"], self._basis_full, self.x0,
-                    cur["carry"], t, seg, self.root_key,
-                    sharded=self.sharded, exact=self.exact)
-            else:
-                e = t // self.rpc
-                if self._cur is None or self._cur["epoch"] != e:
-                    with spans.span("cohort.unload"):
-                        self._unload_current()
-                    with spans.span("cohort.load", epoch=e):
-                        self._load_epoch(e)
-                cur = self._cur
-                seg = min(end, (e + 1) * self.rpc) - t
-                carry, ys = rounds.run_cohort_chunk(
-                    self.spec, cur["batch"], self._basis_cap, self.x0,
-                    cur["carry"], t, seg, self.root_key,
-                    cidx=cur["cidx"], creal=cur["real"],
-                    frozen=cur["frozen"], n_global=self.n,
-                    sharded=self.sharded, exact=self.exact)
-            cur["carry"] = carry
+            e = t // self.rpc
+            if self._cur is None or self._cur["epoch"] != e:
+                with spans.span("cohort.unload"):
+                    self._unload_current()
+                with spans.span("cohort.load", epoch=e):
+                    self._load_epoch(e)
+            cur = self._cur
+            seg = min(end, (e + 1) * self.rpc) - t
+            prog, args = rounds.chunk_program(
+                self.spec, cur["batch"], self._basis_cap, self.x0,
+                cur["carry"], t, seg, self.root_key,
+                cohort=(cur["cidx"], cur["real"], cur["frozen"], self.n),
+                sharded=self.sharded)
+            cur["carry"], ys = prog(*args)
             outs.append(ys)
             t += seg
         if len(outs) == 1:
@@ -567,9 +538,9 @@ class CohortEngine:
     # ------------------------------------------------------------------
     def carry_template(self):
         """Shape/dtype template of the device carry (the serialization
-        contract the serve loop validates checkpoints against)."""
-        if self.full:
-            return self._full_carry()
+        contract the serve loop validates checkpoints against).  The first
+        call initialises the fleet."""
+        self._ready()
         elems = []
         for name, cl in zip(self._names, self._is_client):
             if cl:
@@ -602,14 +573,11 @@ class CohortEngine:
         fleet totals and the frozen epoch stats, valid until the next
         `run_chunk` or `restore`: the unload that the next chunk may start
         writes the cohort's rows into the store, and `restore` replaces it.
-        The carry leaves are host copies of the device carry either way:
-        its buffers are DONATED to the next chunk program."""
+        The carry leaves are host copies of the device carry either way."""
         if self._cur is None:
             raise RuntimeError("no rounds have run — nothing to checkpoint")
         leaves = [np.array(l)
                   for l in jax.tree_util.tree_leaves(self._cur["carry"])]
-        if self.full:
-            return leaves, {}
         host = {f"store/{k}": v for k, v in self.store.state.items()}
         host.update({f"totals/{k}": v for k, v in self._totals.items()})
         host.update({f"frozen/{k}": v
@@ -621,12 +589,7 @@ class CohortEngine:
         validated/unflattened by the caller).  The resident epoch is
         ``(t−1) // rpc`` — the epoch of the last computed round; its cohort
         resamples deterministically and its data re-gathers from the store."""
-        if self.full:
-            batch = self.store.gather_batch(np.arange(self.n))
-            self._cur = {"epoch": None, "idx": np.arange(self.n),
-                         "batch": batch, "carry": tuple(carry),
-                         "frozen_np": {}}
-            return
+        self._ready()
         host_state = host_state or {}
         frozen_np = {}
         for key, val in host_state.items():
@@ -657,6 +620,14 @@ class CohortEngine:
             "frozen_np": frozen_np,
         }
         self._prefetch_submit(e + 1)
+
+    def serve_meta(self) -> dict:
+        """The serve record's ``meta`` keys of this engine: the cohort
+        geometry and the prefetch counters."""
+        return {"cohort": self.cohort, "rounds_per_cohort": self.rpc,
+                "n_clients": self.n,
+                "prefetch_overlap": self.prefetch_overlap,
+                "prefetch": dict(self.metrics)}
 
     def close(self):
         if self._pool is not None:

@@ -69,9 +69,10 @@ from typing import Any, Callable, Optional, Tuple
 
 import numpy as np
 
-# v2: entries must come from donation-free lowerings (`rounds._chunk_jit_aot`
-# and twins) — v1 entries serialized donating programs, which corrupt chained
-# carry calls after deserialization, so they are invalidated wholesale
+# v2: entries must come from donation-free lowerings (every program of
+# `rounds._backend` is one) — v1 entries serialized donating programs, which
+# corrupt chained carry calls after deserialization, so they are invalidated
+# wholesale
 SCHEMA_VERSION = 2
 #: manifest schema tag of one AOT cache entry (re-exported by
 #: `repro.exp.artifacts` next to the checkpoint schemas; validated by

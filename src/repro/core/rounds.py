@@ -41,8 +41,10 @@ into three pieces:
   3. **Drivers** — jitted `lax.scan`s over rounds.  A `MethodSpec` (see
      `repro.core.specs`) supplies `prepare/init/step`; the drivers never
      know which algorithm they are running.  ONE chunked scan program
-     underlies both entry points — the carry is an explicit, DONATED
-     input/output and per-round PRNG keys are explicit scan inputs:
+     per kind (`_chunk_body`: the stacked kind, and the cohort kind of
+     `repro.core.cohort`) underlies every entry point — the carry is an
+     explicit input/output, never donated, and per-round PRNG keys are
+     explicit scan inputs:
 
        * `run_rounds`  — the batch driver (figure path): feeds its
          pre-split key array through one chunk (or one chunk per
@@ -51,7 +53,8 @@ into three pieces:
        * `run_chunk` / `init_serve_carry` — the *service-loop* driver:
          rounds run in bounded chunks so control returns to the host
          between chunks (fault injection, checkpointing — see
-         `repro.launch.fed_serve`).  Per-round keys are
+         `repro.launch.fed_serve`, which drives them through
+         `StackedEngine`).  Per-round keys are
          ``fold_in(root_key, t)`` of the absolute round index, so a
          trajectory is invariant to how rounds are batched into chunks —
          the crash-safe bit-exact-resume contract.
@@ -986,10 +989,9 @@ def run_rounds(spec, batch, basisb, x0, f_star, keys, *,
     This is the chunked service-loop driver (`run_chunk`) under another
     entry point — one init program plus one scan program per chunk length,
     with per-round keys supplied explicitly (the batch path pre-splits
-    them; the serve path derives them by `fold_in`).  The scan carry is
-    DONATED between chunks, so per-chunk state never copies."""
+    them; the serve path derives them by `fold_in`)."""
     steps = int(keys.shape[0])
-    init, chunk = _serve_backend(spec, batch, basisb, x0, sharded, exact)
+    init, chunk = _backend(spec, batch, basisb, x0, sharded, exact)
     carry = init(batch, basisb, x0)
     chunk_len = steps if stream is None else max(1, int(stream.every))
     parts = []
@@ -1134,8 +1136,24 @@ def _carry_flags_key_cached(spec, batch, basisb, x0):
     return fk
 
 
-def _chunk_body(spec, R: Reducer, batch, basisb, x0, carry, ts, keys, avail):
-    _note_trace("chunk")
+def _chunk_body(spec, R: Reducer, batch, basisb, x0, carry, ts, keys, avail,
+                cidx=None, creal=None, frozen=None, *, n_global=None):
+    """One chunk of rounds: a scan of ``spec.step`` over ``(ts, keys,
+    avail)``, the carry in and out.
+
+    The stacked kind (``n_global`` None) shows spec code the fleet's
+    reducer ``R``.  The cohort kind wraps the cohort-capacity reducer ``R``
+    in a `CohortReducer` over the slots' global client indices ``cidx``,
+    their padding mask ``creal`` and the absent clients' ``frozen`` fleet
+    statistics, all constant for the chunk (the cohort engine cuts chunks
+    at epoch boundaries); it takes no availability schedule (``avail``
+    None)."""
+    if n_global is None:
+        _note_trace("chunk")
+    else:
+        _note_trace("cohort_chunk")
+        R = CohortReducer(inner=R, idx=cidx, real=creal, frozen=frozen,
+                          n_global=n_global)
     env = Env(batch=batch, basisb=basisb, x0=x0,
               extra=spec.prepare(R, batch, basisb, x0))
 
@@ -1147,34 +1165,23 @@ def _chunk_body(spec, R: Reducer, batch, basisb, x0, carry, ts, keys, avail):
     return jax.lax.scan(step, carry, (ts, keys, avail))
 
 
-# the carry is DONATED: its buffers are reused for the output carry, which
-# kills the per-chunk state copy.  Callers must treat the argument as
-# consumed and continue from the returned carry (every driver in this repo
-# reassigns `carry, ys = chunk(...)`).
-_chunk_jit = functools.partial(
-    jax.jit, static_argnames=("spec", "R"),
-    donate_argnames=("carry",))(_chunk_body)
-
-# AOT twin WITHOUT donation, used for every program that goes through the
-# progcache (`_AotProgram`).  Executables that came back through
-# serialize/deserialize mishandle donated carry buffers once calls are
-# CHAINED through engine state (outputs aliased into donated memory feed
-# the next call): outputs go bitwise-wrong with bitwise-identical inputs,
-# while the same executable on fresh copies is correct.  Donation never
-# affects values, only buffer reuse, so compiling the cache path from a
-# donation-free lowering pins hit == miss == uncached bitwise — at the cost
-# of one in-flight carry copy per chunk call.  REPRO_PROGCACHE=0 restores
-# the donating fast path above.
-_chunk_jit_aot = functools.partial(
-    jax.jit, static_argnames=("spec", "R"))(_chunk_body)
+# the one single-device chunk program of both kinds.  It donates nothing:
+# executables that came back through serialize/deserialize mishandle
+# donated carry buffers once calls are CHAINED through engine state
+# (outputs aliased into donated memory feed the next call) — outputs go
+# bitwise-wrong with bitwise-identical inputs.  Donation never changes
+# values, so one donation-free program keeps a cache hit == a miss ==
+# the uncached call bitwise, at one in-flight carry copy per chunk call.
+_chunk_program = functools.partial(
+    jax.jit, static_argnames=("spec", "R", "n_global"))(_chunk_body)
 
 
 # --------------------------------------------------------------------------
 # AOT program dispatch (repro.core.progcache tier 1)
 # --------------------------------------------------------------------------
 # resolved executables, keyed (kind, spec, backend scope, abstract arg sig)
-# — module-level so the memo survives `_serve_backend`'s per-dispatch
-# wrapper construction (a closure-held memo would be rebuilt every call)
+# — module-level so the memo survives `_backend`'s per-dispatch wrapper
+# construction (a closure-held memo would be rebuilt every call)
 _AOT_PROGS: dict = {}
 
 
@@ -1185,31 +1192,28 @@ def clear_aot_memo() -> None:
 
 
 class _AotProgram:
-    """One serve program behind cache-aware dispatch.
+    """One serve program, ``fn(*static, *args, **static_kw)`` for a jitted
+    ``fn``, behind cache-aware dispatch.
 
-    With no active `progcache` cache, ``__call__`` IS the plain jitted
-    ``fast`` path — the pre-subsystem dispatch, byte for byte.  With a
+    With no active `progcache` cache, ``__call__`` calls ``fn``.  With a
     cache active, the first call per abstract argument signature resolves
-    an AOT executable — deserialized from disk on a hit, compiled from the
-    *identical* lowering on any miss and persisted — and every later call
-    reuses it.  AOT lowerings are DONATION-FREE (see `_chunk_jit_aot`):
-    deserialized executables corrupt chained donated-carry calls, and
-    donation is invisible to values, so the cache path trades the in-place
-    carry update for a bitwise hit == miss == uncached guarantee.  Callers
-    must still treat the carry argument as consumed — which path runs is a
-    cache-availability detail.
+    an AOT executable — deserialized from disk on a hit, compiled from
+    ``fn``'s own lowering on any miss and persisted — and every later call
+    reuses it.  ``fn`` donates nothing (see `_chunk_program`), so the two
+    paths are bitwise the same program.
 
     ``resolve`` is the execution-free half (lower/load only): the serve
     loop warms programs through it *before* checkpoint restore, which is
     what moves compile latency out of time-to-first-round."""
 
-    def __init__(self, kind: str, spec, scope: tuple, fast: Callable,
-                 lower: Callable):
+    def __init__(self, kind: str, spec, scope: tuple, fn: Callable,
+                 *static, **static_kw):
         self.kind = kind
         self._spec = spec
         self._scope = scope
-        self._fast = fast
-        self._lower = lower
+        self._fn = fn
+        self._static = static
+        self._static_kw = static_kw
 
     def resolve(self, *args):
         """The compiled executable for these (concrete) args, or None when
@@ -1226,7 +1230,8 @@ class _AotProgram:
                 key_parts=(self.kind, progcache.source_digest(),
                            progcache.fingerprint(self._spec),
                            progcache.fingerprint(self._scope), repr(sig)),
-                lower=lambda: self._lower(*args),
+                lower=lambda: self._fn.lower(*self._static, *args,
+                                             **self._static_kw),
                 aux={"scope": [str(s) for s in self._scope]})
             _AOT_PROGS[memo_key] = prog
         return prog
@@ -1234,15 +1239,12 @@ class _AotProgram:
     def __call__(self, *args):
         prog = self.resolve(*args)
         if prog is None:
-            return self._fast(*args)
+            return self._fn(*self._static, *args, **self._static_kw)
         return prog(*args)
 
 
 def _vmap_init_program(spec, R: Reducer) -> _AotProgram:
-    return _AotProgram(
-        "serve_init", spec, ("vmap", R.n),
-        functools.partial(_init_jit, spec, R),
-        functools.partial(_init_jit.lower, spec, R))
+    return _AotProgram("serve_init", spec, ("vmap", R.n), _init_jit, spec, R)
 
 
 def serve_init(spec, R: Reducer, batch, basisb, x0):
@@ -1254,12 +1256,14 @@ def serve_init(spec, R: Reducer, batch, basisb, x0):
 
 
 @functools.lru_cache(maxsize=None)
-def _sharded_chunk_fns(spec, R: "ShardMapReducer", mesh, flags_key):
+def _sharded_programs(spec, R: "ShardMapReducer", mesh, flags_key, n_global):
     """Jitted shard_map (init, chunk) programs whose carry crosses the
     shard_map boundary: client-stacked carry leaves shard over the mesh,
-    everything else is replicated (per `carry_client_flags`).  The chunk
-    program donates its carry argument like the vmap path; its AOT twin
-    (third element) is donation-free like `_chunk_jit_aot`."""
+    everything else is replicated (per `carry_client_flags`).  The cohort
+    kind (``n_global`` set) shards the COHORT axis in the client axis's
+    place and takes three more arguments: the slots' global indices and
+    padding mask shard with it, the frozen fleet aggregates are replicated
+    server state (every shard needs them to finish a fleet mean/max)."""
     from jax.sharding import PartitionSpec as P
 
     from repro.sharding.rules import CLIENT_AXIS, client_chunk_specs
@@ -1270,26 +1274,29 @@ def _sharded_chunk_fns(spec, R: "ShardMapReducer", mesh, flags_key):
     in_specs, out_specs = client_chunk_specs(
         carry_specs,
         basis_replicated=getattr(spec, "basis_replicated", False))
-    body = jax.shard_map(
-        functools.partial(_chunk_body, spec, R), mesh=mesh,
-        in_specs=in_specs, out_specs=out_specs, check_vma=False)
+    if n_global is not None:
+        in_specs += (P(CLIENT_AXIS), P(CLIENT_AXIS), P())
     init = jax.jit(jax.shard_map(
         functools.partial(_init_body, spec, R), mesh=mesh,
         in_specs=in_specs[:3], out_specs=carry_specs, check_vma=False))
-    # (batch, basisb, x0, carry, ts, keys, avail) — carry is argument 3
-    chunk = jax.jit(body, donate_argnums=(3,))
-    chunk_aot = jax.jit(body)
-    return init, chunk, chunk_aot
+    chunk = jax.jit(jax.shard_map(
+        functools.partial(_chunk_body, spec, R, n_global=n_global),
+        mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False))
+    return init, chunk
 
 
-def _serve_backend(spec, batch, basisb, x0, sharded: bool, exact: bool):
+def _backend(spec, batch, basisb, x0, sharded: bool, exact: bool,
+             n_global: Optional[int] = None):
+    """The (init, chunk) programs of one chunk kind: the stacked kind
+    (``n_global`` None) over the fleet in ``batch``, or the cohort kind
+    over ``batch.n`` cohort slots of a fleet of ``n_global`` clients."""
+    kind = "serve_chunk" if n_global is None else "cohort_chunk"
     if not sharded:
         R = VmapReducer(n=batch.n)
+        scope = ("vmap", R.n if n_global is None else n_global)
         return (_vmap_init_program(spec, R),
-                _AotProgram("serve_chunk", spec, ("vmap", R.n),
-                            functools.partial(_chunk_jit, spec, R),
-                            functools.partial(_chunk_jit_aot.lower, spec,
-                                              R)))
+                _AotProgram(kind, spec, scope, _chunk_program, spec, R,
+                            n_global=n_global))
     from repro.launch.mesh import make_client_mesh
     from repro.sharding.rules import mesh_fingerprint
 
@@ -1297,10 +1304,11 @@ def _serve_backend(spec, batch, basisb, x0, sharded: bool, exact: bool):
     R = ShardMapReducer(n=batch.n, ndev=ndev, exact=exact,
                         plan=getattr(spec, "reduce_plan", ReducePlan()))
     fk = _carry_flags_key_cached(spec, batch, basisb, x0)
-    init, chunk, chunk_aot = _sharded_chunk_fns(spec, R, mesh, fk)
-    scope = ("shmap", ndev, exact, mesh_fingerprint(mesh))
-    return (_AotProgram("serve_init", spec, scope, init, init.lower),
-            _AotProgram("serve_chunk", spec, scope, chunk, chunk_aot.lower))
+    init, chunk = _sharded_programs(spec, R, mesh, fk, n_global)
+    scope = (("shmap", ndev, exact, mesh_fingerprint(mesh))
+             + (() if n_global is None else (n_global,)))
+    return (_AotProgram("serve_init", spec, scope, init),
+            _AotProgram(kind, spec, scope, chunk))
 
 
 def init_serve_carry(spec, batch, basisb, x0, *, sharded: bool = False,
@@ -1311,8 +1319,48 @@ def init_serve_carry(spec, batch, basisb, x0, *, sharded: bool = False,
     stores the flattened leaves and restore validates them against a fresh
     `init_serve_carry` shape evaluation, so an incompatible spec change
     fails loudly instead of resuming garbage."""
-    init, _ = _serve_backend(spec, batch, basisb, x0, sharded, exact)
+    init, _ = _backend(spec, batch, basisb, x0, sharded, exact)
     return init(batch, basisb, x0)
+
+
+def chunk_program(spec, batch, basisb, x0, carry, t0: int, steps: int,
+                  root_key, *, avail=None, cohort=None, sharded: bool = False,
+                  exact: bool = True):
+    """The chunk program for rounds [t0, t0 + steps) and its arguments:
+    ``program(*args)`` runs the rounds, ``program.resolve(*args)`` only
+    loads or compiles the program (the engines warm through it before
+    checkpoint restore).
+
+    Per-round keys are ``fold_in(root_key, t)`` of the absolute round
+    index.  ``cohort`` None is the stacked kind, whose ``avail`` is an
+    optional ``(steps, n)`` availability schedule (all-ones by default,
+    bitwise no fault layer at all).  ``cohort = (cidx, creal, frozen,
+    n_global)`` is the cohort kind: ``batch`` and ``carry`` hold the cohort
+    slots, ``cidx`` their global client indices, ``creal`` their padding
+    mask and ``frozen`` the fleet statistics of the epoch's ABSENT
+    clients; it takes no schedule."""
+    ts = jnp.arange(t0, t0 + steps)
+    # the fold_in happens outside the scan (vmapped over the chunk's round
+    # indices — threefry is elementwise, so this is bitwise the in-scan
+    # per-round fold_in) so the scan body takes explicit keys: the batch
+    # driver feeds the same program its pre-split key array instead
+    keys = jax.vmap(lambda t: jax.random.fold_in(root_key, t))(ts)
+    if cohort is not None:
+        cidx, creal, frozen, n_global = cohort
+        _, prog = _backend(spec, batch, basisb, x0, sharded, exact,
+                           int(n_global))
+        return prog, (batch, basisb, x0, carry, ts, keys, None,
+                      jnp.asarray(cidx, jnp.int32), jnp.asarray(creal, bool),
+                      frozen)
+    if avail is None:
+        avail = jnp.ones((steps, batch.n), bool)
+    avail = jnp.asarray(avail, bool)
+    if avail.shape != (steps, batch.n):
+        raise ValueError(
+            f"avail schedule must be (steps, n) = ({steps}, {batch.n}), "
+            f"got {avail.shape}")
+    _, prog = _backend(spec, batch, basisb, x0, sharded, exact)
+    return prog, (batch, basisb, x0, carry, ts, keys, avail)
 
 
 def run_chunk(spec, batch, basisb, x0, carry, t0: int, steps: int, root_key,
@@ -1330,171 +1378,91 @@ def run_chunk(spec, batch, basisb, x0, carry, t0: int, steps: int, root_key,
     reach specs as `RoundCtx.avail`.  An all-ones schedule (the default) is
     bitwise-equivalent to no fault layer at all.
 
-    The input ``carry`` is CONSUMED: continue (or checkpoint) from the
-    returned carry, never the argument.  On the fast (no-progcache) path
-    its buffers are donated outright — reuse raises jax's deleted-buffer
-    error; under an active program cache the AOT executable is
-    donation-free (see `_chunk_jit_aot`), but the consumed contract is the
-    same on both paths.
+    The program donates nothing (see `_chunk_program`): the input carry
+    stays valid, and a caller that goes on from the returned carry holds
+    one more carry only for the length of the call.
 
     Chunk programs compile once per (spec, backend, chunk length); the
     service loop reuses one length for every full chunk, so only a trailing
     partial chunk costs a second compile."""
-    ts = jnp.arange(t0, t0 + steps)
-    # the fold_in happens outside the scan (vmapped over the chunk's round
-    # indices — threefry is elementwise, so this is bitwise the in-scan
-    # per-round fold_in) so the scan body takes explicit keys: the batch
-    # driver feeds the same program its pre-split key array instead
-    keys = jax.vmap(lambda t: jax.random.fold_in(root_key, t))(ts)
-    if avail is None:
-        avail = jnp.ones((steps, batch.n), bool)
-    avail = jnp.asarray(avail, bool)
-    if avail.shape != (steps, batch.n):
-        raise ValueError(
-            f"avail schedule must be (steps, n) = ({steps}, {batch.n}), "
-            f"got {avail.shape}")
-    _, chunk = _serve_backend(spec, batch, basisb, x0, sharded, exact)
-    return chunk(batch, basisb, x0, carry, ts, keys, avail)
+    prog, args = chunk_program(spec, batch, basisb, x0, carry, t0, steps,
+                               root_key, avail=avail, sharded=sharded,
+                               exact=exact)
+    return prog(*args)
 
 
-def warm_chunk_program(spec, batch, basisb, x0, carry, steps: int, root_key,
-                       *, sharded: bool = False, exact: bool = True) -> bool:
-    """Resolve the serve (init, chunk) programs for this cell — load from
-    the active program cache or compile-and-persist — WITHOUT executing a
-    round.  ``carry`` is a template (e.g. `init_serve_carry`'s output) used
-    only for its shapes; nothing is donated or mutated.  The serve loop
-    calls this before checkpoint restore so a warm restart's
-    time-to-first-round contains no compilation.  Returns False (no-op)
-    when no cache is active."""
-    if progcache.active() is None:
-        return False
-    steps = int(steps)
-    init, chunk = _serve_backend(spec, batch, basisb, x0, sharded, exact)
-    init.resolve(batch, basisb, x0)
-    ts = jnp.arange(0, steps)
-    keys = jax.vmap(lambda t: jax.random.fold_in(root_key, t))(ts)
-    avail = jnp.ones((steps, batch.n), bool)
-    chunk.resolve(batch, basisb, x0, carry, ts, keys, avail)
-    return True
+class StackedEngine:
+    """The whole fleet on the device, behind the engine contract the serve
+    loop drives (`repro.core.cohort.CohortEngine` is the other engine):
+    `init_serve_carry` and `run_chunk` over one client-stacked batch, with
+    per-round keys ``fold_in(root_key, t)``.
 
+    The engine owns the carry.  Its first `carry_template` runs the init
+    program once; a fresh run goes on from that carry itself and `restore`
+    replaces it, so the device never holds a template beside the carry.
+    Each chunk's carry is a new buffer (nothing is donated), and the engine
+    drops the old one."""
 
-# ==========================================================================
-# Cohort-streaming chunk programs (repro.core.cohort)
-# ==========================================================================
-def _cohort_chunk_body(spec, R, n_global, batch, basisb, x0, carry, ts, keys,
-                       cidx, creal, frozen):
-    """One epoch-aligned chunk of cohort rounds: same scan skeleton as
-    `_chunk_body`, but spec code sees a `CohortReducer` wrapping the
-    cohort-capacity reducer `R`.  ``cidx``/``creal``/``frozen`` are
-    constant for the chunk (the cohort engine cuts chunks at epoch
-    boundaries), so they ride in as plain traced inputs, not scan xs."""
-    _note_trace("cohort_chunk")
-    CR = CohortReducer(inner=R, idx=cidx, real=creal, frozen=frozen,
-                       n_global=n_global)
-    env = Env(batch=batch, basisb=basisb, x0=x0,
-              extra=spec.prepare(CR, batch, basisb, x0))
+    def __init__(self, spec, batch, basisb, x0, root_key, *,
+                 sharded: bool = False):
+        self.spec = spec
+        self.batch = batch
+        self.basisb = basisb
+        self.x0 = x0
+        self.root_key = root_key
+        self.sharded = bool(sharded)
+        self._carry = None
 
-    def step(carry, xt):
-        t, key_t = xt
-        return spec.step(CR, env, carry, RoundCtx(key=key_t, t=t, avail=None))
+    def carry_template(self):
+        """The carry the next chunk starts from: the round-0 carry (the
+        first call runs the init program) until a chunk or `restore`
+        replaces it.  Its structure and leaf shapes are the serialization
+        contract checkpoints are validated against."""
+        if self._carry is None:
+            self._carry = init_serve_carry(
+                self.spec, self.batch, self.basisb, self.x0,
+                sharded=self.sharded)
+        return self._carry
 
-    return jax.lax.scan(step, carry, (ts, keys))
+    def warm_programs(self, chunk: int) -> bool:
+        """Resolve the program of a ``chunk``-round chunk — load it from the
+        active program cache or compile and persist it — without running a
+        round.  Returns False when no cache is active."""
+        if progcache.active() is None:
+            return False
+        prog, args = chunk_program(
+            self.spec, self.batch, self.basisb, self.x0,
+            self.carry_template(), 0, int(chunk), self.root_key,
+            sharded=self.sharded)
+        prog.resolve(*args)
+        return True
 
+    def run_chunk(self, t0: int, steps: int, avail=None):
+        """Run rounds [t0, t0 + steps) (``avail``: `run_chunk`'s schedule)
+        and return their streams ``(eval_x, CommLedger of per-leg streams,
+        events)``."""
+        self._carry, ys = run_chunk(
+            self.spec, self.batch, self.basisb, self.x0,
+            self.carry_template(), t0, steps, self.root_key, avail=avail,
+            sharded=self.sharded)
+        return ys
 
-_cohort_chunk_jit = functools.partial(
-    jax.jit, static_argnames=("spec", "R", "n_global"),
-    donate_argnames=("carry",))(_cohort_chunk_body)
+    def checkpoint_view(self):
+        """(carry leaves, host state) for `artifacts.save_checkpoint`: the
+        device carry pulled to the host; the stacked engine keeps no host
+        state (None)."""
+        return [np.asarray(leaf)
+                for leaf in jax.tree_util.tree_leaves(self._carry)], None
 
-# donation-free AOT twin — see `_chunk_jit_aot` for why cached programs
-# must not donate
-_cohort_chunk_jit_aot = functools.partial(
-    jax.jit, static_argnames=("spec", "R", "n_global"))(_cohort_chunk_body)
+    def restore(self, t: int, carry, host_state=None):
+        """Adopt a checkpoint's carry (validated and unflattened by the
+        caller) as the carry round ``t`` starts from."""
+        del t, host_state
+        self._carry = carry
 
+    def serve_meta(self) -> dict:
+        """The serve record's ``meta`` keys of this engine: none."""
+        return {}
 
-@functools.lru_cache(maxsize=None)
-def _sharded_cohort_chunk_fns(spec, R: "ShardMapReducer", mesh, flags_key,
-                              n_global):
-    """The cohort chunk program under shard_map: the COHORT axis shards
-    over the client mesh (cidx/creal shard with it; frozen fleet stats are
-    replicated like the server state)."""
-    from jax.sharding import PartitionSpec as P
-
-    from repro.sharding.rules import CLIENT_AXIS, cohort_chunk_specs
-
-    leaves, treedef = flags_key
-    carry_specs = jax.tree_util.tree_unflatten(
-        treedef, [P(CLIENT_AXIS) if f else P() for f in leaves])
-    in_specs, out_specs = cohort_chunk_specs(
-        carry_specs,
-        basis_replicated=getattr(spec, "basis_replicated", False))
-    body = jax.shard_map(
-        functools.partial(_cohort_chunk_body, spec, R, n_global), mesh=mesh,
-        in_specs=in_specs, out_specs=out_specs, check_vma=False)
-    # (batch, basisb, x0, carry, ts, keys, cidx, creal, frozen) — carry is 3
-    chunk = jax.jit(body, donate_argnums=(3,))
-    chunk_aot = jax.jit(body)  # donation-free twin for the progcache path
-    return chunk, chunk_aot
-
-
-def _cohort_backend(spec, batch, basisb, x0, n_global: int, sharded: bool,
-                    exact: bool) -> _AotProgram:
-    if not sharded:
-        R = VmapReducer(n=batch.n)
-        return _AotProgram(
-            "cohort_chunk", spec, ("vmap", n_global),
-            functools.partial(_cohort_chunk_jit, spec, R, n_global),
-            functools.partial(_cohort_chunk_jit_aot.lower, spec, R,
-                              n_global))
-    from repro.launch.mesh import make_client_mesh
-    from repro.sharding.rules import mesh_fingerprint
-
-    mesh, ndev = make_client_mesh(batch.n)
-    R = ShardMapReducer(n=batch.n, ndev=ndev, exact=exact,
-                        plan=getattr(spec, "reduce_plan", ReducePlan()))
-    fk = _carry_flags_key_cached(spec, batch, basisb, x0)
-    chunk, chunk_aot = _sharded_cohort_chunk_fns(spec, R, mesh, fk, n_global)
-    scope = ("shmap", ndev, exact, mesh_fingerprint(mesh), n_global)
-    return _AotProgram("cohort_chunk", spec, scope, chunk, chunk_aot.lower)
-
-
-def run_cohort_chunk(spec, batch, basisb, x0, carry, t0: int, steps: int,
-                     root_key, *, cidx, creal, frozen, n_global: int,
-                     sharded: bool = False, exact: bool = True):
-    """Run `steps` cohort rounds starting at absolute round `t0`.
-
-    ``batch`` is the COHORT's `ClientBatch` (capacity c rows gathered from
-    the `ClientStore`), ``carry`` the cohort-capacity carry, ``cidx`` the
-    slots' global client indices (c,) int32, ``creal`` the padding mask
-    (c,) bool, ``frozen`` the dict of fleet aggregate statistics for the
-    epoch's ABSENT clients.  Per-round keys are ``fold_in(root_key, t)``
-    exactly like `run_chunk`, so cohort trajectories share the serve
-    driver's chunk-boundary invariance.  The carry is CONSUMED (donated on
-    the fast path, left intact but still not reusable by contract under an
-    active program cache — see `_chunk_jit_aot`)."""
-    ts = jnp.arange(t0, t0 + steps)
-    keys = jax.vmap(lambda t: jax.random.fold_in(root_key, t))(ts)
-    cidx = jnp.asarray(cidx, jnp.int32)
-    creal = jnp.asarray(creal, bool)
-    chunk = _cohort_backend(spec, batch, basisb, x0, int(n_global), sharded,
-                            exact)
-    return chunk(batch, basisb, x0, carry, ts, keys, cidx, creal, frozen)
-
-
-def warm_cohort_chunk_program(spec, batch, basisb, x0, carry, steps: int,
-                              root_key, *, cidx, creal, frozen,
-                              n_global: int, sharded: bool = False,
-                              exact: bool = True) -> bool:
-    """`warm_chunk_program` for the cohort chunk program: resolve (load or
-    compile-and-persist) without executing.  All array arguments are shape
-    templates; `repro.core.cohort.CohortEngine.warm_programs` builds them
-    from the store's dtypes before any epoch is gathered."""
-    if progcache.active() is None:
-        return False
-    ts = jnp.arange(0, int(steps))
-    keys = jax.vmap(lambda t: jax.random.fold_in(root_key, t))(ts)
-    prog = _cohort_backend(spec, batch, basisb, x0, int(n_global), sharded,
-                           exact)
-    prog.resolve(batch, basisb, x0, carry, ts, keys,
-                 jnp.asarray(cidx, jnp.int32), jnp.asarray(creal, bool),
-                 frozen)
-    return True
+    def close(self):
+        pass

@@ -26,7 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core import baselines, bl, client_batch, compressors, glm
+from repro.core import baselines, batched, bl, client_batch, compressors, glm
 from repro.core.basis import make_bases
 from repro.core.rounds import StreamHook
 
@@ -273,27 +273,102 @@ def build_stream_spec(cell: MethodCell, d: int, n: int, lam: float,
     return spec, basis, cohort_size, rpc, seed
 
 
-def _run_stream_cell(cell: MethodCell, prob: StreamProblem, steps: int,
-                     params: dict, backend: str) -> bl.History:
-    from repro.core import batched, cohort
+#: methods the serve loop can drive: the GLM specs, and bldnn on a
+#: frozen-trunk fine-tuning problem (whose per-round evaluation is a dict
+#: of scalars; a pytree iterate per round would not fit the streams)
+SERVE_METHODS = ("bl1", "bl2", "bl3", "fednl_bag", "bldnn")
 
-    spec, basis, csize, rpc, seed = build_stream_spec(
-        cell, prob.d, prob.n, prob.store.lam, params)
-    eng = cohort.CohortEngine(
-        spec, prob.store, prob.x0, cohort=csize, rounds_per_cohort=rpc,
-        root_key=jax.random.PRNGKey(seed), basis=basis,
-        sharded=backend.endswith("+sharded"))
+
+def build_setup(exp, cell, prob):
+    """(spec, batch, basisb) for a registered cell on a stacked problem —
+    the static half of a run, shared between the batch engine and the
+    serve loop (the `repro.core.batched` ``*_setup`` factorization)."""
+    m = cell.method
+    if m not in SERVE_METHODS:
+        raise SystemExit(
+            f"fed_serve drives methods {', '.join(SERVE_METHODS)}; cell "
+            f"{cell.name!r} uses {m!r} (run it via `python -m repro.exp`)")
+    if m == "bldnn":
+        from repro.fed import bldnn
+
+        return bldnn.serve_setup(cell, prob)
+    params = cell.params_dict()
+    params.pop("seed", None)        # the serve PRNG root comes from --seed
+    n, d = prob.n, prob.d
+    clients = prob.clients
+    hc = [_comp(cell.hess_comp, d, "hessian")] * n
+    if m == "bl1":
+        mc = _comp(cell.model_comp, d, "model")
+        return batched.bl1_setup(clients, prob.bases(cell.basis), hc, mc,
+                                 **params)
+    if m == "bl2":
+        mc = [_comp(cell.model_comp, d, "model")] * n
+        return batched.bl2_setup(clients, prob.bases(cell.basis), hc, mc,
+                                 **params)
+    if m == "bl3":
+        mc = [_comp(cell.model_comp, d, "model")] * n
+        return batched.bl3_setup(clients, hc, mc, **params)
+    return batched.fednl_bag_setup(clients, prob.bases(cell.basis), hc,
+                                   **params)
+
+
+def make_engine(exp, cell, prob, *, root_key, sharded: bool):
+    """The engine that runs ``cell`` on ``prob`` — the one place that
+    chooses it.  A store-backed problem (`StreamProblem`) whose cohort is
+    smaller than its fleet streams through `cohort.CohortEngine`; anything
+    else runs on `rounds.StackedEngine`, a store-backed fleet that one
+    cohort covers gathered onto the device whole.  Both engines build
+    nothing on the device here: their first ``carry_template`` does."""
+    from repro.core import cohort, rounds
+
+    if not isinstance(prob, StreamProblem):
+        spec, batch, basisb = build_setup(exp, cell, prob)
+        return rounds.StackedEngine(spec, batch, basisb, prob.x0, root_key,
+                                    sharded=sharded)
+    spec, basis, csize, rpc, _ = build_stream_spec(
+        cell, prob.d, prob.n, prob.store.lam, cell.params_dict())
+    if csize < prob.n:
+        return cohort.CohortEngine(
+            spec, prob.store, prob.x0, cohort=csize, rounds_per_cohort=rpc,
+            root_key=root_key, basis=basis, sharded=sharded)
+    basisb = None if basis is None else cohort.standard_basisb(prob.d, prob.n)
+    return rounds.StackedEngine(
+        spec, prob.store.gather_batch(np.arange(prob.n)), basisb, prob.x0,
+        root_key, sharded=sharded)
+
+
+def gap_evaluator(prob, eng):
+    """``evaluate(evaluated) -> {name: stream}`` (``"gap"`` always) for the
+    trajectories ``eng`` runs on ``prob``; f* is computed at first use.
+    Keyed on the problem, not on the engine: a store-backed fleet evaluates
+    slab-wise on the host (`cohort.store_loss` — the device never holds
+    more than a cohort), anything else through the spec's
+    ``eval_streams`` on the engine's batch (a fine-tuning problem has no
+    f*)."""
+    if isinstance(prob, StreamProblem):
+        from repro.core import cohort
+
+        loss = functools.partial(cohort.store_loss, prob.store)
+        f_star = functools.cache(lambda: loss(prob.x_star))
+        return lambda xs: {"gap": np.array([loss(x) - f_star()
+                                            for x in np.asarray(xs)])}
+    f_star = functools.cache(
+        lambda: 0.0 if isinstance(prob, DNNProblem)
+        else batched._f_star(eng.batch, prob.x_star))
+    return lambda evaluated: eng.spec.eval_streams(eng.batch, evaluated,
+                                                   f_star())
+
+
+def _run_stream_cell(exp: Experiment, cell: MethodCell, prob: StreamProblem,
+                     steps: int, params: dict, backend: str) -> bl.History:
+    eng = make_engine(exp, cell, prob,
+                      root_key=jax.random.PRNGKey(int(params.get("seed", 0))),
+                      sharded=backend.endswith("+sharded"))
     try:
         eval_x, leds, _events = eng.run_chunk(0, steps)
     finally:
         eng.close()
-    # fleet gaps evaluate slab-wise on the host — the device never holds
-    # more than the cohort, so the stacked eval program has no input here
-    xs = np.asarray(eval_x)
-    f_star = cohort.store_loss(prob.store, prob.x_star)
-    gaps = np.array([cohort.store_loss(prob.store, xs[t]) - f_star
-                     for t in range(xs.shape[0])])
-    return batched._history({"gap": gaps}, leds)
+    return batched._history(gap_evaluator(prob, eng)(eval_x), leds)
 
 
 def run_cell(exp: Experiment, cell: MethodCell, prob: Problem, *,
@@ -328,7 +403,7 @@ def run_cell(exp: Experiment, cell: MethodCell, prob: Problem, *,
             raise ValueError(
                 f"cell {cell.name!r}: a synthetic_stream problem runs on "
                 f"the cohort backends, got backend={backend!r}")
-        return _run_stream_cell(cell, prob, steps, params, backend)
+        return _run_stream_cell(exp, cell, prob, steps, params, backend)
 
     if m == "bldnn":
         from repro.fed import bldnn

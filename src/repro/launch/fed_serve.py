@@ -4,10 +4,14 @@
         --max-rounds 200 --chunk 25 --ckpt-dir runs/serve
 
 Where `repro.exp` runs a cell as one fixed-length batch scan and exits, this
-launcher *serves* it: rounds run in bounded-length chunks against the
-chunked scan driver (`repro.core.rounds.run_chunk` — the jitted program is
-reused across chunks, control returns to the host every chunk), and between
-chunks the orchestrator
+launcher *serves* it: one loop (`serve`) runs rounds in bounded-length
+chunks on an engine — the jitted chunk program is reused across chunks and
+control returns to the host every chunk.  `repro.exp.engine.make_engine`
+picks the engine: `repro.core.cohort.CohortEngine` for a store-backed cell
+whose cohort is smaller than its fleet, `repro.core.rounds.StackedEngine`
+(the whole fleet on the device) for any other.  Both take the same calls
+(``carry_template``, ``warm_programs``, ``run_chunk``, ``checkpoint_view``,
+``restore``, ``close``), and between chunks the loop
 
   1. **injects faults** — a `repro.core.faults.FaultPlan` (i.i.d. dropout,
      deterministic outage windows, straggler timeouts with retry/backoff)
@@ -18,9 +22,10 @@ chunks the orchestrator
      (`History.events`, `rounds.EVENT_*` bitmasks).
   2. **checkpoints** the full server state — scan carry (iterate, shifts,
      `comm.CommLedger`), accumulated history streams, root PRNG key and
-     round counter — via `repro.exp.artifacts.save_checkpoint`
-     (schema-versioned, atomically written, digest-keyed to this serve
-     config).
+     round counter, and the cohort engine's host plane (client store,
+     fleet totals, frozen epoch stats: the ckpt@2 ``host_state``) — via
+     `repro.exp.artifacts.save_checkpoint` (schema-versioned, atomically
+     written, digest-keyed to this serve config).
 
 Start-up is **compile-free on a warm restart**: the serve programs resolve
 through the AOT program cache (`repro.core.progcache`, rooted at
@@ -66,8 +71,10 @@ draw is a pure function of ``(fault seed, t)``, the trajectory is invariant
 to chunk boundaries: kill -9 the process at any point, rerun the same
 command, and the run resumes from the latest valid checkpoint **bit-exactly
 ** — trajectory, `History.events` and per-leg `CommLedger` bit streams all
-match an uninterrupted run at the same seed (pinned by tests/test_serve.py
-and the CI ``serve-smoke`` job).  ``--crash-after-round N`` arms
+match an uninterrupted run at the same seed, on either engine (the cohort
+schedule is a pure function of the absolute epoch index; pinned by
+tests/test_serve.py, tests/test_cohort.py and the CI ``serve-smoke``
+job).  ``--crash-after-round N`` arms
 `faults.CrashInjector` — a deterministic in-process SIGKILL after round N
 is computed but before its covering checkpoint lands (omit the flag on
 restart, or it crashes at the same boundary forever).
@@ -81,7 +88,8 @@ expert pairs computed per round), ``moe_dropped`` and
 ``basis_slices_rotated``.  Fault injection additionally requires the method to react to
 availability (`MethodSpec.supports_faults`: bl2/bl3 partial participation,
 fednl_bag lazy aggregation) — serving bl1 works, but injecting faults into
-it is refused rather than silently ignored.
+it is refused rather than silently ignored.  The cohort engine refuses a
+fault plan: client absence there is its own participation draw.
 """
 from __future__ import annotations
 
@@ -100,53 +108,13 @@ from repro.core import batched, comm, faults, progcache, rounds, spans
 from repro.exp import artifacts
 from repro.exp.engine import (
     StreamProblem,
-    _comp,
     build_problem,
-    build_stream_spec,
+    gap_evaluator,
+    make_engine,
 )
+# a cell's (spec, batch, basisb), under the name callers of this module use
+from repro.exp.engine import build_setup  # noqa: F401
 from repro.exp.registry import get_experiment
-
-#: methods the serve loop can drive: the GLM specs, and bldnn on a
-#: frozen-trunk fine-tuning problem (whose per-round evaluation is a dict
-#: of scalars; a pytree iterate per round would not fit the streams)
-SERVE_METHODS = ("bl1", "bl2", "bl3", "fednl_bag", "bldnn")
-
-#: checkpoint stream names: eval iterates, events, one per ledger leg
-_STREAMS = ("eval_x", "events") + tuple(
-    f"led_{leg}" for leg in comm.CommLedger.LEGS)
-
-
-def build_setup(exp, cell, prob):
-    """(spec, batch, basisb) for a registered cell — the static half of a
-    run, shared between the batch engine and the serve loop (the
-    `repro.core.batched` ``*_setup`` factorization)."""
-    m = cell.method
-    if m not in SERVE_METHODS:
-        raise SystemExit(
-            f"fed_serve drives methods {', '.join(SERVE_METHODS)}; cell "
-            f"{cell.name!r} uses {m!r} (run it via `python -m repro.exp`)")
-    if m == "bldnn":
-        from repro.fed import bldnn
-
-        return bldnn.serve_setup(cell, prob)
-    params = cell.params_dict()
-    params.pop("seed", None)        # the serve PRNG root comes from --seed
-    n, d = prob.n, prob.d
-    clients = prob.clients
-    hc = [_comp(cell.hess_comp, d, "hessian")] * n
-    if m == "bl1":
-        mc = _comp(cell.model_comp, d, "model")
-        return batched.bl1_setup(clients, prob.bases(cell.basis), hc, mc,
-                                 **params)
-    if m == "bl2":
-        mc = [_comp(cell.model_comp, d, "model")] * n
-        return batched.bl2_setup(clients, prob.bases(cell.basis), hc, mc,
-                                 **params)
-    if m == "bl3":
-        mc = [_comp(cell.model_comp, d, "model")] * n
-        return batched.bl3_setup(clients, hc, mc, **params)
-    return batched.fednl_bag_setup(clients, prob.bases(cell.basis), hc,
-                                   **params)
 
 
 def serve_config(exp, cell, seed: int, backend: str,
@@ -167,27 +135,25 @@ def serve_config(exp, cell, seed: int, backend: str,
     }
 
 
-def _resolve_backend(cell, override: Optional[str]) -> str:
+def _resolve_backend(cell, override: Optional[str], stream: bool) -> str:
+    """The cell's backend, or the override, with ``auto`` resolved: a
+    store-backed cell serves on the cohort backends, any other on the
+    engine's fast backends."""
     backend = override or cell.backend
-    if backend == "auto":
-        backend = "fast"
+    if stream:
+        backend = "cohort" if backend == "auto" else backend
+        if backend not in ("cohort", "cohort+sharded"):
+            raise SystemExit(
+                f"a synthetic_stream cell serves on the 'cohort' or "
+                f"'cohort+sharded' backends, not {backend!r} (the stacked "
+                "backends would materialize the whole fleet on device)")
+        return backend
+    backend = "fast" if backend == "auto" else backend
     if backend not in ("fast", "fast+sharded"):
         raise SystemExit(
             f"fed_serve runs on the engine backends 'fast' or "
             f"'fast+sharded', not {backend!r} (the reference backend has "
             "no checkpointable scan carry)")
-    return backend
-
-
-def _resolve_cohort_backend(cell, override: Optional[str]) -> str:
-    backend = override or cell.backend
-    if backend == "auto":
-        backend = "cohort"
-    if backend not in ("cohort", "cohort+sharded"):
-        raise SystemExit(
-            f"a synthetic_stream cell serves on the 'cohort' or "
-            f"'cohort+sharded' backends, not {backend!r} (the stacked "
-            "backends would materialize the whole fleet on device)")
     return backend
 
 
@@ -363,11 +329,12 @@ def _ckpt_counter() -> dict:
     return {"count": 0, "bytes": 0, "copied_bytes": 0, "hash_wait_s": 0.0}
 
 
-def _copied_bytes(host_state: dict) -> int:
+def _copied_bytes(host_state: Optional[dict]) -> int:
     """Bytes of a checkpoint's host plane that the payload step copied: an
     array that owns its memory was made for the checkpoint, a view
     (`CohortEngine.checkpoint_view`) borrows the engine's."""
-    return sum(int(v.nbytes) for v in host_state.values() if v.flags.owndata)
+    return sum(int(v.nbytes) for v in (host_state or {}).values()
+               if v.flags.owndata)
 
 
 def _activate_progcache(ckpt_dir: str, progcache_dir: Optional[str],
@@ -381,169 +348,6 @@ def _activate_progcache(ckpt_dir: str, progcache_dir: Optional[str],
                                or os.path.join(ckpt_dir, "progcache"))
     log(f"[serve] program cache at {cache.root}")
     return cache
-
-
-def _serve_cohort(exp, cell, prob: StreamProblem, *, seed: int, chunk: int,
-                  max_rounds: int, ckpt_dir: str, backend: Optional[str],
-                  keep: int, plan: Optional[faults.FaultPlan],
-                  crash_after_round: Optional[int],
-                  result_path: Optional[str],
-                  progcache_dir: Optional[str] = None,
-                  no_progcache: bool = False,
-                  metrics_out: Optional[str] = None, log=print) -> dict:
-    """The serve loop over the cohort-streaming engine: same chunked
-    checkpoint/resume/crash contract as the stacked path, with the engine's
-    host plane (client store, fleet totals, frozen epoch stats) riding in
-    the ckpt@2 ``host_state`` payload.  The trajectory stays chunk-boundary
-    invariant — per-round keys are ``fold_in(root_key, t)`` and the cohort
-    schedule is a pure function of the absolute epoch index — so kill -9 +
-    rerun is bit-exact here too (tests/test_cohort.py)."""
-    from repro.core import cohort
-
-    spans_before, traces_before = spans.snapshot(), rounds.trace_counts()
-    plan = plan if plan is not None else faults.FaultPlan(n=prob.n)
-    if not plan.trivial:
-        raise SystemExit(
-            "cohort streaming does not take an injected fault schedule: "
-            "client absence is the engine's own per-round participation "
-            "draw over the global fleet (Alg. 2-3 partial participation); "
-            "drop the fault flags or serve a stacked cell")
-    backend = _resolve_cohort_backend(cell, backend)
-    crash = (faults.CrashInjector(crash_after_round)
-             if crash_after_round is not None else None)
-    params = cell.params_dict()
-    params.pop("seed", None)        # the serve PRNG root comes from --seed
-    spec, basis, csize, rpc, _ = build_stream_spec(
-        cell, prob.d, prob.n, prob.store.lam, params)
-    config = serve_config(exp, cell, seed, backend, plan)
-    digest = artifacts.config_digest(config)
-    root_key = jax.random.PRNGKey(seed)
-    cache = _activate_progcache(ckpt_dir, progcache_dir, no_progcache, log)
-    t0_wall = time.perf_counter()      # time-to-first-round starts here
-    with spans.span("serve.warm"):
-        eng = cohort.CohortEngine(
-            spec, prob.store, prob.x0, cohort=csize, rounds_per_cohort=rpc,
-            root_key=root_key, basis=basis,
-            sharded=backend == "cohort+sharded")
-        template = eng.carry_template()
-        # resolve the chunk program BEFORE checkpoint restore: on a warm
-        # restart the executable deserializes in milliseconds and the
-        # first round starts compile-free
-        eng.warm_programs(min(chunk, max_rounds))
-    with spans.span("serve.restore"):
-        ck = artifacts.load_checkpoint(ckpt_dir, config_digest=digest)
-        resumed_from = None
-        if ck is not None:
-            t = int(ck["t"])
-            carry = _restore_carry(ck, template)
-            eng.restore(t, carry, ck.get("host_state"))
-            streams = {name: np.asarray(ck["streams"][name])
-                       for name in _STREAMS}
-            resumed_from = t
-            log(f"[serve] {exp.name}/{cell.name}: resumed from checkpoint "
-                f"at round {t} (config {digest})")
-        else:
-            t = 0
-            streams = _empty_streams(prob.d)
-            log(f"[serve] {exp.name}/{cell.name}: fresh run (config "
-                f"{digest}, cohort {eng.cohort}/{eng.n})")
-
-    sink = MetricsSink(metrics_out) if metrics_out else None
-    f_star = cohort.store_loss(prob.store, prob.x_star) if sink else None
-    chunks_run = 0
-    ttfr_s = None
-    ckpt = _ckpt_counter()
-    try:
-        while t < max_rounds:
-            steps = min(chunk, max_rounds - t)
-            with spans.span("serve.dispatch", t=t, steps=steps):
-                ys = eng.run_chunk(t, steps)
-            streams = _append_chunk(streams, ys)
-            t += steps
-            chunks_run += 1
-            if ttfr_s is None:
-                ttfr_s = time.perf_counter() - t0_wall
-            log(f"[serve] rounds {t - steps}..{t - 1} done "
-                f"(epoch {(t - 1) // rpc})")
-            if sink is not None:
-                with spans.span("serve.sink"):
-                    xs_new = np.asarray(streams["eval_x"][-steps:])
-                    sink.emit_chunk(
-                        range(t - steps, t),
-                        [cohort.store_loss(prob.store, x) - f_star
-                         for x in xs_new],
-                        streams["events"][-steps:],
-                        {leg: streams[f"led_{leg}"][-steps:]
-                         for leg in comm.CommLedger.LEGS})
-            if crash is not None:
-                crash.maybe_crash(t - 1)
-            with spans.span("serve.checkpoint"):
-                with spans.span("ckpt.payload"):
-                    # views of the host plane: written before the next chunk
-                    leaves, host_state = eng.checkpoint_view()
-                ckpt["copied_bytes"] += _copied_bytes(host_state)
-                artifacts.save_checkpoint(
-                    ckpt_dir, t=t, carry_leaves=leaves, streams=streams,
-                    root_key=np.asarray(root_key), config_digest=digest,
-                    keep=keep, host_state=host_state, stats=ckpt)
-    finally:
-        eng.close()
-
-    # fleet gaps evaluate slab-wise on the host (the device never holds
-    # more than the cohort)
-    xs = np.asarray(streams["eval_x"])
-    f_star = (cohort.store_loss(prob.store, prob.x_star)
-              if f_star is None else f_star)
-    evals = {"gap": np.array([cohort.store_loss(prob.store, xs[i]) - f_star
-                              for i in range(xs.shape[0])])}
-    led_streams = comm.CommLedger(
-        *(jnp.asarray(streams[f"led_{leg}"])
-          for leg in comm.CommLedger.LEGS))
-    hist = batched._history(evals, led_streams)
-    hist.events = [int(e) for e in streams["events"]]
-    record = {
-        "schema": artifacts.SERVE_SCHEMA,
-        "experiment": exp.name,
-        "cell": cell.name,
-        "seed": seed,
-        "config_digest": digest,
-        "config": config,
-        "rounds": t,
-        "history": {
-            "gaps": [float(g) for g in hist.gaps],
-            "up_bits": [float(b) for b in hist.up_bits],
-            "down_bits": [float(b) for b in hist.down_bits],
-            "legs": {leg: [float(v) for v in hist.legs[leg]]
-                     for leg in comm.CommLedger.LEGS},
-            "events": hist.events,
-        },
-        "degraded_rounds": _degraded(streams["events"]),
-        "meta": {
-            "backend": backend,
-            "chunk": chunk,
-            "chunks_run": chunks_run,
-            "resumed_from": resumed_from,
-            "straggler_wait_s": 0.0,
-            "runtime_s": time.perf_counter() - t0_wall,
-            "ttfr_s": ttfr_s,
-            "progcache": cache.summary() if cache is not None else None,
-            "cohort": eng.cohort,
-            "rounds_per_cohort": rpc,
-            "n_clients": eng.n,
-            "prefetch_overlap": eng.prefetch_overlap,
-            "prefetch": dict(eng.metrics),
-            "server_eigh": _server_eigh(streams["events"], resumed_from),
-            "ckpt": ckpt,
-            "spans": spans.since(spans_before),
-            "retraces": _retraces(traces_before),
-        },
-    }
-    if result_path:
-        artifacts.write_json(result_path, record)
-        log(f"[serve] result → {result_path}")
-    log(f"[serve] {t} rounds, final gap {record['history']['gaps'][-1]:.3e}, "
-        f"prefetch overlap {eng.prefetch_overlap:.0%}")
-    return record
 
 
 def serve(*, exp_name: str, cell_name: str, seed: int = 0, chunk: int = 25,
@@ -567,63 +371,58 @@ def serve(*, exp_name: str, cell_name: str, seed: int = 0, chunk: int = 25,
     exp = get_experiment(exp_name)
     cell = exp.cell(cell_name)
     prob = build_problem(exp.problem)
-    if isinstance(prob, StreamProblem):
-        return _serve_cohort(
-            exp, cell, prob, seed=seed, chunk=chunk, max_rounds=max_rounds,
-            ckpt_dir=ckpt_dir, backend=backend, keep=keep, plan=plan,
-            crash_after_round=crash_after_round, result_path=result_path,
-            progcache_dir=progcache_dir, no_progcache=no_progcache,
-            metrics_out=metrics_out, log=log)
-    spec, batch, basisb = build_setup(exp, cell, prob)
-    plan = plan if plan is not None else faults.FaultPlan(n=batch.n)
-    if plan.n != batch.n:
+    stream = isinstance(prob, StreamProblem)
+    plan = plan if plan is not None else faults.FaultPlan(n=prob.n)
+    if stream and not plan.trivial:
         raise SystemExit(
-            f"fault plan is for n={plan.n} clients, fleet has {batch.n}")
-    if not plan.trivial and not getattr(spec, "supports_faults", False):
+            "cohort streaming does not take an injected fault schedule: "
+            "client absence is the engine's own per-round participation "
+            "draw over the global fleet (Alg. 2-3 partial participation); "
+            "drop the fault flags or serve a stacked cell")
+    backend = _resolve_backend(cell, backend, stream)
+    # the root key the engine folds every round key from; a resumed run's
+    # checkpoint holds the same key, since the config digest pins --seed
+    root_key = jax.random.PRNGKey(seed)
+    eng = make_engine(exp, cell, prob, root_key=root_key,
+                      sharded=backend.endswith("+sharded"))
+    if plan.n != prob.n:
+        raise SystemExit(
+            f"fault plan is for n={plan.n} clients, fleet has {prob.n}")
+    if not plan.trivial and not getattr(eng.spec, "supports_faults", False):
         raise SystemExit(
             f"method {cell.method!r} models a fully synchronous fleet and "
             "cannot absorb injected faults (MethodSpec.supports_faults is "
             "False) — drop the fault flags or serve a partial-participation "
             "cell (bl2/bl3) or fednl_bag")
-    backend = _resolve_backend(cell, backend)
-    sharded = backend == "fast+sharded"
     crash = (faults.CrashInjector(crash_after_round)
              if crash_after_round is not None else None)
     tree = cell.method == "bldnn"
-    x0 = prob.x0
-    f_star_of = (lambda: 0.0) if tree else (
-        lambda: batched._f_star(batch, prob.x_star))
+    evaluate = gap_evaluator(prob, eng)
 
     config = serve_config(exp, cell, seed, backend, plan)
     digest = artifacts.config_digest(config)
     cache = _activate_progcache(ckpt_dir, progcache_dir, no_progcache, log)
     t0_wall = time.perf_counter()      # time-to-first-round starts here
     with spans.span("serve.warm"):
-        template = rounds.init_serve_carry(spec, batch, basisb, x0,
-                                           sharded=sharded)
+        template = eng.carry_template()
         # resolve the chunk program BEFORE checkpoint restore: on a warm
         # restart the executable deserializes in milliseconds and the
         # first round starts compile-free
-        rounds.warm_chunk_program(spec, batch, basisb, x0, template,
-                                  min(chunk, max_rounds),
-                                  jax.random.PRNGKey(seed), sharded=sharded)
+        eng.warm_programs(min(chunk, max_rounds))
     with spans.span("serve.restore"):
         ck = artifacts.load_checkpoint(ckpt_dir, config_digest=digest)
         resumed_from = None
         if ck is not None:
             t = int(ck["t"])
-            carry = _restore_carry(ck, template)
+            eng.restore(t, _restore_carry(ck, template), ck.get("host_state"))
             streams = {name: np.asarray(arr)
                        for name, arr in ck["streams"].items()}
-            root_key = jnp.asarray(ck["root_key"])
             resumed_from = t
             log(f"[serve] {exp.name}/{cell.name}: resumed from checkpoint "
                 f"at round {t} (config {digest})")
         else:
             t = 0
-            carry = template
             streams = _empty_streams(None if tree else prob.d)
-            root_key = jax.random.PRNGKey(seed)
             log(f"[serve] {exp.name}/{cell.name}: fresh run (config "
                 f"{digest})")
         # the template is the fresh carry or a shape pattern: holding it
@@ -631,56 +430,55 @@ def serve(*, exp_name: str, cell_name: str, seed: int = 0, chunk: int = 25,
         del template
 
     sink = MetricsSink(metrics_out) if metrics_out else None
-    f_star = f_star_of() if sink else None
     chunks_run = 0
     waited_total = 0.0
     ttfr_s = None
     ckpt = _ckpt_counter()
-    while t < max_rounds:
-        steps = min(chunk, max_rounds - t)
-        if plan.trivial:
-            avail, waited = None, 0.0
-        else:
-            avail, waited = plan.schedule(t, steps)
-        # run_chunk DONATES the carry (its buffers back the next chunk's
-        # output) — reassign, and only ever checkpoint the returned carry
-        with spans.span("serve.dispatch", t=t, steps=steps):
-            carry, ys = rounds.run_chunk(spec, batch, basisb, x0, carry, t,
-                                         steps, root_key, avail=avail,
-                                         sharded=sharded)
-        streams = _append_chunk(streams, ys)
-        t += steps
-        chunks_run += 1
-        waited_total += waited
-        if ttfr_s is None:
-            ttfr_s = time.perf_counter() - t0_wall
-        if sink is not None:
-            with spans.span("serve.sink"):
-                gaps = spec.eval_streams(
-                    batch, _evaluated(streams, steps), f_star)["gap"]
-                sink.emit_chunk(
-                    range(t - steps, t), np.asarray(gaps),
-                    streams["events"][-steps:],
-                    {leg: streams[f"led_{leg}"][-steps:]
-                     for leg in comm.CommLedger.LEGS})
-        n_deg = _degraded(streams["events"][-steps:])
-        log(f"[serve] rounds {t - steps}..{t - 1} done"
-            + (f", {n_deg} degraded" if n_deg else "")
-            + (f", straggler wait {waited:.2f}s" if waited else ""))
-        if crash is not None:
-            # fires BEFORE the covering checkpoint: the chunk is lost and
-            # the resume path must recompute it (the acceptance scenario)
-            crash.maybe_crash(t - 1)
-        with spans.span("serve.checkpoint"):
-            with spans.span("ckpt.payload"):
-                leaves = [np.asarray(leaf)
-                          for leaf in jax.tree_util.tree_leaves(carry)]
-            artifacts.save_checkpoint(
-                ckpt_dir, t=t, carry_leaves=leaves, streams=streams,
-                root_key=np.asarray(root_key), config_digest=digest,
-                keep=keep, stats=ckpt)
+    try:
+        while t < max_rounds:
+            steps = min(chunk, max_rounds - t)
+            if plan.trivial:
+                avail, waited = None, 0.0
+            else:
+                avail, waited = plan.schedule(t, steps)
+            with spans.span("serve.dispatch", t=t, steps=steps):
+                ys = eng.run_chunk(t, steps, avail=avail)
+            streams = _append_chunk(streams, ys)
+            t += steps
+            chunks_run += 1
+            waited_total += waited
+            if ttfr_s is None:
+                ttfr_s = time.perf_counter() - t0_wall
+            if sink is not None:
+                with spans.span("serve.sink"):
+                    gaps = evaluate(_evaluated(streams, steps))["gap"]
+                    sink.emit_chunk(
+                        range(t - steps, t), np.asarray(gaps),
+                        streams["events"][-steps:],
+                        {leg: streams[f"led_{leg}"][-steps:]
+                         for leg in comm.CommLedger.LEGS})
+            n_deg = _degraded(streams["events"][-steps:])
+            log(f"[serve] rounds {t - steps}..{t - 1} done"
+                + (f", {n_deg} degraded" if n_deg else "")
+                + (f", straggler wait {waited:.2f}s" if waited else ""))
+            if crash is not None:
+                # fires BEFORE the covering checkpoint: the chunk is lost and
+                # the resume path must recompute it (the acceptance scenario)
+                crash.maybe_crash(t - 1)
+            with spans.span("serve.checkpoint"):
+                with spans.span("ckpt.payload"):
+                    # the cohort engine's host plane comes as views,
+                    # written before the next chunk runs
+                    leaves, host_state = eng.checkpoint_view()
+                ckpt["copied_bytes"] += _copied_bytes(host_state)
+                artifacts.save_checkpoint(
+                    ckpt_dir, t=t, carry_leaves=leaves, streams=streams,
+                    root_key=np.asarray(root_key), config_digest=digest,
+                    keep=keep, host_state=host_state, stats=ckpt)
+    finally:
+        eng.close()
 
-    evals = spec.eval_streams(batch, _evaluated(streams), f_star_of())
+    evals = evaluate(_evaluated(streams))
     led_streams = comm.CommLedger(
         *(jnp.asarray(streams[f"led_{leg}"])
           for leg in comm.CommLedger.LEGS))
@@ -714,11 +512,13 @@ def serve(*, exp_name: str, cell_name: str, seed: int = 0, chunk: int = 25,
             "runtime_s": time.perf_counter() - t0_wall,
             "ttfr_s": ttfr_s,
             "progcache": cache.summary() if cache is not None else None,
+            **eng.serve_meta(),
             "server_eigh": _server_eigh(streams["events"], resumed_from),
             "ckpt": ckpt,
             "spans": spans.since(spans_before),
             "retraces": _retraces(traces_before),
-            **(_tree_counters(streams, basisb, resumed_from) if tree else {}),
+            **(_tree_counters(streams, eng.basisb, resumed_from)
+               if tree else {}),
         },
     }
     if result_path:

@@ -40,9 +40,10 @@ def mesh_fingerprint(mesh: "Mesh") -> str:
 
 
 def client_chunk_specs(carry_specs, basis_replicated: bool = False):
-    """shard_map specs for the unified chunked round driver's body
-    (`repro.core.rounds._chunk_body` — the one scan program behind both
-    `run_rounds` and `run_chunk`).
+    """shard_map specs for the chunked round driver's body
+    (`repro.core.rounds._chunk_body` — the one scan program behind
+    `run_rounds`, `run_chunk` and, with three more arguments that
+    `rounds._sharded_programs` appends, the cohort engine).
 
     Positional layout is (batch, basisb, x0, carry, ts, keys, avail) →
     (carry, (eval_x, ledger, events)).  The client-stacked pytrees
@@ -63,23 +64,6 @@ def client_chunk_specs(carry_specs, basis_replicated: bool = False):
     sharded = P(CLIENT_AXIS)
     in_specs = (sharded, P() if basis_replicated else sharded, P(),
                 carry_specs, P(), P(), P())
-    return in_specs, (carry_specs, (P(), P(), P()))
-
-
-def cohort_chunk_specs(carry_specs, basis_replicated: bool = False):
-    """shard_map specs for the cohort-streaming chunk body
-    (`repro.core.rounds._cohort_chunk_body`).
-
-    Positional layout is (batch, basisb, x0, carry, ts, keys, cidx, creal,
-    frozen) → (carry, (eval_x, ledger, events)).  The COHORT axis takes the
-    client axis's place across the shard_map boundary: the gathered cohort
-    batch, the cohort-capacity carry's client-stacked leaves, and the
-    per-slot global-index/padding-mask vectors all shard over CLIENT_AXIS,
-    while the frozen fleet aggregates are replicated server state (every
-    shard needs them to finish a fleet mean/max)."""
-    sharded = P(CLIENT_AXIS)
-    in_specs = (sharded, P() if basis_replicated else sharded, P(),
-                carry_specs, P(), P(), sharded, sharded, P())
     return in_specs, (carry_specs, (P(), P(), P()))
 
 

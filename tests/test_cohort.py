@@ -3,10 +3,11 @@ and resume contracts that license the flat-in-n refactor.
 
 The load-bearing pins:
 
-  * **cohort == fleet is bitwise the stacked engine** — full mode gathers
-    the whole fleet once and dispatches to the EXISTING `rounds.run_chunk`
-    program, on both reducers (the sharded leg runs in a subprocess: the
-    device count is locked at first jax init).
+  * **cohort == fleet is the stacked engine** — the engine factory
+    (`repro.exp.engine.make_engine`) routes a store-backed cell whose
+    cohort covers the fleet to `rounds.StackedEngine` over the whole fleet,
+    bitwise `rounds.run_chunk` on both reducers (the sharded leg runs in a
+    subprocess: the device count is locked at first jax init).
   * **chunk-boundary invariance** — any segmentation of `run_chunk` calls
     produces the same streams (per-round keys fold in the absolute round
     index; the cohort schedule is a pure function of the absolute epoch).
@@ -137,26 +138,45 @@ def test_cohort_engine_constructor_validation():
 
 
 # --------------------------------------------------------------------------
-# full mode: cohort == fleet is bitwise the stacked engine
+# cohort == fleet: the factory's stacked engine, bitwise run_chunk
 # --------------------------------------------------------------------------
+def _fleet_engine(n, store, sharded=False):
+    """The engine `make_engine` picks for a BL2 cell (the `_bl2` spec's
+    fields) whose cohort covers a store-backed fleet of ``n``."""
+    from repro.exp import engine, registry
+
+    cell = registry.MethodCell(
+        "BL2", "bl2", 6, basis="standard",
+        hess_comp=registry.CompressorCfg(kind="topk", k=2 * D),
+        model_comp=registry.CompressorCfg(kind="identity"), backend="cohort",
+        params=(("tau", n), ("cohort", n), ("rounds_per_cohort", 2)))
+    spec = registry.ProblemSpec(kind="synthetic_stream", n_clients=n, m=M,
+                                d=D)
+    exp = registry.Experiment(
+        name="cohort-fleet", figure="extra", title="cohort == fleet",
+        paper_ref="test", cells=(cell,), problem=spec)
+    prob = engine.StreamProblem(spec=spec, store=store, x0=X0, x_star=None)
+    return engine.make_engine(exp, cell, prob, root_key=KEY, sharded=sharded)
+
+
 def test_full_mode_bitwise_parity_vmap():
     n = 32
-    spec = _bl2(n, n)
-    store = _store(n)
-    batch = store.gather_batch(np.arange(n))
+    eng = _fleet_engine(n, _store(n))
+    assert isinstance(eng, rounds.StackedEngine)
+    spec = eng.spec
+    batch = _store(n).gather_batch(np.arange(n))
     bb = cohort.standard_basisb(D, n)
     c0 = rounds.init_serve_carry(spec, batch, bb, X0)
     c1, ys1 = rounds.run_chunk(spec, batch, bb, X0, c0, 0, 6, KEY)
 
-    eng = cohort.CohortEngine(spec, _store(n), X0, cohort=n,
-                              rounds_per_cohort=2, root_key=KEY,
-                              basis="standard")
-    # two calls: full mode must also be invariant to call segmentation
+    # two calls: the engine must also be invariant to call segmentation
     ys2 = jax.tree.map(lambda *a: jnp.concatenate(a, 0),
                        eng.run_chunk(0, 3), eng.run_chunk(3, 3))
-    _assert_trees_equal(ys1, ys2, "full-mode streams != stacked streams")
-    _assert_trees_equal(c1, eng._cur["carry"],
-                        "full-mode carry != stacked carry")
+    _assert_trees_equal(ys1, ys2, "fleet-cohort streams != stacked streams")
+    leaves, host = eng.checkpoint_view()
+    assert host is None
+    _assert_trees_equal(jax.tree_util.tree_leaves(c1), leaves,
+                        "fleet-cohort carry != stacked carry")
     eng.close()
 
 
@@ -166,6 +186,7 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import numpy as np
 import jax, jax.numpy as jnp
 from repro.core import cohort, client_batch, rounds, specs, compressors
+from repro.exp import engine, registry
 
 d, m = 6, 8
 key = jax.random.PRNGKey(7)
@@ -185,17 +206,28 @@ def eq(a, b):
                for x, y in zip(jax.tree_util.tree_leaves(a),
                                jax.tree_util.tree_leaves(b)))
 
-# full mode vs the stacked sharded engine
+# a cohort that covers the fleet: the factory's stacked engine vs the
+# stacked sharded run_chunk
 n = 32
-spec = bl2(n, n)
-store = client_batch.synthetic_store(11, n, m, d)
-batch = store.gather_batch(np.arange(n))
+cell = registry.MethodCell(
+    "BL2", "bl2", 6, basis="standard",
+    hess_comp=registry.CompressorCfg(kind="topk", k=2 * d),
+    model_comp=registry.CompressorCfg(kind="identity"), backend="cohort",
+    params=(("tau", n), ("cohort", n), ("rounds_per_cohort", 2)))
+pspec = registry.ProblemSpec(kind="synthetic_stream", n_clients=n, m=m, d=d)
+exp = registry.Experiment(name="cohort-fleet", figure="extra",
+                          title="cohort == fleet", paper_ref="test",
+                          cells=(cell,), problem=pspec)
+prob = engine.StreamProblem(spec=pspec,
+                            store=client_batch.synthetic_store(11, n, m, d),
+                            x0=x0, x_star=None)
+eng = engine.make_engine(exp, cell, prob, root_key=key, sharded=True)
+assert isinstance(eng, rounds.StackedEngine)
+spec = eng.spec
+batch = client_batch.synthetic_store(11, n, m, d).gather_batch(np.arange(n))
 bb = cohort.standard_basisb(d, n)
 c0 = rounds.init_serve_carry(spec, batch, bb, x0, sharded=True)
 _, ys1 = rounds.run_chunk(spec, batch, bb, x0, c0, 0, 6, key, sharded=True)
-eng = cohort.CohortEngine(spec, client_batch.synthetic_store(11, n, m, d),
-                          x0, cohort=n, rounds_per_cohort=2, root_key=key,
-                          basis="standard", sharded=True)
 ys2 = eng.run_chunk(0, 6)
 eng.close()
 print("FULL_SHARDED", eq(ys1, ys2), flush=True)
@@ -215,9 +247,10 @@ print("STREAM_SHARDED", eq(outs[0], outs[1]), flush=True)
 
 
 def test_sharded_parity_subprocess():
-    """Both sharded pins in one 8-virtual-device child: full-mode parity
-    vs the stacked sharded engine, and streaming vmap == streaming sharded
-    (the exact fixed-order reducer contract)."""
+    """Both sharded pins in one 8-virtual-device child: a cohort that
+    covers the fleet runs the factory's stacked engine, bitwise the stacked
+    sharded `run_chunk`, and streaming vmap == streaming sharded (the exact
+    fixed-order reducer contract)."""
     env = dict(os.environ, PYTHONPATH="src", JAX_PLATFORMS="cpu")
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     r = subprocess.run([sys.executable, "-c", _SHARDED_SCRIPT], env=env,

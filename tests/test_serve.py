@@ -79,9 +79,8 @@ def test_checkpoint_roundtrip_resume_bitwise(problem, tmp_path, sharded):
     c0 = rounds.init_serve_carry(spec, batch, basisb, x0, **kw)
     _, ref = _chunks(spec, batch, basisb, x0, c0, plan, 0, 14, 14, root, **kw)
 
-    # run 6 rounds, checkpoint through the artifact layer, restore, finish
-    # (fresh carry: run_chunk DONATES its carry argument, so c0's buffers
-    # died inside the reference run above)
+    # run 6 rounds from a fresh carry, checkpoint through the artifact
+    # layer, restore, finish
     c0 = rounds.init_serve_carry(spec, batch, basisb, x0, **kw)
     mid, head = _chunks(spec, batch, basisb, x0, c0, plan, 0, 6, 3, root, **kw)
     artifacts.save_checkpoint(
@@ -241,6 +240,57 @@ def test_fed_serve_counts_server_eigh_rounds(tmp_path, monkeypatch, mu):
                           backend="reference")
     np.testing.assert_allclose(rec["history"]["gaps"], ref.gaps, rtol=1e-9, atol=1e-8)
     np.testing.assert_allclose(rec["history"]["up_bits"], ref.up_bits, rtol=1e-12)
+
+
+#: what a serve record holds at its top level and in ``meta``, on either
+#: engine, and the host spans a call without a metrics sink closes
+_RECORD_KEYS = {"schema", "experiment", "cell", "seed", "config_digest",
+                "config", "rounds", "history", "degraded_rounds", "meta"}
+_META_KEYS = {"backend", "chunk", "chunks_run", "resumed_from",
+              "straggler_wait_s", "runtime_s", "ttfr_s", "progcache",
+              "server_eigh", "ckpt", "spans", "retraces"}
+_SPANS = {"serve.warm", "serve.restore", "serve.dispatch", "serve.pull",
+          "serve.checkpoint", "ckpt.payload", "ckpt.write", "ckpt.digest",
+          "ckpt.prune"}
+_ENGINE_RECORD = {
+    "stacked": ("fig4", "BL2_tau_half", set(), set()),
+    "cohort": ("cohort-smoke", "BL2",
+               {"cohort", "rounds_per_cohort", "n_clients",
+                "prefetch_overlap", "prefetch"},
+               {"cohort.unload", "cohort.load", "cohort.prefetch_wait",
+                "cohort.gather"}),
+}
+
+
+@pytest.mark.parametrize("engine", sorted(_ENGINE_RECORD))
+def test_serve_record_shape_per_engine(tmp_path, engine):
+    """The record's keys, its ``meta`` keys, the host span names and the
+    per-chunk "rounds a..b done" lines, on the stacked engine and on the
+    cohort engine: the fields that readers of a serve record take."""
+    import re
+
+    from repro.launch import fed_serve
+
+    exp, cell, meta_extra, spans_extra = _ENGINE_RECORD[engine]
+    lines = []
+    rec = fed_serve.serve(exp_name=exp, cell_name=cell, seed=1, chunk=2,
+                          max_rounds=4, ckpt_dir=str(tmp_path / "ck"),
+                          progcache_dir=str(tmp_path / "pc"),
+                          log=lambda msg, *a: lines.append(str(msg)))
+    assert set(rec) == _RECORD_KEYS
+    assert set(rec["meta"]) == _META_KEYS | meta_extra
+    assert set(rec["meta"]["spans"]) == _SPANS | spans_extra
+    assert set(rec["history"]) == {"gaps", "up_bits", "down_bits", "legs",
+                                   "events"}
+    assert set(rec["meta"]["ckpt"]) == {"count", "bytes", "copied_bytes",
+                                        "hash_wait_s"}
+    assert set(rec["meta"]["server_eigh"]) == {"rounds", "of"}
+    done = [m.groups() for m in map(
+        re.compile(r"\[serve\] rounds (\d+)\.\.(\d+) done").match, lines) if m]
+    assert done == [("0", "1"), ("2", "3")]
+    assert rec["rounds"] == 4 and len(rec["history"]["gaps"]) == 4
+    assert rec["meta"]["backend"] == ("cohort" if engine == "cohort"
+                                      else "fast")
 
 
 _ENV = {"PYTHONPATH": "src", "PATH": os.environ.get("PATH", "/usr/bin:/bin"),

@@ -188,7 +188,7 @@ def _compiled(spec, batch, basisb, x0):
     keys = jax.vmap(lambda t: jax.random.fold_in(jax.random.PRNGKey(5), t))(ts)
     avail = jnp.ones((3, batch.n), bool)
     args = (batch, basisb, x0, carry, ts, keys, avail)
-    compiled = rounds._chunk_jit_aot.lower(spec, R, *args).compile()
+    compiled = rounds._chunk_program.lower(spec, R, *args).compile()
     return compiled, compiled(*args)
 
 
